@@ -32,53 +32,40 @@ import threading as _threading
 import jax._src.compiler as _jax_compiler
 
 if not getattr(_jax_compiler, "_srtpu_compile_lock_installed", False):
-    # RLock: _compile_and_write_cache calls the backend compile entry
-    # internally, and both are wrapped.  The entry point is named
-    # backend_compile_and_load on new jax and backend_compile on 0.4.x —
-    # wrap whichever this image ships.
+    # RLock: _compile_and_write_cache calls backend_compile_and_load
+    # internally, and both are wrapped.
     _compile_lock = _threading.RLock()
 
     def _serialize(name):
-        orig = getattr(_jax_compiler, name, None)
-        if orig is None:
-            return
+        orig = getattr(_jax_compiler, name)
 
         def wrapped(*args, _orig=orig, **kwargs):
             with _compile_lock:
-                # tpu-lint: allow-lock-order(serializing XLA compiles IS this lock's purpose; old jaxlib CPU backends crash on concurrent compile)
+                # tpu-lint: allow-lock-order(serializing XLA compiles IS this lock's purpose; the jaxlib CPU backend crashes on concurrent compile)
                 return _orig(*args, **kwargs)
 
         setattr(_jax_compiler, name, wrapped)
 
-    for _name in ("backend_compile_and_load", "backend_compile",
-                  "_compile_and_write_cache"):
+    for _name in ("backend_compile_and_load", "_compile_and_write_cache"):
         _serialize(_name)
     _jax_compiler._srtpu_compile_lock_installed = True
 
-# Persistent XLA compilation cache — OPT-IN via
-# SPARK_RAPIDS_TPU_COMPILE_CACHE=<dir>.  It speeds compile-heavy reruns
-# dramatically, but jaxlib 0.9's executable SERIALIZATION (cache write,
-# compilation_cache.put_executable_and_time) segfaults natively when other
-# threads are executing programs — reproduced twice on large string-key
-# join programs under the engine thread pool, and not catchable from
-# Python.  Default off; enable for single-process benchmark/driver runs
-# where compiles are effectively serial.
+# Persistent XLA compilation cache.  Sort-bearing programs take minutes
+# each to compile for the TPU (CHANGES.md, PR 22), so a chip run is only
+# usable warm.  The directory is part of the cache key's environment and
+# must not move: JAX_COMPILATION_CACHE_DIR places it from outside (JAX
+# reads that variable itself — nothing is set here then); otherwise it is
+# <checkout>/.jax_cache.  JAX's defaults persist only programs that took
+# a second or more to build.  The CPU test suite turns the cache off
+# (tests/conftest.py): jaxlib 0.9's cache WRITE has crashed natively there
+# under the engine's thread pool.
 import os as _os
 
-_cache_dir = _os.environ.get("SPARK_RAPIDS_TPU_COMPILE_CACHE")
-if _cache_dir:
-    try:
-        _os.makedirs(_cache_dir, exist_ok=True)
-        _jax.config.update("jax_compilation_cache_dir", _cache_dir)
-        # only persist programs that are actually expensive to build: tiny
-        # eager primitives round-tripping the disk cache cost more in AOT
-        # load/verify than they save
-        _jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        _jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    # tpu-lint: allow-swallow(compile cache is an optimization; failing import over it would take down every entry point)
-    except Exception:
-        pass
-
+if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    _jax.config.update(
+        "jax_compilation_cache_dir",
+        _os.path.join(_os.path.dirname(_os.path.dirname(
+            _os.path.abspath(__file__))), ".jax_cache"))
 from spark_rapids_tpu import types  # noqa: F401
 from spark_rapids_tpu.config import RapidsConf  # noqa: F401
 from spark_rapids_tpu.columnar.batch import ColumnarBatch, Schema  # noqa: F401

@@ -4,8 +4,10 @@ Reference: GpuDeviceManager.scala (:473-480 pool sizing from
 spark.rapids.memory.gpu.allocFraction over the device's total memory,
 device selection/pinning, init-time validation).  The TPU analog reads the
 PJRT device's memory stats and sizes the arena budget as
-allocFraction x HBM bytes; on backends that expose no stats (CPU tests,
-some tunnels) the arena stays in unlimited bookkeeping mode.
+allocFraction x HBM bytes.  The CPU backend exposes no stats, and there
+(tests) the arena stays in unlimited bookkeeping mode; on a TPU missing
+stats are an error — a chip run with no HBM budget would look healthy
+until the device itself ran out.
 """
 from __future__ import annotations
 
@@ -29,14 +31,13 @@ def probe_device() -> DeviceInfo:
     reference's one-GPU-per-executor model)."""
     import jax
     dev = jax.devices()[0]
-    hbm = None
-    try:
-        stats = dev.memory_stats()
-        if stats:
-            hbm = int(stats.get("bytes_limit")
-                      or stats.get("bytes_reservable_limit") or 0) or None
-    except Exception:
-        hbm = None
+    stats = dev.memory_stats() or {}
+    hbm = int(stats.get("bytes_limit")
+              or stats.get("bytes_reservable_limit") or 0) or None
+    if hbm is None and dev.platform == "tpu":
+        raise RuntimeError(
+            f"{dev} reports no memory limit (memory_stats()={stats!r}): "
+            "cannot size the HBM arena budget")
     return DeviceInfo(dev, hbm, dev.platform)
 
 

@@ -5,9 +5,10 @@ serializer ("tpu-kudo", native/kudo.cpp) and the row<->columnar converter
 (native/rowconv.cpp) run as C++ — these sit on host hot paths where a
 Python loop would dominate.
 
-Build: lazily compiled with g++ on first use (no pip); the .so is cached in
-native/build/.  Set SPARK_RAPIDS_TPU_NO_NATIVE=1 to force the pure-Python
-fallbacks (used to differential-test the native code itself).
+Build: lazily compiled with g++ on first use (no pip) into native/build/
+(git-ignored), keyed by the sources' content hash.  A failed build raises.
+Set SPARK_RAPIDS_TPU_NO_NATIVE=1 to force the pure-Python fallbacks (used
+to differential-test the native code itself).
 """
 from __future__ import annotations
 
@@ -22,11 +23,9 @@ import numpy as np
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _SRC_DIR = os.path.join(_REPO, "native")
 _BUILD_DIR = os.path.join(_SRC_DIR, "build")
-_SO_PATH = os.path.join(_BUILD_DIR, "libtpurapids.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
-_tried = False
 
 
 class TkCol(ctypes.Structure):
@@ -58,41 +57,60 @@ class RcCol(ctypes.Structure):
     ]
 
 
-def _build() -> Optional[str]:
+_SOURCES = ("kudo.cpp", "rowconv.cpp")
+# no -march=native: the build directory is not part of a checkout but IS
+# part of a disk copy, so a binary may run on another host than built it
+_CXXFLAGS = ("-O3", "-shared", "-fPIC", "-std=c++17")
+
+
+def _build() -> str:
+    """Compile the library unless a build of exactly these sources and
+    flags exists: the content hash is part of the file name, so a stale or
+    foreign binary is never picked up, and concurrent builders (xdist
+    workers on a fresh checkout) race only to an atomic rename."""
+    import hashlib
+    srcs = [os.path.join(_SRC_DIR, f) for f in _SOURCES]
+    h = hashlib.sha256(" ".join(_CXXFLAGS).encode())
+    for src in srcs:
+        with open(src, "rb") as f:
+            h.update(f.read())
+    so_path = os.path.join(_BUILD_DIR,
+                           f"libtpurapids-{h.hexdigest()[:16]}.so")
+    if os.path.exists(so_path):
+        return so_path
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    srcs = [os.path.join(_SRC_DIR, f) for f in ("kudo.cpp", "rowconv.cpp")]
-    newest_src = max(os.path.getmtime(s) for s in srcs)
-    if os.path.exists(_SO_PATH) and os.path.getmtime(_SO_PATH) >= newest_src:
-        return _SO_PATH
-    cmd = ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
-           "-o", _SO_PATH] + srcs
+    tmp = f"{so_path}.{os.getpid()}.tmp"
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return _SO_PATH
-    except Exception:
-        return None
+        subprocess.run(["g++", *_CXXFLAGS, "-o", tmp, *srcs], check=True,
+                       capture_output=True, text=True, timeout=300)
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError(
+            f"building {so_path} failed:\n{e.stderr}\n(set "
+            "SPARK_RAPIDS_TPU_NO_NATIVE=1 for the pure-Python "
+            "serializer)") from e
+    os.replace(tmp, so_path)
+    return so_path
 
 
 def lib() -> Optional[ctypes.CDLL]:
-    """The native library, or None when unavailable/disabled."""
-    global _lib, _tried
+    """The native library; None only under SPARK_RAPIDS_TPU_NO_NATIVE.  A
+    build that fails raises — silently dropping every caller to the
+    pure-Python serializer would hide a broken host path."""
+    global _lib
     if os.environ.get("SPARK_RAPIDS_TPU_NO_NATIVE"):
         return None
     with _lock:
-        if _lib is None and not _tried:
-            _tried = True
+        if _lib is None:
             # tpu-lint: allow-lock-order(one-time double-checked build; holding the lock prevents two threads compiling the native lib)
-            so = _build()
-            if so:
-                l = ctypes.CDLL(so)
-                l.tk_serialized_size.restype = ctypes.c_uint64
-                l.tk_serialize.restype = ctypes.c_uint64
-                l.tk_serialize_range.restype = ctypes.c_uint64
-                l.tk_row_count.restype = ctypes.c_uint64
-                l.tk_col_count.restype = ctypes.c_uint32
-                l.tk_merge.restype = ctypes.c_uint64
-                l.trow_sizes.restype = ctypes.c_uint64
-                _lib = l
+            l = ctypes.CDLL(_build())
+            l.tk_serialized_size.restype = ctypes.c_uint64
+            l.tk_serialize.restype = ctypes.c_uint64
+            l.tk_serialize_range.restype = ctypes.c_uint64
+            l.tk_row_count.restype = ctypes.c_uint64
+            l.tk_col_count.restype = ctypes.c_uint32
+            l.tk_merge.restype = ctypes.c_uint64
+            l.trow_sizes.restype = ctypes.c_uint64
+            _lib = l
         return _lib
 
 

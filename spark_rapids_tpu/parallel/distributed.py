@@ -150,7 +150,6 @@ def make_all_to_all_exchange(mesh: Mesh, schema: Schema, key_cols: Sequence[int]
         recv_occupied = jax.lax.all_to_all(occupied, "data", 0, 0, tiled=False)
         return recv, recv_valid, recv_occupied, required
 
-    from spark_rapids_tpu.utils.jax_compat import shard_map
     in_spec = (
         {n: P("data") for n in names},
         {n: P("data") for n in names},
@@ -162,8 +161,8 @@ def make_all_to_all_exchange(mesh: Mesh, schema: Schema, key_cols: Sequence[int]
         P("data", None),
         P("data"),
     )
-    step = shard_map(local_step, mesh=mesh, in_specs=in_spec,
-                     out_specs=out_spec)
+    step = jax.shard_map(local_step, mesh=mesh, in_specs=in_spec,
+                         out_specs=out_spec)
     return jax.jit(step)
 
 
@@ -209,8 +208,7 @@ def distributed_group_sum(mesh: Mesh, schema: Schema, key_col: str,
         n_groups = jnp.sum(boundary.astype(jnp.int32)).reshape(1)
         return group_keys, sums, n_groups
 
-    from spark_rapids_tpu.utils.jax_compat import shard_map
-    local_agg_sm = shard_map(
+    local_agg_sm = jax.shard_map(
         local_agg, mesh=mesh,
         in_specs=(P("data", None),) * 5,
         out_specs=(P("data"), P("data"), P("data")))

@@ -329,11 +329,10 @@ def _exchange_fn(mesh, axis, schema, key_idx, P, row_quota, byte_quota,
 
     # check_vma off: kernel scan carries (string hash/sort) start from
     # unvarying constants, which the VMA checker rejects inside manual mode
-    from spark_rapids_tpu.utils.jax_compat import shard_map
-    sm = shard_map(per_device, mesh=mesh,
-                   in_specs=(PS(axis),),
-                   out_specs=(PS(axis), PS(axis), PS(axis)),
-                   check_vma=False)
+    sm = jax.shard_map(per_device, mesh=mesh,
+                       in_specs=(PS(axis),),
+                       out_specs=(PS(axis), PS(axis), PS(axis)),
+                       check_vma=False)
     fn = jax.jit(sm)
     _EXCHANGE_CACHE[key] = fn
     if len(_EXCHANGE_CACHE) > 64:

@@ -111,6 +111,13 @@ def _exec_signature(node) -> str:
             + ",".join(_exec_signature(c) for c in node.children) + ")")
 
 
+def _is_scan(node) -> bool:
+    """Leaf scans the SPMD compiler takes as program arguments."""
+    from spark_rapids_tpu.plan.execs.scan import (
+        TpuInMemoryScanExec, TpuParquetScanExec)
+    return isinstance(node, (TpuInMemoryScanExec, TpuParquetScanExec))
+
+
 class IciQueryExecutor:
     """Executes a planned exec tree SPMD over a mesh, one jitted program."""
 
@@ -184,6 +191,12 @@ class IciQueryExecutor:
                     _SPMD_PROGRAMS.popitem(last=False)
             out, feedback = fn(*[self._place(x, k)
                                  for x, k in zip(inputs, in_kinds)])
+            # capacity defaults are only seeded while tracing, so the
+            # next run looks this program up under the SEEDED caps: alias
+            # it there, or every plan would compile its program twice
+            _SPMD_PROGRAMS.setdefault(
+                base_key + "|" + repr(sorted(caps.caps.items())),
+                (fn, out_kind))
             ok = True
             # tpu-lint: allow-host-sync(capacity feedback must reach the host; one batched sync per attempt)
             for key, required in jax.device_get(feedback).items():
@@ -224,8 +237,7 @@ class IciQueryExecutor:
 
     def _collect_scans(self, node, out, replicated=False):
         from spark_rapids_tpu.plan.execs.join import TpuBroadcastHashJoinExec
-        from spark_rapids_tpu.plan.execs.scan import TpuInMemoryScanExec
-        if isinstance(node, TpuInMemoryScanExec):
+        if _is_scan(node):
             out.append((node, REPLICATED if replicated else SHARDED))
             return
         if isinstance(node, TpuBroadcastHashJoinExec):
@@ -237,8 +249,15 @@ class IciQueryExecutor:
 
     def _scan_shards(self, node, kind):
         """Round-robin partitions onto devices; one local batch per device
-        (REPLICATED: single full batch, same on every device)."""
-        batches = [b for part in node.partitions for b in part]
+        (REPLICATED: single full batch, same on every device).  A file
+        scan is read whole first: its batches upload to the default device
+        and are resharded when the program runs."""
+        from spark_rapids_tpu.plan.execs.scan import TpuInMemoryScanExec
+        if isinstance(node, TpuInMemoryScanExec):
+            batches = [b for part in node.partitions for b in part]
+        else:
+            batches = [b for p in range(node.num_partitions())
+                       for b in node.execute_partition(p)]
         if kind == REPLICATED:
             merged = _host_concat(batches, node.schema)
             return merged
@@ -292,8 +311,7 @@ class IciQueryExecutor:
                          for k in build.arg_kinds)
         fb_spec = {k: PS(self.axis) for k in build.feedback_keys}
 
-        from spark_rapids_tpu.utils.jax_compat import shard_map
-        sm = shard_map(
+        sm = jax.shard_map(
             device_program, mesh=self.mesh,
             in_specs=in_specs,
             out_specs=(PS(self.axis), fb_spec),
@@ -338,9 +356,8 @@ class _NodeBuilder:
         from spark_rapids_tpu.plan.execs.join import (
             TpuBroadcastHashJoinExec, TpuShuffledHashJoinExec)
         from spark_rapids_tpu.plan.execs.range_sort import TpuRangeSortExec
-        from spark_rapids_tpu.plan.execs.scan import TpuInMemoryScanExec
         from spark_rapids_tpu.plan.execs.sort import TpuLimitExec, TpuSortExec
-        if isinstance(node, TpuInMemoryScanExec):
+        if _is_scan(node):
             return self.arg_kinds[self.scan_args[id(node)]]
         if isinstance(node, (TpuSinglePartitionExec, TpuRangeSortExec,
                              TpuLimitExec)):
@@ -393,7 +410,6 @@ class _NodeBuilder:
             TpuShuffleExchangeExec)
         from spark_rapids_tpu.plan.execs.join import (
             TpuBroadcastHashJoinExec, TpuShuffledHashJoinExec)
-        from spark_rapids_tpu.plan.execs.scan import TpuInMemoryScanExec
 
         def join_keys(node):
             from spark_rapids_tpu.kernels.selection import (
@@ -413,7 +429,7 @@ class _NodeBuilder:
         index(root)
 
         def walk(node, replicated):
-            if isinstance(node, TpuInMemoryScanExec):
+            if _is_scan(node):
                 pos = self.scan_args[id(node)]
                 self.arg_ids[pos] = id(node)
                 self.arg_kinds[pos] = REPLICATED if replicated else SHARDED
@@ -448,10 +464,9 @@ class _NodeBuilder:
         from spark_rapids_tpu.plan.execs.join import (
             TpuBroadcastHashJoinExec, TpuShuffledHashJoinExec)
         from spark_rapids_tpu.plan.execs.range_sort import TpuRangeSortExec
-        from spark_rapids_tpu.plan.execs.scan import TpuInMemoryScanExec
         from spark_rapids_tpu.plan.execs.sort import TpuLimitExec, TpuSortExec
 
-        if isinstance(node, TpuInMemoryScanExec):
+        if _is_scan(node):
             kind = self.arg_kinds[self.scan_args[id(node)]]
             return env[id(node)], kind
 

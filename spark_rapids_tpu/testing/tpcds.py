@@ -99,8 +99,7 @@ def gen_item(n_items: int = 2000, seed: int = 11) -> ColumnarBatch:
         ITEM_SCHEMA)
 
 
-def gen_store_sales(n_rows: int, n_items: int = 2000, seed: int = 13,
-                    batch_rows: int = 1 << 19) -> List[ColumnarBatch]:
+def _store_sales_spec(n_rows: int, n_items: int):
     def spec(rng, n):
         data = {
             "ss_sold_date_sk": (2450000 + rng.randint(0, 6 * 365, n)
@@ -127,8 +126,23 @@ def gen_store_sales(n_rows: int, n_items: int = 2000, seed: int = 13,
                                    ).astype(np.int32)
         validity["ss_promo_sk"] = rng.rand(n) >= 0.1   # some null promos
         return data, validity
-    return _gen_channel_fact(STORE_SALES_SCHEMA, spec, n_rows, seed, 31,
-                             batch_rows)
+    return spec
+
+
+def store_sales_host_chunks(n_rows: int, n_items: int = 2000, seed: int = 13,
+                            batch_rows: int = 1 << 19):
+    """store_sales as host (column dict, {name: validity}) chunks — what
+    ``gen_store_sales`` uploads; file-backed callers (chip_smoke.py) write
+    them to Parquet instead."""
+    return _host_chunks(_store_sales_spec(n_rows, n_items), n_rows, seed, 31,
+                        batch_rows)
+
+
+def gen_store_sales(n_rows: int, n_items: int = 2000, seed: int = 13,
+                    batch_rows: int = 1 << 19) -> List[ColumnarBatch]:
+    return _gen_channel_fact(STORE_SALES_SCHEMA,
+                             _store_sales_spec(n_rows, n_items), n_rows,
+                             seed, 31, batch_rows)
 
 
 def q3(store_sales_df, date_dim_df, item_df):
@@ -203,29 +217,37 @@ CHANNEL_RETURNS_SCHEMA = Schema.of(
 )
 
 
-def _gen_channel_fact(schema, colspec, n_rows: int, seed: int,
-                      seed_stride: int, batch_rows: int):
-    """Shared chunking loop for the fact generators.
+def _host_chunks(colspec, n_rows: int, seed: int, seed_stride: int,
+                 batch_rows: int):
+    """Shared chunking loop for the fact generators: yields host
+    (column dict, {name: validity}) per ``batch_rows`` rows.
 
     colspec(rng, n) -> column dict, or (column dict, {name: validity})."""
-    from spark_rapids_tpu.columnar.column import DeviceColumn, round_up_pow2
-    import jax.numpy as jnp
-    out = []
     remaining = n_rows
     chunk = 0
     while remaining > 0:
         n = min(batch_rows, remaining)
         rng = np.random.RandomState(seed + seed_stride * chunk)
         spec = colspec(rng, n)
-        data, validity = spec if isinstance(spec, tuple) else (spec, {})
+        yield spec if isinstance(spec, tuple) else (spec, {})
+        remaining -= n
+        chunk += 1
+
+
+def _gen_channel_fact(schema, colspec, n_rows: int, seed: int,
+                      seed_stride: int, batch_rows: int):
+    """Upload ``_host_chunks`` as one device batch per chunk."""
+    from spark_rapids_tpu.columnar.column import DeviceColumn, round_up_pow2
+    out = []
+    for data, validity in _host_chunks(colspec, n_rows, seed, seed_stride,
+                                       batch_rows):
+        n = len(data[schema.names[0]])
         cap = round_up_pow2(n)
         cols = tuple(
             DeviceColumn.from_numpy(data[m], dt, validity.get(m),
                                     capacity=cap)
             for m, dt in zip(schema.names, schema.dtypes))
         out.append(ColumnarBatch(cols, host_scalar(n), schema))
-        remaining -= n
-        chunk += 1
     return out
 
 

@@ -20,8 +20,7 @@ def _executor_proc(driver_rpc_addr, stop_ev):
     import sys
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
-    from spark_rapids_tpu.utils.jax_compat import set_host_device_count
-    set_host_device_count(8)
+    jax.config.update("jax_num_cpu_devices", 8)
     jax.config.update("jax_enable_x64", True)
     from spark_rapids_tpu.cluster.executor import executor_main
     executor_main(tuple(driver_rpc_addr), stop_check=stop_ev.is_set)

@@ -575,15 +575,23 @@ def test_on_disk_subset_is_augmented():
 # -- runtime budget (satellite: the tier must stay usable) -------------------
 
 def test_lint_runtime_budgets():
-    """Full run ≤30s, --changed (two-file subset) ≤5s, per ISSUE 18.
-    Measured on the per-rule timing sums run_all_timed reports."""
+    """Full run ≤30s, --changed (two-file subset) ≤5s, per ISSUE 18 — of
+    process CPU time: the linter is single-threaded pure Python, and the
+    per-rule wall-clock sums run_all_timed reports are stretched past the
+    budget by whatever else shares the cores (xdist workers)."""
+    import time
+
+    t0 = time.process_time()
     _vs, full_t = lint_core.run_all_timed(REPO, with_drift=False)
-    assert sum(full_t.values()) <= 30.0, full_t
+    full_cpu = time.process_time() - t0
+    assert full_cpu <= 30.0, (full_cpu, full_t)
     changed = ["spark_rapids_tpu/shuffle/net.py",
                "spark_rapids_tpu/memory/spill.py"]
+    t0 = time.process_time()
     _vs, chg_t = lint_core.run_all_timed(REPO, with_drift=False,
                                          files=changed)
-    assert sum(chg_t.values()) <= 5.0, chg_t
+    chg_cpu = time.process_time() - t0
+    assert chg_cpu <= 5.0, (chg_cpu, chg_t)
 
 
 # -- lock-order: transitive blocking-under-lock ------------------------------

@@ -23,8 +23,7 @@ jax.config.update("jax_platforms", "cpu")
 import sys
 sys.path.insert(0, {repo!r})
 sys.path.insert(0, {testdir!r})
-from spark_rapids_tpu.utils.jax_compat import set_host_device_count
-set_host_device_count(8)
+jax.config.update("jax_num_cpu_devices", 8)
 jax.config.update("jax_enable_x64", True)
 from spark_rapids_tpu.expressions import col
 from test_out_of_core import _join_sources, assert_ooc_equal

@@ -4,7 +4,7 @@ enable_launch_profile — the engine mode behind `bench.py --profile`).
 The profiler must (1) attribute execution to the program that ran it
 (dispatches block through block_until_ready while armed), (2) record
 launches and output row capacities per program key, (3) cost nothing
-when disarmed (the default), and (4) surface through the bench child as
+when disarmed (the default), and (4) surface through bench.py as
 a `prog_profile` artifact entry.
 """
 import numpy as np
@@ -82,27 +82,17 @@ def test_out_row_capacity_walks_result_pytrees():
     assert _out_row_capacity(123) == 0
 
 
-def test_bench_child_emits_prog_profile(monkeypatch):
-    """The bench child's --profile plumbing: with the env flag set, the
-    JSON line carries a prog_profile list sorted by wall time."""
-    import io
-    import json
-    import sys
-
+def test_bench_query_emits_prog_profile(monkeypatch):
+    """bench.py's --profile plumbing: with the env flag set, a query's
+    result carries a prog_profile list sorted by wall time, and names the
+    device it ran on."""
     import bench
 
     monkeypatch.setenv("SPARK_RAPIDS_TPU_BENCH_PROGPROF", "1")
     monkeypatch.setenv("TPU_ORACLE_CACHE", "0")
-    captured = io.StringIO()
-    monkeypatch.setattr(sys, "stdout", captured)
-    try:
-        bench._child_query("cpu", "q6", 65536)
-    finally:
-        sys.stdout = sys.__stdout__
-    line = [ln for ln in captured.getvalue().splitlines()
-            if ln.startswith("{")][-1]
-    out = json.loads(line)
+    out = bench._run_query("q6", 65536)
     assert out["query"] == "q6"
+    assert out["device"]["platform"] == "cpu" and "engine_s" in out
     prof = out.get("prog_profile")
     assert prof, out.keys()
     assert all({"program", "launches", "ns", "rows"} <= set(e)

@@ -40,3 +40,27 @@ def test_map_batches_with_pandas():
 
     assert_tpu_cpu_equal(
         lambda s: source(s).map_batches(via_pandas, OUT_SCHEMA))
+
+
+def test_udf_worker_never_opens_the_parents_chip(monkeypatch):
+    """One process per chip: whatever platform the parent runs on, a UDF
+    worker is spawned with JAX_PLATFORMS=cpu, and the parent's own
+    setting is restored after the spawn."""
+    import os
+
+    from spark_rapids_tpu.plan.execs.python_worker import PythonWorkerPool
+
+    def worker_platform(table: pa.Table) -> pa.Table:
+        import os
+        return pa.table({"p": [os.environ.get("JAX_PLATFORMS")]})
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")   # a parent on the chip
+    pool = PythonWorkerPool(1)                   # spawns its worker now
+    try:
+        assert os.environ["JAX_PLATFORMS"] == "tpu"
+        out = pool.run(worker_platform, pa.table({"x": [1]}))
+        assert out.column("p").to_pylist() == ["cpu"]
+    finally:
+        for w in pool._free:
+            if w is not None:
+                w.close()

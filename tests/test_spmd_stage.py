@@ -179,3 +179,45 @@ def test_spmd_global_agg(mesh):
     assert len(got) == 1
     assert got[0][0] == expect[0][0] and got[0][1] == expect[0][1]
     assert abs(got[0][2] - expect[0][2]) < 1e-9
+
+
+def test_spmd_takes_parquet_scans_and_compiles_once(mesh, tmp_path):
+    """A file-backed plan runs on the mesh — it used to be declined
+    (UnsupportedSpmd), which collect() answers by falling back to the
+    one-device engine without a word — and its program is built once:
+    the second run finds it under the capacities the first one seeded."""
+    from spark_rapids_tpu.parallel import stage
+
+    tpu = TpuSession({"spark.rapids.sql.enabled": "true"})
+    k = [i % 7 for i in range(4000)]
+    v = [float(i) for i in range(4000)]
+    paths = []
+    for p in range(2):
+        path = str(tmp_path / f"t{p}.parquet")
+        tpu.create_dataframe(
+            {"k": k[p::2], "v": v[p::2]},
+            Schema.of(k=T.INT, v=T.DOUBLE)).write_parquet(path)
+        paths.append(path)
+
+    def q(sess):
+        from spark_rapids_tpu.expressions import sum_
+        return sess.read_parquet(*paths).group_by("k").agg(
+            sum_("v").alias("s"))
+
+    expect = sorted(q(TpuSession({"spark.rapids.sql.enabled": "false"}))
+                    .collect())
+    built = []
+    real_compile = IciQueryExecutor._compile
+
+    def counting_compile(self, *a, **kw):
+        built.append(1)
+        return real_compile(self, *a, **kw)
+
+    IciQueryExecutor._compile = counting_compile
+    try:
+        for _ in range(2):
+            assert sorted(_spmd_rows(mesh, q(tpu))) == expect
+    finally:
+        IciQueryExecutor._compile = real_compile
+    assert len(built) == 1, built
+    assert stage._SPMD_PROGRAMS

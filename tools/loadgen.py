@@ -16,6 +16,9 @@ Used by the chaos soak (tests/test_load_soak.py) and by
 stand-alone against a self-built mini cluster:
 
     python tools/loadgen.py --rate 20 --duration 5
+
+Stand-alone it forces JAX_PLATFORMS=cpu unless the variable is already
+set: its latencies are then CPU-backend numbers, not device numbers.
 """
 from __future__ import annotations
 
@@ -162,7 +165,11 @@ def _main() -> None:
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if repo not in sys.path:
         sys.path.insert(0, repo)
-    parser = argparse.ArgumentParser(description=__doc__)
+    parser = argparse.ArgumentParser(
+        description=__doc__,
+        formatter_class=argparse.RawDescriptionHelpFormatter,
+        epilog="Runs on the CPU backend unless JAX_PLATFORMS is set: the "
+               "latencies it prints are not device numbers.")
     parser.add_argument("--rate", type=float, default=10.0,
                         help="offered arrival rate (queries/second)")
     parser.add_argument("--duration", type=float, default=5.0,

@@ -13,6 +13,10 @@ Run:
     python tools/run_suites.py --list
     python tools/run_suites.py --timeout-scale 2.0   # slow container
 
+Every suite runs with JAX_PLATFORMS=cpu forced: these are correctness
+runs on XLA's CPU backend, and the seconds in the summary table are suite
+wall-clock there, never device numbers.
+
 Exit code: number of failing suites (0 = all green).
 """
 from __future__ import annotations
@@ -138,7 +142,10 @@ def run_suite(name: str, files, timeout_s: float, extra_args,
 
 
 def main(argv=None) -> int:
-    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap = argparse.ArgumentParser(
+        description=__doc__.splitlines()[0],
+        epilog="Forces JAX_PLATFORMS=cpu for every suite: the seconds it "
+               "prints are CPU-backend test wall-clock, not device numbers.")
     ap.add_argument("suites", nargs="*",
                     help=f"subset to run (default all): {sorted(SUITES)}")
     ap.add_argument("--list", action="store_true")

@@ -1,0 +1,7 @@
+"""Peak bytes in use on the fullest chip after the window, in GB (1e9)."""
+
+
+def read(ctx):
+    if ctx.memory_peak_bytes is None:
+        return None
+    return ctx.memory_peak_bytes / 1e9

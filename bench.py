@@ -1,6 +1,6 @@
 """Benchmark harness: TPC-H/TPC-DS queries through the full engine path.
 
-    python bench.py [--profile]     q6 q1 q3 q25 q72, one JSON line
+    python bench.py                 q6 q1 q3 q25 q72, one JSON line
     python bench.py --concurrent    serving layer, concurrent vs serialized
     python bench.py --load          open-loop load against a mini cluster
 
@@ -19,13 +19,9 @@ timed collect(), ``oracle_s`` the CPU oracle engine's on the same data.
 With SPARK_RAPIDS_TPU_BENCH_PROFILE=<dir> one EXTRA run per query is wrapped
 in jax.profiler.trace and digested (tools/profile_digest.py).
 
-With --profile (or SPARK_RAPIDS_TPU_BENCH_PROGPROF=1) each query runs one
-EXTRA pass with per-program attribution armed (plan/execs/base
-enable_launch_profile: every shared_jit dispatch timed through
-block_until_ready + its output row capacity recorded) and emits the topN
-programs by wall time as "prog_profile" — the mode that names a query's
-structural wall by data instead of guesswork.  The attribution pass is
-separate from the timed run (blocking serializes the dispatch pipeline).
+Each query's result carries ``by_program``: launches per program NAME
+(plan/execs/base ``launch_stats``), the name the program has in the device
+trace, so the trace's ``XLA Modules`` line says what each one cost.
 """
 from __future__ import annotations
 
@@ -196,30 +192,10 @@ def _run_query(qname: str, n_rows: int) -> dict:
     trace_counters = {k: v for k, v in trace.counters_snapshot().items()
                       if v}
     # the trace FILE is opt-in like the other bench_profile artifacts:
-    # a plain bench run must not litter the cwd — export only under
-    # --profile or an explicit dir
-    trace_dir = os.environ.get("SPARK_RAPIDS_TPU_BENCH_TRACE_DIR") or (
-        "bench_profile"
-        if os.environ.get("SPARK_RAPIDS_TPU_BENCH_PROGPROF") else None)
+    # a plain bench run must not litter the cwd — export only into an
+    # explicit dir
+    trace_dir = os.environ.get("SPARK_RAPIDS_TPU_BENCH_TRACE_DIR")
     trace_export = export_trace_file(trace, trace_dir) if trace_dir else None
-
-    prog_profile = None
-    if os.environ.get("SPARK_RAPIDS_TPU_BENCH_PROGPROF"):
-        # per-program attribution runs a SEPARATE pass: dispatches block
-        # (block_until_ready per program) so execution time is charged to
-        # the program that ran it, which would distort the timed run
-        from spark_rapids_tpu.plan.execs.base import (
-            disable_launch_profile, enable_launch_profile)
-        enable_launch_profile()
-        try:
-            run(tpu_sess)
-        finally:
-            prof = disable_launch_profile()
-        prog_profile = [
-            {"program": k[:160], "launches": v["launches"], "ns": v["ns"],
-             "rows": v["rows"]}
-            for k, v in sorted(prof.items(),
-                               key=lambda kv: -kv[1]["ns"])[:12]]
 
     util = None
     profile_dir = os.environ.get("SPARK_RAPIDS_TPU_BENCH_PROFILE")
@@ -258,6 +234,7 @@ def _run_query(qname: str, n_rows: int) -> dict:
         "rows_per_sec": round(n_rows / engine_time),
         "engine_s": round(engine_time, 4), "oracle_s": round(cpu_time, 4),
         "launches": stats["launches"], "programs": stats["programs"],
+        "by_program": stats["by_program"],
         "launches_per_stage": round(
             stats["launches"] / max(shuffle.get("exchange_stages", 0), 1),
             1),
@@ -266,7 +243,6 @@ def _run_query(qname: str, n_rows: int) -> dict:
         "trace_counters": trace_counters,
         **({"trace_export": trace_export} if trace_export else {}),
         "input_bytes": input_bytes,
-        **({"prog_profile": prog_profile} if prog_profile else {}),
         **({"util": util} if util else {}),
         **({"profile_dir": profile_dir} if profile_dir else {}),
     }
@@ -517,10 +493,6 @@ def _load_bench() -> None:
 
 
 def main() -> None:
-    if "--profile" in sys.argv:
-        # arm per-program wall-clock/rows attribution (an extra pass per
-        # query; the timed numbers are unaffected — see module doc)
-        os.environ["SPARK_RAPIDS_TPU_BENCH_PROGPROF"] = "1"
     # a failing query raises out of here: non-zero exit, no result line
     results = {q: _run_query(q, N_ROWS) for q in QUERIES}
     print(json.dumps({"unit": "rows/s", "device": _device(),

@@ -9,6 +9,8 @@ engines (reference: integration_tests/src/main/python/spark_session.py:
 """
 from __future__ import annotations
 
+import itertools
+from contextlib import contextmanager, nullcontext
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 from spark_rapids_tpu import types as T
@@ -20,6 +22,9 @@ from spark_rapids_tpu.plan import logical as L
 from spark_rapids_tpu.plan.cpu_engine import CpuEngine
 from spark_rapids_tpu.plan.engine import TpuEngine
 from spark_rapids_tpu.planner.overrides import explain_query, plan_query
+from spark_rapids_tpu.utils import obs, tracing
+
+_COLLECT_IDS = itertools.count(1)   # query ids of traces collect() opens
 
 
 def _to_expr(e) -> Expression:
@@ -120,6 +125,9 @@ class TpuSession:
             crashdump.install(self.conf.diag_dump_dir,
                               context={"session": "standalone"})
         self.last_query_metrics = None
+        #: the QueryTrace the last action opened for itself (a sink was
+        #: on and no trace was ambient); None where it opened none
+        self.last_query_trace = None
 
     def set_conf(self, key: str, value) -> None:
         self.conf = self.conf.with_overrides(**{key: value})
@@ -769,8 +777,40 @@ class DataFrame:
             return self._collect_impl()
 
     def _collect_impl(self) -> List[tuple]:
-        if self.session.conf.sql_enabled:
+        with self._query_root():
+            return self._collect_traced()
+
+    @contextmanager
+    def _query_root(self):
+        """The root of one action's span tree: ``query.collect``, under a
+        ``QueryTrace`` of this action's own when none is ambient (outside
+        serving) and a sink is on, so its spans share a query id and each
+        names its parent.  With no sink on no trace is made."""
+        conf = self.session.conf
+        own = None
+        if obs.current_query_trace() is None and (
+                tracing.span_log.enabled or conf.trace_enabled):
+            own = obs.QueryTrace(f"collect-{next(_COLLECT_IDS)}",
+                                 max_spans=conf.trace_max_spans)
+        try:
+            with obs.trace_scope(own) if own is not None else nullcontext(), \
+                    tracing.trace_range("query.collect"):
+                yield
+        finally:
+            self.session.last_query_trace = own
+            if own is not None:
+                own.finish()
+                if conf.trace_dir:
+                    obs.export_trace_file(own, conf.trace_dir)
+
+    def _plan_query(self):
+        with tracing.trace_range("query.plan"):
             exec_plan, _ = plan_query(self.plan, self.session.conf)
+        return exec_plan
+
+    def _collect_traced(self) -> List[tuple]:
+        if self.session.conf.sql_enabled:
+            exec_plan = self._plan_query()
             from spark_rapids_tpu.plan.execs.fallback import (
                 TpuCpuFallbackExec)
             if isinstance(exec_plan, TpuCpuFallbackExec):
@@ -838,8 +878,8 @@ class DataFrame:
     def _collect_batches(self):
         """Materialize as device batches (the ColumnarRdd analog: zero-copy
         handoff to ML frameworks, reference sql-plugin-api ColumnarRdd.scala)."""
-        with self._session_tz_scope():
-            exec_plan, _ = plan_query(self.plan, self.session.conf)
+        with self._session_tz_scope(), self._query_root():
+            exec_plan = self._plan_query()
             engine = TpuEngine(self.session.conf)
             out = engine.execute(exec_plan)
         self.session.last_query_metrics = engine.last_metrics

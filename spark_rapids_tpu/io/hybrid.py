@@ -17,11 +17,14 @@ def iter_hybrid_parquet(path: str,
                         batch_size_rows: int = 1 << 20) -> Iterator:
     """Yield pyarrow RecordBatches via the dataset scanner."""
     import pyarrow.dataset as ds
-    dataset = ds.dataset(path, format="parquet")
-    scanner = dataset.scanner(
-        columns=list(columns) if columns else None,
-        batch_size=batch_size_rows,
-        use_threads=True)
+
+    from spark_rapids_tpu.utils.tracing import trace_range
+    with trace_range("scan.open"):
+        dataset = ds.dataset(path, format="parquet")
+        scanner = dataset.scanner(
+            columns=list(columns) if columns else None,
+            batch_size=batch_size_rows,
+            use_threads=True)
     for rb in scanner.to_batches():
         if rb.num_rows:
             yield rb

@@ -75,6 +75,8 @@ def iter_parquet_arrow(
 ) -> Iterator[pa.Table]:
     """HOST side of the scan: footer parse, row-group pruning, page decode
     to Arrow tables — safe to run on the reader pool with no semaphore.
+    Everything up to the first row group being ready to read is one
+    ``scan.open`` span.
 
     range_filters: {column: (lo, hi)} predicate-pushdown hints used for
     row-group pruning only (exact filtering stays in the Filter exec —
@@ -86,49 +88,53 @@ def iter_parquet_arrow(
     independent of file size.  coalesce_ranges reads the pruned column
     chunks as few merged I/O requests (io/rangeio.py).
     """
-    from spark_rapids_tpu.io.rangeio import is_remote_path
-    remote = is_remote_path(path)
-    if remote:
-        # object-store scans ALWAYS take the coalesced multithreaded tier:
-        # per-page seeks against an object store are latency death
-        # (the reference routes cloud paths to the MULTITHREADED reader,
-        # GpuParquetScan.scala:3134)
-        coalesce_ranges = True
-    pf = _open_parquet(path)
-    groups: List[int] = []
-    meta = pf.metadata
-    name_to_idx = {meta.schema.column(i).name: i
-                   for i in range(len(meta.schema))}
-    for rg in range(meta.num_row_groups):
-        row_group = meta.row_group(rg)
-        keep = True
-        if range_filters:
-            for cname, (lo, hi) in range_filters.items():
-                ci = name_to_idx.get(cname)
-                if ci is not None and not _stats_allow(row_group, ci, lo, hi):
-                    keep = False
-                    break
-        if keep:
-            groups.append(rg)
-    if not groups:
-        return
-    rows_per_batch = batch_size_rows
-    if batch_size_bytes > 0 and meta.num_rows:
-        total_bytes = sum(meta.row_group(rg).total_byte_size
-                          for rg in range(meta.num_row_groups))
-        bytes_per_row = max(total_bytes / max(meta.num_rows, 1), 1.0)
-        rows_per_batch = max(min(
-            batch_size_rows, int(batch_size_bytes / bytes_per_row)), 1)
-    if coalesce_ranges:
-        from spark_rapids_tpu.io.rangeio import open_coalesced_parquet
-        src, _ = open_coalesced_parquet(path, groups, columns)
-        pf = pq.ParquetFile(src)
-    # LEGACY-calendar files (org.apache.spark.legacyDateTime footer tag)
-    # carry hybrid Julian dates/timestamps: rebase to proleptic Gregorian
-    # on the host path (datetimeRebaseUtils.scala:53-58; VERDICT r3 #4 —
-    # without this, pre-1582 values are silently wrong)
-    from spark_rapids_tpu.io.rebase import needs_rebase, rebase_arrow_table
-    legacy = needs_rebase(meta)
+    from spark_rapids_tpu.utils.tracing import trace_range
+    with trace_range("scan.open"):
+        from spark_rapids_tpu.io.rangeio import is_remote_path
+        remote = is_remote_path(path)
+        if remote:
+            # object-store scans ALWAYS take the coalesced multithreaded
+            # tier: per-page seeks against an object store are latency
+            # death (the reference routes cloud paths to the
+            # MULTITHREADED reader, GpuParquetScan.scala:3134)
+            coalesce_ranges = True
+        pf = _open_parquet(path)
+        groups: List[int] = []
+        meta = pf.metadata
+        name_to_idx = {meta.schema.column(i).name: i
+                       for i in range(len(meta.schema))}
+        for rg in range(meta.num_row_groups):
+            row_group = meta.row_group(rg)
+            keep = True
+            if range_filters:
+                for cname, (lo, hi) in range_filters.items():
+                    ci = name_to_idx.get(cname)
+                    if ci is not None and not _stats_allow(
+                            row_group, ci, lo, hi):
+                        keep = False
+                        break
+            if keep:
+                groups.append(rg)
+        if not groups:
+            return
+        rows_per_batch = batch_size_rows
+        if batch_size_bytes > 0 and meta.num_rows:
+            total_bytes = sum(meta.row_group(rg).total_byte_size
+                              for rg in range(meta.num_row_groups))
+            bytes_per_row = max(total_bytes / max(meta.num_rows, 1), 1.0)
+            rows_per_batch = max(min(
+                batch_size_rows, int(batch_size_bytes / bytes_per_row)), 1)
+        if coalesce_ranges:
+            from spark_rapids_tpu.io.rangeio import open_coalesced_parquet
+            src, _ = open_coalesced_parquet(path, groups, columns)
+            pf = pq.ParquetFile(src)
+        # LEGACY-calendar files (org.apache.spark.legacyDateTime footer
+        # tag) carry hybrid Julian dates/timestamps: rebase to proleptic
+        # Gregorian on the host path (datetimeRebaseUtils.scala:53-58;
+        # VERDICT r3 #4 — without this, pre-1582 values are silently wrong)
+        from spark_rapids_tpu.io.rebase import (needs_rebase,
+                                                rebase_arrow_table)
+        legacy = needs_rebase(meta)
     for record_batch in pf.iter_batches(batch_size=rows_per_batch,
                                         row_groups=groups,
                                         columns=list(columns) if columns else None):

@@ -73,18 +73,22 @@ def prefetched(host_iter_fn: Callable[[], Iterator], num_threads: int,
 
     def produce():
         try:
-            with trace_range("scan.decode",
-                             "host-side file decode on the reader pool "
-                             "(no device semaphore held)"):
-                for item in host_iter_fn():
-                    while not _stop():
-                        try:
-                            q.put(item, timeout=0.2)
-                            break
-                        except queue.Full:
-                            continue
-                    if _stop():
-                        return
+            it = iter(host_iter_fn())
+            while True:
+                # one chunk's decode; the wait on a full queue below is
+                # the consumer's pace, not decode time
+                with trace_range("scan.decode"):
+                    item = next(it, _SENTINEL)
+                if item is _SENTINEL:
+                    break
+                while not _stop():
+                    try:
+                        q.put(item, timeout=0.2)
+                        break
+                    except queue.Full:
+                        continue
+                if _stop():
+                    return
         except BaseException as e:   # noqa: BLE001 — relayed to consumer
             while not _stop():
                 try:

@@ -94,6 +94,7 @@ class GroupedLayout:
     boundary: jax.Array          # bool [capacity], True at first row of group
 
 
+@jax.named_scope("group_rows")
 def group_rows(
     batch: ColumnarBatch,
     key_cols: Sequence[int],

@@ -24,6 +24,7 @@ from spark_rapids_tpu.kernels import strings as strkern
 from spark_rapids_tpu.kernels.selection import gather_batch
 
 
+@jax.named_scope("hash_partition")
 def hash_partition(
     batch: ColumnarBatch,
     key_cols: Sequence[int],

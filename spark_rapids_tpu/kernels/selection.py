@@ -62,6 +62,7 @@ class OverflowStatus:
         return False
 
 
+@jax.named_scope("compaction_map")
 def compaction_map(mask: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """Build a gather map packing rows where ``mask`` is True to the front.
 
@@ -188,6 +189,7 @@ def gather_column(
     return DeviceColumn(data, validity, col.dtype, new_offsets)
 
 
+@jax.named_scope("gather_batch")
 def gather_batch(
     batch: ColumnarBatch,
     indices: jax.Array,

@@ -197,6 +197,7 @@ def _string_hash_key(col: DeviceColumn, max_bytes: int) -> jax.Array:
     return jnp.where(col.validity, h, jnp.uint64(0))
 
 
+@jax.named_scope("sort_indices")
 def sort_indices(
     batch: ColumnarBatch,
     key_cols: Sequence[int],

@@ -33,9 +33,10 @@ class TpuEngine:
         from spark_rapids_tpu.utils.cancel import (
             QueryCancelled, cancel_scope, current_cancel_token)
         from spark_rapids_tpu.utils.obs import (
-            current_query_trace, trace_scope)
+            current_query_trace, current_span_id, trace_scope)
         from spark_rapids_tpu.utils.sanitizer import (hot_section,
                                                       query_scope)
+        from spark_rapids_tpu.utils.tracing import trace_range
         tenant = TENANTS.current()
         priority = current_task_priority()
         token = current_cancel_token()
@@ -43,6 +44,7 @@ class TpuEngine:
         # task thread's counter deltas and trace ranges must attribute
         # to the submitting query (utils/obs.py)
         trace = current_query_trace()
+        parent_span = current_span_id()
 
         def run_one(p: int) -> List[ColumnarBatch]:
             from spark_rapids_tpu.memory.task_completion import task_scope
@@ -55,7 +57,8 @@ class TpuEngine:
                 sem.acquire_if_necessary(priority)
                 try:
                     with TENANTS.scope(tenant), cancel_scope(token), \
-                            trace_scope(trace), task_scope():
+                            trace_scope(trace, parent_span), \
+                            task_scope():
                         try:
                             out: List[ColumnarBatch] = []
                             # sanitizer hot section: a task's batch loop
@@ -93,8 +96,11 @@ class TpuEngine:
                 with ThreadPoolExecutor(max_workers=threads) as pool:
                     return list(pool.map(run_one, range(nparts)))
             finally:
-                self.last_metrics = self._metrics_report(plan)
-                plan.cleanup()
+                # the report resolves every lazily kept device scalar (a
+                # row count per batch per exec) with a transfer of its own
+                with trace_range("query.finish"):
+                    self.last_metrics = self._metrics_report(plan)
+                    plan.cleanup()
 
     def _metrics_report(self, plan: TpuExec):
         """Per-exec metric snapshots at the configured verbosity
@@ -104,8 +110,11 @@ class TpuEngine:
 
     def collect(self, plan: TpuExec) -> List[tuple]:
         from spark_rapids_tpu.plan.cpu_engine import CpuTable
+        from spark_rapids_tpu.utils.tracing import trace_range
         rows: List[tuple] = []
-        for part in self.execute(plan):
-            for batch in part:
-                rows.extend(CpuTable.from_batch(batch).rows())
+        parts = self.execute(plan)
+        with trace_range("query.fetch"):
+            for part in parts:
+                for batch in part:
+                    rows.extend(CpuTable.from_batch(batch).rows())
         return rows

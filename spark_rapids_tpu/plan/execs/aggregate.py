@@ -831,12 +831,15 @@ class TpuHashAggregateExec(TpuExec):
         bucket_exprs = tuple(spec.group_exprs) + spec.string_order_exprs
         self._jit_partial = lambda b, _k=key: shared_jit(
             f"{_k}|partial|{(bkt := string_key_bucket(b, bucket_exprs))}",
-            lambda: _partial(spec._partial_step, string_bucket=bkt))(b)
+            lambda: _partial(spec._partial_step, string_bucket=bkt),
+            kind="agg_partial")(b)
         self._jit_merge = lambda b, _k=key: shared_jit(
             f"{_k}|merge|{(bkt := spec._merge_bucket(b))}",
-            lambda: _partial(spec._merge_step, string_bucket=bkt))(b)
+            lambda: _partial(spec._merge_step, string_bucket=bkt),
+            kind="agg_merge")(b)
         self._jit_finalize = lambda b, _k=key: shared_jit(
-            f"{_k}|finalize", lambda: spec._finalize)(b)
+            f"{_k}|finalize", lambda: spec._finalize,
+            kind="agg_finalize")(b)
 
         # in-core reduce path as ONE program: concat + merge + finalize.
         # The per-op path pays three launches per reduce partition (what
@@ -875,7 +878,8 @@ class TpuHashAggregateExec(TpuExec):
 
         self._jit_combine = lambda ps, _k=key: shared_jit(
             f"{_k}|combine|{len(ps)}|{(bkt := _combine_bucket(ps))}",
-            lambda: _partial(combine, string_bucket=bkt))(tuple(ps))
+            lambda: _partial(combine, string_bucket=bkt),
+            kind="agg_combine")(tuple(ps))
 
     # -- host-side orchestration -------------------------------------------
 

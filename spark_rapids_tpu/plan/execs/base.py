@@ -17,10 +17,13 @@ recompiles stay bounded while batch sizes vary.
 from __future__ import annotations
 
 import collections
+import functools
+import hashlib
 import time
 from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
 from spark_rapids_tpu.columnar.batch import ColumnarBatch, Schema
+from spark_rapids_tpu.utils.tracing import trace_range
 
 
 # -- cross-query jit sharing --------------------------------------------------
@@ -38,19 +41,15 @@ _JIT_CACHE_LOCK = __import__("threading").Lock()
 
 class _LaunchStats:
     """Process-wide program-launch accounting: how many XLA programs a
-    query dispatches.  Every launch is a host dispatch; what one costs on
-    a directly attached chip is not measured, so the count is a count and
-    not yet a ranking.  Counts every shared_jit dispatch;
+    query dispatches, and how many of each.  Every launch is a host
+    dispatch; what one costs on the device is read from the profiler's
+    trace, where each program appears under the same name as here.
+    Counts every shared_jit dispatch, never blocks;
     reset/read from bench.py around each timed run.  Lock-guarded: tasks
     dispatch from a thread pool and `+=` is not atomic bytecode."""
     lock = __import__("threading").Lock()
     count = 0
-    unique = set()      # distinct program keys dispatched since reset
-    #: per-program attribution mode (bench.py --profile): program key ->
-    #: [launches, blocked wall ns, output row capacity].  None = off (the
-    #: default — attribution BLOCKS on each dispatch to charge execution
-    #: to the program that ran it, so it must never time a real run).
-    profile = None
+    by_program: Dict[str, int] = {}     # program name -> launches since reset
 
 
 #: runtime-sanitizer compile-budget seam (utils/sanitizer.py): called
@@ -67,77 +66,62 @@ def set_compile_hook(fn) -> None:
 def reset_launch_stats() -> None:
     with _LaunchStats.lock:
         _LaunchStats.count = 0
-        _LaunchStats.unique = set()
+        _LaunchStats.by_program = {}
 
 
 def launch_stats() -> dict:
+    """``by_program``: launches per program NAME — the name the jitted
+    function carries (``program_name``), so the same string names the
+    program in the device trace's ``XLA Modules`` line."""
     with _LaunchStats.lock:
         return {"launches": _LaunchStats.count,
-                "programs": len(_LaunchStats.unique)}
+                "programs": len(_LaunchStats.by_program),
+                "by_program": dict(_LaunchStats.by_program)}
 
 
-def enable_launch_profile() -> None:
-    """Arm per-program wall-clock/rows attribution: every shared_jit
-    dispatch is timed THROUGH block_until_ready (async dispatch would
-    otherwise bill a program's execution to whoever syncs next) and its
-    output batch capacities recorded.  Profile runs are SEPARATE from
-    timed runs — blocking serializes the dispatch pipeline."""
-    with _LaunchStats.lock:
-        _LaunchStats.profile = {}
+def program_name(kind: str, key: str) -> str:
+    """``<kind>_<8 hex digits of a digest of the cache key>``: what a
+    shared_jit program is called in the device trace and in
+    ``launch_stats()["by_program"]``.  A ``hashlib`` digest and not
+    ``hash()``, which is salted per process: the name is part of the
+    persistent compile cache's key (JAX hashes the module, and the module
+    is named after the function), so a name that changed between
+    processes would make every run compile cold."""
+    digest = hashlib.blake2s(key.encode("utf-8"), digest_size=4)
+    return f"{kind}_{digest.hexdigest()}"
 
 
-def disable_launch_profile() -> dict:
-    """Disarm attribution and return {key: {launches, ns, rows}}."""
-    with _LaunchStats.lock:
-        prof = _LaunchStats.profile or {}
-        _LaunchStats.profile = None
-    return {k: {"launches": v[0], "ns": v[1], "rows": v[2]}
-            for k, v in prof.items()}
-
-
-def _out_row_capacity(out) -> int:
-    """Static output row capacity summed over every ColumnarBatch in a
-    program result pytree (capacity is static — no device sync)."""
-    if isinstance(out, ColumnarBatch):
-        return out.capacity
-    if isinstance(out, (tuple, list)):
-        return sum(_out_row_capacity(x) for x in out)
-    if isinstance(out, dict):
-        return sum(_out_row_capacity(x) for x in out.values())
-    return 0
-
-
-def _counted(key: str, fn):
+def _counted(name: str, fn):
     def wrapper(*a, **k):
         with _LaunchStats.lock:
             _LaunchStats.count += 1
-            _LaunchStats.unique.add(key)
-            profiling = _LaunchStats.profile is not None
-        if not profiling:
-            return fn(*a, **k)
-        t0 = time.perf_counter_ns()
-        out = fn(*a, **k)
-        import jax
-        # tpu-lint: allow-host-sync(attribution mode only: armed by enable_launch_profile for a dedicated profile run, never a timed one)
-        jax.block_until_ready(out)
-        ns = time.perf_counter_ns() - t0
-        rows = _out_row_capacity(out)
-        with _LaunchStats.lock:
-            if _LaunchStats.profile is not None:
-                ent = _LaunchStats.profile.setdefault(key, [0, 0, 0])
-                ent[0] += 1
-                ent[1] += ns
-                ent[2] += rows
-        return out
+            _LaunchStats.by_program[name] = \
+                _LaunchStats.by_program.get(name, 0) + 1
+        return fn(*a, **k)
     wrapper.__wrapped__ = fn
     return wrapper
 
 
-def shared_jit(key: str, make_fn: Callable[[], Callable], **jit_kwargs):
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` under ``name``, for ``jax.jit`` to call the program after.
+    A wrapper and not ``fn.__name__ = name``: ``make_fn`` may hand back a
+    module-level function or a ``partial`` that other keys share."""
+    @functools.wraps(fn)
+    def named(*a, **k):
+        return fn(*a, **k)
+    named.__name__ = named.__qualname__ = name
+    return named
+
+
+def shared_jit(key: str, make_fn: Callable[[], Callable], *, kind: str,
+               **jit_kwargs):
     """Return a jitted function shared by all execs with the same plan key.
 
     ``make_fn`` is only called on a cache miss; the key must fully determine
     the computation (expression tree incl. dtypes, schemas, static params).
+    ``kind`` is a short identifier of what the program does
+    (``agg_partial``, ``sort_local``, ...): with a digest of the key it
+    names the program in the device trace (``program_name``).
 
     CONTRACT: the function ``make_fn`` returns must NOT close over an exec
     instance (``self``) — cached entries outlive queries, and an exec pins
@@ -160,7 +144,9 @@ def shared_jit(key: str, make_fn: Callable[[], Callable], **jit_kwargs):
         _COMPILE_HOOK(key)   # may raise: compile budget exceeded
     # a REAL XLA RESOURCE_EXHAUSTED from any cached program enters the
     # retry/spill machinery as TpuRetryOOM (DeviceMemoryEventHandler analog)
-    made = _counted(key, translate_device_oom(jax.jit(make_fn(), **jit_kwargs)))
+    name = program_name(kind, key)
+    made = _counted(name, translate_device_oom(
+        jax.jit(_named(make_fn(), name), **jit_kwargs)))
     with _JIT_CACHE_LOCK:
         fn = _JIT_CACHE.setdefault(key, made)   # racer may have won; reuse
         _JIT_CACHE.move_to_end(key)
@@ -328,17 +314,24 @@ class TpuExec:
 
 
 class timed:
-    """Context manager adding wall time to a metric (NvtxWithMetrics analog)."""
+    """Context manager adding wall time to a metric and, given a
+    registered span name, recording the same interval as a trace range
+    (NvtxWithMetrics analog: the range paired with the metric)."""
 
-    def __init__(self, metric: Metric):
+    def __init__(self, metric: Metric, span: Optional[str] = None):
         self.metric = metric
+        self.span = trace_range(span) if span is not None else None
 
     def __enter__(self):
+        if self.span is not None:
+            self.span.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         self.metric.add(time.perf_counter_ns() - self.t0)
+        if self.span is not None:
+            self.span.__exit__(*exc)
         return False
 
 
@@ -435,11 +428,12 @@ def regex_bucket(batch, exprs) -> int:
     return SK.bucket_for(m)
 
 
-def jit_bucketed_step(key: str, exprs, make_call):
+def jit_bucketed_step(key: str, exprs, make_call, *, kind: str):
     """Shared project/filter wiring: collect trace consts once, then per
     batch compute the static regex bucket, key the shared_jit cache on it,
     and invoke with (batch, consts).  ``make_call(string_bucket)`` returns
-    the traceable fn(batch, consts)."""
+    the traceable fn(batch, consts); ``kind`` names the program
+    (shared_jit)."""
     import jax.numpy as _jnp
     from spark_rapids_tpu.expressions.bridge import tree_has_bridge
     exprs = tuple(exprs)
@@ -454,7 +448,7 @@ def jit_bucketed_step(key: str, exprs, make_call):
 
     def call(batch):
         bkt = regex_bucket(batch, exprs)
-        fn = shared_jit(f"{key}|{bkt}", lambda: make_call(bkt))
+        fn = shared_jit(f"{key}|{bkt}", lambda: make_call(bkt), kind=kind)
         return fn(batch, consts)
     return call
 

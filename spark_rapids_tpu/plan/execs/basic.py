@@ -50,7 +50,8 @@ class TpuProjectExec(TpuExec):
         key = (f"project|{schema_cache_key(child.schema)}|"
                f"{exprs_cache_key(self.exprs)}")
         self._run = jit_bucketed_step(
-            key, self.exprs, lambda bkt: _p(run, string_bucket=bkt))
+            key, self.exprs, lambda bkt: _p(run, string_bucket=bkt),
+            kind="project")
 
     def execute_partition(self, idx: int) -> Iterator[ColumnarBatch]:
         for batch in self.children[0].execute_partition(idx):
@@ -87,7 +88,8 @@ class TpuFilterExec(TpuExec):
         key = (f"filter|{schema_cache_key(child.schema)}|"
                f"{expr_cache_key(condition)}")
         self._run = jit_bucketed_step(
-            key, [condition], lambda bkt: _p(run, string_bucket=bkt))
+            key, [condition], lambda bkt: _p(run, string_bucket=bkt),
+            kind="filter")
 
     def execute_partition(self, idx: int) -> Iterator[ColumnarBatch]:
         from spark_rapids_tpu.plan.execs.coalesce import maybe_shrink

@@ -31,7 +31,7 @@ def concat_batches_jit(batches: List[ColumnarBatch],
     key = (f"concat|{schema_cache_key(batches[0].schema)}|"
            f"{_shape_key(batches)}|{out_capacity}")
     fn = shared_jit(key, lambda: partial(
-        concat_batches_device, out_capacity=out_capacity))
+        concat_batches_device, out_capacity=out_capacity), kind="concat")
     out, _ = fn(batches)
     return out
 
@@ -92,7 +92,8 @@ def maybe_shrink(batch: ColumnarBatch,
                      if c.offsets is not None)
     key = (f"shrink|{schema_cache_key(batch.schema)}|{cap}|{bcaps}|"
            f"{target}|{out_bcaps}")
-    return shared_jit(key, lambda: shrink)(batch, host_scalar(n))
+    return shared_jit(key, lambda: shrink, kind="shrink")(
+        batch, host_scalar(n))
 
 
 def retry_over_spillable(handles, body):

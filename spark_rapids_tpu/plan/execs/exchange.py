@@ -31,6 +31,7 @@ from spark_rapids_tpu.kernels.selection import (
 )
 from spark_rapids_tpu.memory.retry import with_capacity_retry, with_retry_no_split
 from spark_rapids_tpu.plan.execs.base import TpuExec, string_key_bucket, timed
+from spark_rapids_tpu.utils.tracing import trace_range
 
 
 def _chain_none(it):
@@ -124,7 +125,8 @@ class TpuShuffleExchangeExec(TpuExec):
                f"{exprs_cache_key(self.keys)}")
         self._jit_slice = lambda b, rr, _k=key: shared_jit(
             f"{_k}|{(bkt := string_key_bucket(b, self.keys))}",
-            lambda: _p(slice_step, string_bucket=bkt))(b, rr)
+            lambda: _p(slice_step, string_bucket=bkt),
+            kind="exchange_slice")(b, rr)
 
     def num_partitions(self) -> int:
         return self.out_partitions
@@ -159,7 +161,7 @@ class TpuShuffleExchangeExec(TpuExec):
             for batch in child.execute_partition(in_part):
                 # keep the slice dispatch (the dominant map-side cost)
                 # inside opTime, as before the fused path
-                with timed(self.op_time):
+                with timed(self.op_time, "exchange.write"):
                     rr = host_scalar(ordinal % self.out_partitions)
                     reordered, counts = with_retry_no_split(
                         lambda: self._jit_slice(batch, rr))
@@ -184,15 +186,17 @@ class TpuShuffleExchangeExec(TpuExec):
         from."""
         from spark_rapids_tpu.plan.execs.out_of_core import slice_by_counts
         for reordered, counts in self._partitioned():
-            with timed(self.op_time):
+            with timed(self.op_time, "exchange.write"):
                 host_counts = np.asarray(counts)  # ONE sync per batch
                 pieces = slice_by_counts(reordered, host_counts,
                                          self.out_partitions,
                                          count_stat=True)
                 self._record_part_rows(host_counts)
-                for p, piece in enumerate(pieces):
-                    if piece is not None:
-                        yield p, piece
+            # yielded with the span CLOSED: the transport's write of each
+            # piece is a span of its own (_spanned_writes)
+            for p, piece in enumerate(pieces):
+                if piece is not None:
+                    yield p, piece
 
     def _range_views(self):
         """Range-view write path (CACHE_ONLY): (partition-reordered
@@ -202,7 +206,7 @@ class TpuShuffleExchangeExec(TpuExec):
         view that fused consumers slice inside their own program (the
         device twin of _range_stream's wire-range framing)."""
         for reordered, counts in self._partitioned():
-            with timed(self.op_time):
+            with timed(self.op_time, "exchange.write"):
                 host_counts = np.asarray(counts)  # ONE sync per batch
             self._record_part_rows(host_counts)
             yield reordered, host_counts
@@ -216,7 +220,7 @@ class TpuShuffleExchangeExec(TpuExec):
         + Kudo row-range serialization analog)."""
         from spark_rapids_tpu.shuffle.serializer import download_partitioned
         for reordered, counts in self._partitioned():
-            with timed(self.op_time):
+            with timed(self.op_time, "exchange.write"):
                 host_batch, host_counts = download_partitioned(
                     reordered, counts)
             self._record_part_rows(host_counts)
@@ -268,7 +272,8 @@ class TpuShuffleExchangeExec(TpuExec):
                     # device twin of the wire range path: one spillable
                     # backing per map batch, per-partition range views —
                     # zero slice/gather programs on the map side
-                    t.write_partitioned(self._range_views())
+                    t.write_partitioned(
+                        _spanned_writes(self._range_views()))
                 elif (t.supports_range_write and range_serialize_enabled()
                         and range_supported(self.schema)):
                     # tpu-lint: allow-lock-order(the materialize lock deliberately covers the ONE map-side download per epoch; concurrent readers must wait for exactly this result)
@@ -278,7 +283,7 @@ class TpuShuffleExchangeExec(TpuExec):
                             pipelined)
                         gen = pipelined(gen, nbytes, fetch_window_bytes(),
                                         name="exchange-map-range")
-                    t.write_batches(gen)
+                    t.write_batches(_spanned_writes(gen))
                 else:
                     gen = self._slices()
                     if pipe:
@@ -286,7 +291,7 @@ class TpuShuffleExchangeExec(TpuExec):
                             pipelined)
                         gen = pipelined(gen, nbytes, fetch_window_bytes(),
                                         name="exchange-map-slices")
-                    t.write(gen)
+                    t.write(_spanned_writes(gen))
                 self._transport = t
             return self._transport
 
@@ -306,7 +311,7 @@ class TpuShuffleExchangeExec(TpuExec):
         transport = self._materialize()
         it = iter(transport.read_pieces(idx, target_rows=self.target_rows))
         while True:
-            with timed(self.op_time):
+            with timed(self.op_time, "exchange.read"):
                 try:
                     piece = next(it)
                 except StopIteration:
@@ -328,11 +333,11 @@ class TpuShuffleExchangeExec(TpuExec):
         transport = self._materialize()
 
         def batches():
-            with timed(self.op_time):
+            with timed(self.op_time, "exchange.read"):
                 it = iter(transport.read_iter(
                     idx, target_rows=self.target_rows))
             while True:
-                with timed(self.op_time):
+                with timed(self.op_time, "exchange.read"):
                     try:
                         b = next(it)
                     except StopIteration:
@@ -348,7 +353,7 @@ class TpuShuffleExchangeExec(TpuExec):
                 continue
             if not group:          # empty partition: nothing to flush
                 continue
-            with timed(self.op_time):
+            with timed(self.op_time, "exchange.read"):
                 if len(group) == 1:
                     out = group[0]
                 else:
@@ -377,6 +382,16 @@ class TpuShuffleExchangeExec(TpuExec):
     def describe(self):
         keys = ", ".join(map(repr, self.keys))
         return f"TpuShuffleExchange[{self.out_partitions}, keys=[{keys}]]"
+
+
+def _spanned_writes(items):
+    """``items`` as handed to a transport's write: what the transport does
+    with each one is an ``exchange.write`` span.  The span is opened when
+    the transport has the item and closed when it asks for the next, so
+    producing the items (the child's compute) is outside it."""
+    for item in items:
+        with trace_range("exchange.write"):
+            yield item
 
 
 def _estimated_row_bytes(schema: Schema) -> int:

@@ -74,7 +74,8 @@ class TpuGenerateExec(TpuExec):
                 return out, OverflowStatus(count.astype(jnp.int64), req_bytes)
             if eager:   # CPU-bridged array input: host round-trip, no jit
                 return run
-            return shared_jit(f"{base_key}|{out_cap}|{byte_caps}", lambda: run)
+            return shared_jit(f"{base_key}|{out_cap}|{byte_caps}",
+                              lambda: run, kind="generate")
 
         def step(batch: ColumnarBatch):
             # initial output capacity: the element buffer bound (+rows for

@@ -194,19 +194,21 @@ class _JoinKernel:
 
         self._jitted_probe = lambda bucket, cand_type: shared_jit(
             f"{base_key}|probe|{bucket}|{cand_type}",
-            lambda: jitted_probe(bucket, cand_type))
+            lambda: jitted_probe(bucket, cand_type), kind="join_probe")
         if self.conditional:
             self._jitted_cond = (
                 lambda pair_cap, out_cap, byte_caps, bucket, path: shared_jit(
                     f"{base_key}|{pair_cap}|{out_cap}|{byte_caps}|{bucket}"
                     f"|{path}",
                     lambda: jitted_cond(pair_cap, out_cap, byte_caps,
-                                        bucket, path)))
+                                        bucket, path),
+                    kind="join_cond"))
         else:
             self._jitted_expand = (
                 lambda out_capacity, byte_caps, path: shared_jit(
                     f"{base_key}|expand|{out_capacity}|{byte_caps}|{path}",
-                    lambda: jitted_expand(out_capacity, byte_caps, path)))
+                    lambda: jitted_expand(out_capacity, byte_caps, path),
+                    kind="join_expand"))
 
     def _string_out_cols(self, l: ColumnarBatch, r: ColumnarBatch):
         """(output ordinal, nested path) -> source plane capacity for EVERY

@@ -55,7 +55,8 @@ class TpuExpandExec(TpuExec):
             key = (f"expand{pi}|{schema_cache_key(child.schema)}|"
                    f"{exprs_cache_key(proj)}")
             self._runs.append(jit_bucketed_step(
-                key, proj, lambda bkt, _r=run: _p(_r, string_bucket=bkt)))
+                key, proj, lambda bkt, _r=run: _p(_r, string_bucket=bkt),
+                kind="expand"))
 
     def execute_partition(self, idx: int) -> Iterator[ColumnarBatch]:
         for batch in self.children[0].execute_partition(idx):
@@ -107,7 +108,8 @@ class TpuRangeExec(TpuExec):
 
             def make(lo_=lo, emitted_=emitted, n_=n, cap_=cap):
                 fn = shared_jit(f"range|{cap_}",
-                                lambda: _partial(_range_kernel, cap=cap_))
+                                lambda: _partial(_range_kernel, cap=cap_),
+                                kind="range")
                 return fn(host_scalar(lo_ + emitted_ * step, np.int64),
                           host_scalar(step, np.int64), host_scalar(n_))
             with timed(self.op_time):
@@ -167,7 +169,8 @@ class TpuSampleExec(TpuExec):
             return gather_batch(batch, indices, count)
 
         key = f"sample|{fraction}|{seed}|{schema_cache_key(child.schema)}"
-        self._step = lambda b, p, o: shared_jit(key, lambda: step)(
+        self._step = lambda b, p, o: shared_jit(
+            key, lambda: step, kind="sample")(
             b, jnp.uint64(p), jnp.uint64(o))
 
     def execute_partition(self, idx: int) -> Iterator[ColumnarBatch]:

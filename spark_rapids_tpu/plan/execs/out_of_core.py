@@ -100,7 +100,7 @@ def slice_by_counts(
         key = (f"oocsliceall|{schema_cache_key(reordered.schema)}|"
                f"{reordered.capacity}|{bcaps}|{ucap}|{num_buckets}")
         _stat(1)
-        pieces = shared_jit(key, lambda: slice_all)(
+        pieces = shared_jit(key, lambda: slice_all, kind="ooc_slice_all")(
             reordered,
             jnp.asarray(offsets[:num_buckets].astype(np.int32)),
             jnp.asarray(host_counts.astype(np.int32)))
@@ -120,7 +120,7 @@ def slice_by_counts(
             return gather_batch(rb, idx, n, out_capacity=_cap)
         key = (f"oocslice|{schema_cache_key(reordered.schema)}|"
                f"{reordered.capacity}|{bcaps}|{cap}")
-        out.append(shared_jit(key, lambda: slice_piece)(
+        out.append(shared_jit(key, lambda: slice_piece, kind="ooc_slice")(
             reordered, host_scalar(int(offsets[p])), host_scalar(cnt)))
     return out
 
@@ -163,7 +163,8 @@ def sub_partition_spillable(
             f"subpart|{schema_cache_key(schema)}|{key_idx}|{num_buckets}"
             f"|{string_bucket}",
             lambda: _partition_step(schema, key_idx, num_buckets,
-                                    string_bucket))
+                                    string_bucket),
+            kind="ooc_subpartition")
         reordered, counts = with_retry_no_split(lambda: fn(batch))
         for p, piece in enumerate(slice_by_counts(reordered, counts,
                                                   num_buckets)):

@@ -74,7 +74,9 @@ def make_encoder(orders, schema: Schema):
     orders = tuple(orders)
     pk = _plan_key(orders, schema, 0)
     encode = _encode_fn(orders)
-    return lambda b: shared_jit(f"{pk}|encode|{b}", lambda: _p(encode, bucket=b))
+    return lambda b: shared_jit(f"{pk}|encode|{b}",
+                                lambda: _p(encode, bucket=b),
+                                kind="sort_encode")
 
 
 def make_router(orders, schema: Schema, n_out: int):
@@ -118,7 +120,8 @@ def make_router(orders, schema: Schema, n_out: int):
         bounds = jnp.asarray(
             np.array(boundaries, np.uint64).reshape(-1, n_keys))
         fn = shared_jit(f"{pk}|route|{bucket}|{bounds.shape}",
-                        lambda: _p(route, bucket=bucket))
+                        lambda: _p(route, bucket=bucket),
+                        kind="sort_route")
         return lambda b: fn(b, bounds)
 
     return routed
@@ -226,24 +229,28 @@ class TpuRangeSortExec(TpuExec):
             batches: List[ColumnarBatch] = []
             for p in range(child.num_partitions()):
                 batches.extend(child.execute_partition(p))
-            if not batches:
-                buckets = [[] for _ in range(self.out_partitions)]
-            elif sum(b.capacity for b in batches) <= self.small_sort_rows:
-                # small input: one local sort IS the global sort.  The
-                # sampling + routing machinery costs ~2 launches and a
-                # host sync per batch plus a per-partition sort — for a
-                # sub-batch-target input (the common post-aggregation
-                # shape) that is pure launch overhead on the TPU.  All
-                # rows land in partition 0; empty partitions follow, so
-                # partition-order concatenation is still the global order.
-                merged = with_retry_no_split(
-                    lambda: coalesce_to_one(batches))
-                buckets = [[make_spillable(merged)]] + \
-                    [[] for _ in range(self.out_partitions - 1)]
-            else:
-                buckets = range_bucket_spillable(
-                    iter(batches), self.orders, child.schema,
-                    self.out_partitions, batches)
+            # the child is drained: from here on the work is the sort's own
+            with timed(self.op_time, "sort.range"):
+                if not batches:
+                    buckets = [[] for _ in range(self.out_partitions)]
+                elif (sum(b.capacity for b in batches)
+                        <= self.small_sort_rows):
+                    # small input: one local sort IS the global sort.  The
+                    # sampling + routing machinery costs ~2 launches and a
+                    # host sync per batch plus a per-partition sort — for
+                    # a sub-batch-target input (the common
+                    # post-aggregation shape) that is pure launch overhead
+                    # on the TPU.  All rows land in partition 0; empty
+                    # partitions follow, so partition-order concatenation
+                    # is still the global order.
+                    merged = with_retry_no_split(
+                        lambda: coalesce_to_one(batches))
+                    buckets = [[make_spillable(merged)]] + \
+                        [[] for _ in range(self.out_partitions - 1)]
+                else:
+                    buckets = range_bucket_spillable(
+                        iter(batches), self.orders, child.schema,
+                        self.out_partitions, batches)
             self._buckets = buckets
             return buckets
 
@@ -258,7 +265,7 @@ class TpuRangeSortExec(TpuExec):
                 batches = transport.read(idx)
             if not batches:
                 return
-            with timed(self.op_time):
+            with timed(self.op_time, "sort.range"):
                 # coalesce INSIDE the retry body (discard-and-rerun on
                 # OOM instead of an unspillable closure capture)
                 out = with_retry_no_split(
@@ -270,7 +277,7 @@ class TpuRangeSortExec(TpuExec):
         handles = self._materialize()[idx]
         if not handles:
             return
-        with timed(self.op_time):
+        with timed(self.op_time, "sort.range"):
             # pin-balanced retry: each attempt re-materializes the
             # handles and unpins before it ends (see
             # coalesce.retry_over_spillable); handles close in cleanup()

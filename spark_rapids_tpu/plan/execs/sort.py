@@ -73,7 +73,7 @@ class TpuSortExec(TpuExec):
                f"{','.join(f'{o.ascending}:{o.nulls_first}' for _, o in self.orders)}")
         self._run = lambda b, _k=key: shared_jit(
             f"{_k}|{(bkt := string_key_bucket(b, [e for e, _ in self.orders]))}",
-            lambda: make_run(bkt))(b)
+            lambda: make_run(bkt), kind="sort_local")(b)
 
     def execute_partition(self, idx: int) -> Iterator[ColumnarBatch]:
         batches = list(self.children[0].execute_partition(idx))

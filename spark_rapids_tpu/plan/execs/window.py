@@ -376,7 +376,7 @@ class TpuWindowExec(TpuExec):
                f"{exprs_cache_key(self.window_exprs)}")
         self._run = lambda b, _k=key: shared_jit(
             f"{_k}|{(bkt := string_key_bucket(b, list(self.spec.partition_by) + [e for e, _ in self.spec.order_by]))}",
-            lambda: _p(dspec._step, string_bucket=bkt))(b)
+            lambda: _p(dspec._step, string_bucket=bkt), kind="window")(b)
 
     def execute_partition(self, idx: int) -> Iterator[ColumnarBatch]:
         batches = list(self.children[0].execute_partition(idx))
@@ -523,7 +523,7 @@ class TpuWindowExec(TpuExec):
                     cols, ngroups = with_retry_no_split(
                         lambda: shared_jit(
                             f"{base_key}|p1|{b.capacity}",
-                            lambda: step)(b))
+                            lambda: step, kind="window_ooc")(b))
             finally:
                 # a retry-exhausted OOM must not leave this batch's pin
                 # held — the handle would refuse to spill for the rest

@@ -58,6 +58,7 @@ from spark_rapids_tpu.plan.execs.base import (
     timed,
     tree_uses_string_bucket,
 )
+from spark_rapids_tpu.utils.tracing import trace_range
 
 
 # converged-capacity memory, keyed by segment signature (+ bucket): the
@@ -696,6 +697,12 @@ class TpuFusedSegmentExec(TpuExec):
                 p.unpin()
 
     def _run(self, stream, builds, slice_spec=None, chain=None, sig=None):
+        """One program call as one ``fused.batch`` span: everything the
+        host does for it (``stream`` arrives already pulled)."""
+        with trace_range("fused.batch"):
+            return self._converge(stream, builds, slice_spec, chain, sig)
+
+    def _converge(self, stream, builds, slice_spec, chain, sig):
         """Converge-and-execute one program call.
 
         ``stream`` is a single ColumnarBatch (per-batch path) or a LIST
@@ -774,10 +781,12 @@ class TpuFusedSegmentExec(TpuExec):
             build_key = f"{caps_key}|caps={sorted(caps.items())}"
             fn = shared_jit(build_key,
                             lambda: self._make(bucket, caps, slice_spec,
-                                               chain))
+                                               chain),
+                            kind=_program_kind(chain, slice_spec))
             out, counts, fb = invoke(fn)
-            # tpu-lint: allow-host-sync(overflow feedback must reach the host; one batched sync per attempt)
-            fetched, host_counts = jax.device_get((fb, counts))
+            with trace_range("fused.feedback"):
+                # tpu-lint: allow-host-sync(overflow feedback must reach the host; one batched sync per attempt)
+                fetched, host_counts = jax.device_get((fb, counts))
             observed = int(fetched.pop("__stream_bytes", 0))
             if observed or bucket:
                 need = SK.bucket_for(max(observed, self._build_bytes,
@@ -900,7 +909,7 @@ def _apply_build_chain(bc: List[TpuExec],
                 cur = _emit_one(op, 0, cur, (), {}, cmap, 0, {}, {})
             return cur
         return fn
-    return shared_jit(key, make)(merged, consts)
+    return shared_jit(key, make, kind="buildchain")(merged, consts)
 
 
 def _degrade_over_budget_group(group, extra_pieces=()):
@@ -1016,23 +1025,60 @@ def _make_program(chain: List[TpuExec], join_build_ix: Dict[int, int],
         from spark_rapids_tpu.kernels.partition import (
             hash_partition, round_robin_partition)
         from spark_rapids_tpu.plan.execs.exchange import append_key_columns
-        if not keys:
-            out, counts = round_robin_partition(cur, n_out)
+        with jax.named_scope("exchange_slice"):
+            if not keys:
+                out, counts = round_robin_partition(cur, n_out)
+                return out, counts, feedback
+            work, key_idx = append_key_columns(cur, keys)
+            reordered, counts = hash_partition(work, key_idx, n_out,
+                                               string_max_bytes=bucket)
+            out = ColumnarBatch(reordered.columns[:len(cur.schema)],
+                                reordered.num_rows, cur.schema)
             return out, counts, feedback
-        work, key_idx = append_key_columns(cur, keys)
-        reordered, counts = hash_partition(work, key_idx, n_out,
-                                           string_max_bytes=bucket)
-        out = ColumnarBatch(reordered.columns[:len(cur.schema)],
-                            reordered.num_rows, cur.schema)
-        return out, counts, feedback
 
     return fn
+
+
+def _node_kind(node) -> str:
+    """What one chain node is called in a program's name and in the scope
+    its operations carry in the device trace."""
+    from spark_rapids_tpu.plan.execs.aggregate import TpuHashAggregateExec
+    from spark_rapids_tpu.plan.execs.basic import (
+        TpuFilterExec, TpuProjectExec)
+    if isinstance(node, TpuProjectExec):
+        return "project"
+    if isinstance(node, TpuFilterExec):
+        return "filter"
+    if isinstance(node, TpuHashAggregateExec):
+        return "agg"
+    return "join"
+
+
+def _program_kind(chain, slice_spec) -> str:
+    """``fused_<chain kinds, top down>[_slice]``, repeats folded
+    (``fused_agg_project_filter``), cut to a readable length."""
+    kinds = [_node_kind(n) for n in chain]
+    folded = [k for i, k in enumerate(kinds) if i == 0 or k != kinds[i - 1]]
+    if slice_spec is not None:
+        folded.append("slice")
+    return ("fused_" + "_".join(folded))[:48]
 
 
 def _emit_one(node, pos: int, cur: ColumnarBatch, builds: tuple,
               join_build_ix: Dict[int, int], cmap, bucket: int,
               caps: Dict[str, int],
               feedback: Dict[str, jax.Array]) -> ColumnarBatch:
+    """One chain node's operations, under a scope that names the node's
+    kind in the device trace."""
+    with jax.named_scope(_node_kind(node)):
+        return _emit_node(node, pos, cur, builds, join_build_ix, cmap,
+                          bucket, caps, feedback)
+
+
+def _emit_node(node, pos: int, cur: ColumnarBatch, builds: tuple,
+               join_build_ix: Dict[int, int], cmap, bucket: int,
+               caps: Dict[str, int],
+               feedback: Dict[str, jax.Array]) -> ColumnarBatch:
     from spark_rapids_tpu.plan.execs.aggregate import TpuHashAggregateExec
     from spark_rapids_tpu.plan.execs.basic import (
         TpuFilterExec, TpuProjectExec)

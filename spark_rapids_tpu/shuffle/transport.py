@@ -299,7 +299,8 @@ def _slice_view(view: RangeView) -> ColumnarBatch:
                      if c.offsets is not None)
     key = (f"rvslice|{schema_cache_key(view.batch.schema)}|"
            f"{view.batch.capacity}|{bcaps}|{view.capacity}")
-    return shared_jit(key, lambda: _rv_slice_step)(view)
+    return shared_jit(key, lambda: _rv_slice_step,
+                      kind="range_view_slice")(view)
 
 
 def _rv_slice_step(view: RangeView) -> ColumnarBatch:

@@ -50,10 +50,11 @@ def set_ambient_hook(fn) -> None:
 class Ambients:
     """Immutable snapshot of the spawning thread's ambient context."""
 
-    __slots__ = ("tenant", "priority", "token", "covered", "trace")
+    __slots__ = ("tenant", "priority", "token", "covered", "trace",
+                 "parent_span")
 
     def __init__(self, tenant, priority: int, token, covered: bool,
-                 trace=None):
+                 trace=None, parent_span=None):
         self.tenant = tenant
         self.priority = priority
         self.token = token
@@ -62,6 +63,9 @@ class Ambients:
         #: worker's counter deltas and spans must attribute to the
         #: spawning query, or concurrent queries interleave again
         self.trace = trace
+        #: id of the span open on the spawning thread at capture: the
+        #: parent of the worker's outermost spans
+        self.parent_span = parent_span
 
     @classmethod
     def capture(cls, inherit_semaphore_cover: bool = True) -> "Ambients":
@@ -75,12 +79,14 @@ class Ambients:
             current_task_priority, tpu_semaphore)
         from spark_rapids_tpu.memory.tenant import TENANTS
         from spark_rapids_tpu.utils.cancel import current_cancel_token
-        from spark_rapids_tpu.utils.obs import current_query_trace
+        from spark_rapids_tpu.utils.obs import (current_query_trace,
+                                                current_span_id)
         covered = (inherit_semaphore_cover
                    and tpu_semaphore().held_count() > 0)
         return cls(TENANTS.current(), current_task_priority(),
                    current_cancel_token(), covered,
-                   trace=current_query_trace())
+                   trace=current_query_trace(),
+                   parent_span=current_span_id())
 
     @contextmanager
     def scope(self):
@@ -93,7 +99,8 @@ class Ambients:
         cover = (tpu_semaphore().borrowed_cover() if self.covered
                  else nullcontext())
         with TENANTS.scope(self.tenant), task_priority(self.priority), \
-                cancel_scope(self.token), trace_scope(self.trace), cover:
+                cancel_scope(self.token), \
+                trace_scope(self.trace, self.parent_span), cover:
             yield self
 
     def bind(self, fn: Callable) -> Callable:

@@ -39,6 +39,7 @@ append under the trace's lock (no device sync, no I/O).
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from contextlib import contextmanager
@@ -56,8 +57,22 @@ DEFAULT_MAX_SPANS = 4096
 #: would lose exactly the serving/driver/rank tracks it exists to show.
 ANCHOR_HEADROOM = 64
 
-_AMBIENT = threading.local()        # .trace: Optional[QueryTrace]
-_OPEN = threading.local()           # .stack: [(name, since_monotonic)]
+class _Ambient(threading.local):
+    """Class-level defaults: reading an attribute a thread never set
+    raises inside ``getattr`` before the default is returned, a
+    microsecond on every span and counter add."""
+    trace: Optional["QueryTrace"] = None
+    #: the span open on the thread that spawned this one
+    parent_span: Optional[int] = None
+
+
+class _Open(threading.local):
+    stack: Optional[list] = None    # [(name, since_monotonic, id)]
+
+
+_AMBIENT = _Ambient()
+_OPEN = _Open()
+_SPAN_IDS = itertools.count(1)      # next() is atomic under the GIL
 
 
 class QueryTrace:
@@ -93,8 +108,16 @@ class QueryTrace:
                     track: Optional[str] = None,
                     tags: Optional[dict] = None,
                     anchor: bool = False,
-                    thread: Optional[str] = None) -> None:
-        """``anchor=True`` marks a control-plane span the timeline's
+                    thread: Optional[str] = None,
+                    span_id: Optional[int] = None,
+                    parent: Optional[int] = None) -> None:
+        """``span_id``/``parent``: this span's process-unique id and the
+        id of the span that caused it — the innermost span open on the
+        recording thread or, for a worker thread's outermost span, the
+        one open on the thread that spawned it (``current_span_id``).
+        ``parent`` is None for a root.
+
+        ``anchor=True`` marks a control-plane span the timeline's
         STRUCTURE depends on (serving.submit, driver.query, a rank's
         executor.task): anchors may spend the ANCHOR_HEADROOM reserve
         past max_spans, so a query whose data-plane ranges filled the
@@ -104,6 +127,9 @@ class QueryTrace:
         span = {"name": name, "t0": t0, "t1": t1,
                 "track": track or self.default_track,
                 "thread": thread or threading.current_thread().name}
+        if span_id is not None:
+            span["id"] = span_id
+            span["parent"] = parent
         if tags:
             span["tags"] = dict(tags)
         cap = self.max_spans + (ANCHOR_HEADROOM if anchor else 0)
@@ -195,20 +221,23 @@ class QueryTrace:
 # -- the ambient ---------------------------------------------------------------
 
 def current_query_trace() -> Optional[QueryTrace]:
-    return getattr(_AMBIENT, "trace", None)
+    return _AMBIENT.trace
 
 
 @contextmanager
-def trace_scope(trace: Optional[QueryTrace]):
+def trace_scope(trace: Optional[QueryTrace],
+                parent_span: Optional[int] = None):
     """Make ``trace`` the thread's ambient query trace for the block —
     the exact shape of cancel_scope/tenant scope, and carried by
-    utils/ambient.py to every blessed worker spawn."""
-    prev = getattr(_AMBIENT, "trace", None)
-    _AMBIENT.trace = trace
+    utils/ambient.py to every blessed worker spawn.  ``parent_span`` is
+    what the spawning thread's ``current_span_id()`` was: the parent of
+    this thread's outermost spans."""
+    prev = (_AMBIENT.trace, _AMBIENT.parent_span)
+    _AMBIENT.trace, _AMBIENT.parent_span = trace, parent_span
     try:
         yield trace
     finally:
-        _AMBIENT.trace = prev
+        _AMBIENT.trace, _AMBIENT.parent_span = prev
 
 
 @contextmanager
@@ -236,15 +265,17 @@ def task_metrics_tee(trace: Optional[QueryTrace]):
 # -- open-span stack (the watchdog's "which query, where" source) --------------
 
 def _open_stack() -> list:
-    st = getattr(_OPEN, "stack", None)
+    st = _OPEN.stack
     if st is None:
-        st = []
-        _OPEN.stack = st
+        st = _OPEN.stack = []
     return st
 
 
-def push_open_span(name: str) -> None:
-    _open_stack().append((name, time.monotonic()))
+def push_open_span(name: str) -> int:
+    """Open a span on this thread; returns its process-unique id."""
+    span_id = next(_SPAN_IDS)
+    _open_stack().append((name, time.monotonic(), span_id))
+    return span_id
 
 
 def pop_open_span() -> None:
@@ -258,8 +289,16 @@ def innermost_open_span() -> Optional[Tuple[str, float]]:
     trace range, or None.  The stall watchdog captures this at
     begin_wait so a stall report names the wedged site's enclosing
     span, not just the wait primitive."""
-    st = getattr(_OPEN, "stack", None)
-    return st[-1] if st else None
+    st = _OPEN.stack
+    return st[-1][:2] if st else None
+
+
+def current_span_id() -> Optional[int]:
+    """Id of the span a span opened NOW on this thread descends from:
+    the innermost one open here, else the one that was open on the thread
+    that spawned this one (``trace_scope``'s ``parent_span``)."""
+    st = _OPEN.stack
+    return st[-1][2] if st else _AMBIENT.parent_span
 
 
 @contextmanager
@@ -274,7 +313,8 @@ def span(name: str, track: Optional[str] = None,
     for the spans the exported timeline's structure depends on (see
     QueryTrace.record_span)."""
     t0 = time.time()
-    push_open_span(name)
+    parent = current_span_id()
+    span_id = push_open_span(name)
     try:
         yield
     finally:
@@ -282,7 +322,7 @@ def span(name: str, track: Optional[str] = None,
         tr = current_query_trace()
         if tr is not None:
             tr.record_span(name, t0, time.time(), track=track, tags=tags,
-                           anchor=anchor)
+                           anchor=anchor, span_id=span_id, parent=parent)
 
 
 # -- plan instrumentation + metric trees (EXPLAIN ANALYZE machinery) -----------

@@ -13,21 +13,31 @@ Registered names + docs are dumped by tools/generate_docs.py.
 """
 from __future__ import annotations
 
-import contextlib
+import collections
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
+from spark_rapids_tpu.utils import obs
+
 _registry: Dict[str, str] = {}
 _lock = threading.Lock()
 
+#: spans the log keeps: the newest ones, so a process that leaves the log
+#: on cannot grow without bound (a traced slice of the benchmark records
+#: some thousands)
+SPAN_LOG_CAPACITY = 1 << 18
+
 
 class SpanLog:
-    """In-process span collector (enable() to start; snapshot() to read)."""
+    """In-process span collector (enable() to start; snapshot() to read):
+    a ring of the newest ``SPAN_LOG_CAPACITY`` spans on the
+    ``time.perf_counter`` clock."""
 
-    def __init__(self):
+    def __init__(self, capacity: int = SPAN_LOG_CAPACITY):
         self.enabled = False
-        self._spans: List[Tuple[str, float, float]] = []
+        self._spans: "collections.deque[Tuple[str, float, float]]" = \
+            collections.deque(maxlen=capacity)
         self._lock = threading.Lock()
 
     def record(self, name: str, t0: float, t1: float) -> None:
@@ -68,35 +78,52 @@ def registered_ranges() -> Dict[str, str]:
         return dict(_registry)
 
 
-@contextlib.contextmanager
-def trace_range(name: str, doc: Optional[str] = None):
+_TraceAnnotation = None     # jax.profiler's, imported at the first range
+
+
+class trace_range:
     """Named range: registers (once), annotates the XLA trace, logs a
     span — and records into the ambient per-query trace (utils/obs.py)
-    so a range that ran on behalf of a query lands on that query's
-    timeline, with the open-span stack maintained for the stall
-    watchdog's "which query, where" reports."""
-    from spark_rapids_tpu.utils import obs
-    if doc is not None and name not in _registry:
-        register_range(name, doc)
-    t0 = time.perf_counter()
-    t0_epoch = time.time()
-    obs.push_open_span(name)
-    try:
-        import jax.profiler
-        cm = jax.profiler.TraceAnnotation(name)
-    except Exception:
-        cm = contextlib.nullcontext()
-    try:
-        with cm:
-            yield
-    finally:
-        # record in finally (matching obs.span): a range a query FAILED
-        # or was cancelled inside is exactly the one its timeline needs
+    with the span that caused it as ``parent``, so a range that ran on
+    behalf of a query lands on that query's timeline, with the open-span
+    stack maintained for the stall watchdog's "which query, where"
+    reports.
+
+    A class and not a generator: a range with every sink off is two clock
+    reads, a push and a pop."""
+
+    __slots__ = ("name", "t0", "t0_epoch", "span_id", "parent", "_ann")
+
+    def __init__(self, name: str, doc: Optional[str] = None):
+        if doc is not None and name not in _registry:
+            register_range(name, doc)
+        self.name = name
+
+    def __enter__(self):
+        global _TraceAnnotation
+        if _TraceAnnotation is None:
+            from jax.profiler import TraceAnnotation
+            _TraceAnnotation = TraceAnnotation
+        self.t0 = time.perf_counter()
+        self.t0_epoch = time.time()
+        self.parent = obs.current_span_id()
+        self.span_id = obs.push_open_span(self.name)
+        self._ann = _TraceAnnotation(self.name)
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        # recorded on every exit (matching obs.span): a range a query
+        # FAILED or was cancelled inside is exactly the one its timeline
+        # needs
+        self._ann.__exit__(*exc)
         obs.pop_open_span()
-        span_log.record(name, t0, time.perf_counter())
+        span_log.record(self.name, self.t0, time.perf_counter())
         tr = obs.current_query_trace()
         if tr is not None:
-            tr.record_span(name, t0_epoch, time.time())
+            tr.record_span(self.name, self.t0_epoch, time.time(),
+                           span_id=self.span_id, parent=self.parent)
+        return False
 
 
 def generate_ranges_doc() -> str:
@@ -127,21 +154,50 @@ def static_ranges() -> Dict[str, str]:
 
 # -- static range registry -----------------------------------------------------
 #
-# Every span name used with trace_range() or obs.span() anywhere in the
-# package is registered HERE at import time, so docs/trace_ranges.md can
-# be generated deterministically (tools/generate_docs.py) and the
-# tpu-lint drift rule can byte-match it — the same docs-from-code
-# discipline configs.md pins.  Call sites may still pass doc= lazily,
+# Every span name used with trace_range(), timed(metric, name) or
+# obs.span() anywhere in the package is registered HERE at import time,
+# so docs/trace_ranges.md can be generated deterministically
+# (tools/generate_docs.py) and the tpu-lint drift rule can byte-match it —
+# the same docs-from-code discipline configs.md pins.  Call sites may still pass doc= lazily,
 # but the doc string must match this table (register_range raises on a
 # conflicting re-registration).
 _STATIC_RANGES = (
-    # io / scan (plan/execs/scan.py + io/reader_pool.py)
-    ("scan.decode", "host-side file decode on the reader pool "
-                    "(no device semaphore held)"),
+    # one collect() (api/session.py, plan/engine.py)
+    ("query.collect", "one collect(): plan, execute, fetch; the root "
+                      "every other span of the query descends from"),
+    ("query.plan", "logical plan -> physical plan: optimizer, tagging, "
+                   "CBO, conversion, fusion, LORE"),
+    ("query.finish", "after the last batch of execute(): the per-exec "
+                     "metric report (one device -> host transfer per "
+                     "lazily kept row count) and plan cleanup"),
+    ("query.fetch", "final device -> host transfer and row building, "
+                    "after execute() returned"),
+    # io / scan (plan/execs/scan.py + io/reader_pool.py + io/parquet.py)
+    ("scan.open", "file open on the reader pool up to the first row "
+                  "group being ready: footer parse, row-group pruning, "
+                  "coalesced open (inside the file's first scan.decode)"),
+    ("scan.decode", "host-side decode of ONE chunk on the reader pool "
+                    "(no device semaphore held; the wait on a full "
+                    "prefetch queue is outside it)"),
     ("scan.wait", "task waiting for a decoded chunk "
                   "(semaphore released)"),
     ("scan.upload", "Arrow host chunk -> HBM batch upload "
                     "(semaphore held)"),
+    # fused segments (plan/fused.py)
+    ("fused.batch", "host work for one fused-program call: key "
+                    "building, shared_jit lookup, dispatch, retry loop, "
+                    "feedback"),
+    ("fused.feedback", "task thread blocked on the device for the "
+                       "capacity feedback of one fused-program call"),
+    # exchange + range sort (plan/execs/exchange.py, range_sort.py)
+    ("exchange.write", "the exchange's own map-side work for one map "
+                       "batch: slice dispatch, counts sync or download, "
+                       "transport write (not the child's compute)"),
+    ("exchange.read", "reduce side: one pull from the transport's "
+                      "reader, or the coalescing concat"),
+    ("sort.range", "the ORDER BY's own work after its child is drained: "
+                   "coalesce or bound sampling and routing, and each "
+                   "partition's local sort dispatch"),
     # serving control plane (serving/admission.py; obs.span)
     ("serving.submit", "one serving submission end-to-end: cache "
                        "lookup, admission, execution"),

@@ -400,7 +400,7 @@ def test_dim_build_fold_gated_by_raw_build_size():
     in-trace fold (no such program).  Rows match per-op either way."""
     from spark_rapids_tpu.expressions import col as _col, lit as _lit
     from spark_rapids_tpu.plan.execs.base import (
-        disable_launch_profile, enable_launch_profile)
+        launch_stats, reset_launch_stats)
 
     def q(s, dim_rows):
         f = s.create_dataframe([_fact(seed=81, n=2000, null_frac=0.0)],
@@ -418,20 +418,17 @@ def test_dim_build_fold_gated_by_raw_build_size():
 
     def profiled_collect(s, dim_rows):
         df = q(s, dim_rows)
-        enable_launch_profile()
-        try:
-            rows = df.collect()
-        finally:
-            prof = disable_launch_profile()
-        return rows, prof
+        reset_launch_stats()
+        rows = df.collect()
+        return rows, launch_stats()["by_program"]
 
     # raw build 3000 rows (cap 4096) > 1024 target: eager one-shot chain
     rows_big, prof_big = profiled_collect(TpuSession(dict(conf)), 3000)
-    assert any(k.startswith("buildchain|") for k in prof_big), \
+    assert any(k.startswith("buildchain_") for k in prof_big), \
         sorted(prof_big)[:6]
     # raw build 600 rows (cap <= 1024): in-trace fold, no standalone run
     rows_small, prof_small = profiled_collect(TpuSession(dict(conf)), 600)
-    assert not any(k.startswith("buildchain|") for k in prof_small), \
+    assert not any(k.startswith("buildchain_") for k in prof_small), \
         sorted(k for k in prof_small if k.startswith("buildchain"))
     perop = TpuSession(dict(
         conf, **{"spark.rapids.sql.tpu.fuseStages": "false",

@@ -1,12 +1,15 @@
-"""Per-program wall-clock/rows attribution (plan/execs/base
-enable_launch_profile — the engine mode behind `bench.py --profile`).
+"""Launches by program name (plan/execs/base ``launch_stats()["by_program"]``).
 
-The profiler must (1) attribute execution to the program that ran it
-(dispatches block through block_until_ready while armed), (2) record
-launches and output row capacities per program key, (3) cost nothing
-when disarmed (the default), and (4) surface through bench.py as
-a `prog_profile` artifact entry.
+Every ``shared_jit`` program is jitted under a name, ``<kind>_<8 hex digits
+of a digest of its cache key>``, so the one string names it in the launch
+counts and in the profiler's trace; the trace then says what each program
+cost on the device, with nothing blocking a dispatch.  The names must (1)
+count every launch under the program that ran, (2) need no arming, (3)
+appear in a trace, and (4) say what the program does.
 """
+import glob
+import os
+
 import numpy as np
 
 from spark_rapids_tpu import types as T
@@ -14,11 +17,8 @@ from spark_rapids_tpu.api.session import TpuSession
 from spark_rapids_tpu.columnar.batch import ColumnarBatch, Schema
 from spark_rapids_tpu.expressions import count, sum_
 from spark_rapids_tpu.plan.execs.base import (
-    _LaunchStats,
-    _out_row_capacity,
-    disable_launch_profile,
-    enable_launch_profile,
     launch_stats,
+    program_name,
     reset_launch_stats,
 )
 
@@ -39,63 +39,82 @@ def _query(s):
             .order_by("k"))
 
 
-def test_attribution_records_launches_ns_and_rows():
+def test_launches_are_counted_by_program_name():
     s = TpuSession({"spark.rapids.sql.enabled": "true"})
     q = _query(s)
     q.collect()                      # warm: compile once
-    enable_launch_profile()
-    try:
-        rows = q.collect()
-    finally:
-        prof = disable_launch_profile()
-    assert rows
-    assert prof, "no programs attributed"
-    for k, v in prof.items():
-        assert v["launches"] >= 1, (k, v)
-        assert v["ns"] >= 0, (k, v)
-        assert v["rows"] >= 0, (k, v)
-    # the aggregate's program keys are attributable by name
-    assert any("agg" in k or "fused" in k for k in prof), list(prof)
-    # a second disable returns empty (armed state cleared)
-    assert disable_launch_profile() == {}
+    reset_launch_stats()
+    assert q.collect()
+    stats = launch_stats()
+    by = stats["by_program"]
+    assert by and sum(by.values()) == stats["launches"]
+    assert len(by) == stats["programs"]
+    # the aggregate's and the sort's programs are attributable by kind
+    assert any(n.startswith(("agg_", "fused_agg")) for n in by), list(by)
+    assert any(n.startswith("sort_") for n in by), list(by)
+    for name in by:
+        kind, _, digest = name.rpartition("_")
+        assert kind and len(digest) == 8 and int(digest, 16) >= 0, name
 
 
-def test_disarmed_by_default_and_counting_unaffected():
-    assert _LaunchStats.profile is None
+def test_counting_needs_no_arming_and_resets():
     s = TpuSession({"spark.rapids.sql.enabled": "true"})
     q = _query(s)
     q.collect()
     reset_launch_stats()
+    assert launch_stats() == {"launches": 0, "programs": 0,
+                              "by_program": {}}
     q.collect()
-    stats = launch_stats()
-    assert stats["launches"] >= 1 and stats["programs"] >= 1
-    assert _LaunchStats.profile is None
+    once = launch_stats()
+    q.collect()
+    twice = launch_stats()
+    assert once["launches"] >= 1
+    assert twice["by_program"] == {k: 2 * v
+                                   for k, v in once["by_program"].items()}
+    # a snapshot, not the live table
+    once["by_program"].clear()
+    assert launch_stats()["by_program"]
 
 
-def test_out_row_capacity_walks_result_pytrees():
-    b = _batch(64)
-    cap = b.capacity
-    assert _out_row_capacity(b) == cap
-    assert _out_row_capacity((b, b)) == 2 * cap
-    assert _out_row_capacity({"x": b, "y": (b, None)}) == 2 * cap
-    assert _out_row_capacity(None) == 0
-    assert _out_row_capacity(123) == 0
+def test_program_names_are_a_digest_of_the_key():
+    a = program_name("agg_partial", "agg|schema|exprs|partial|0")
+    assert a == program_name("agg_partial", "agg|schema|exprs|partial|0")
+    assert a != program_name("agg_partial", "agg|schema|exprs|partial|8")
+    assert a.startswith("agg_partial_") and len(a) == len("agg_partial_") + 8
 
 
-def test_bench_query_emits_prog_profile(monkeypatch):
-    """bench.py's --profile plumbing: with the env flag set, a query's
-    result carries a prog_profile list sorted by wall time, and names the
-    device it ran on."""
-    import bench
+def test_names_appear_in_a_trace_recorded_on_the_cpu_backend(tmp_path):
+    """What ``launch_stats`` calls a program is what the profiler calls its
+    module (``jit_<name>``): on the CPU backend the operation events carry
+    it in their ``hlo_module`` stat."""
+    import jax.profiler
+    s = TpuSession({"spark.rapids.sql.enabled": "true"})
+    q = _query(s)
+    q.collect()
+    reset_launch_stats()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        q.collect()
+    finally:
+        jax.profiler.stop_trace()
+    launched = set(launch_stats()["by_program"])
+    pb, = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                 "*.xplane.pb"))
+    modules = set()
+    for plane in jax.profiler.ProfileData.from_file(pb).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                modules.update(str(v) for k, v in ev.stats
+                               if k == "hlo_module")
+    assert modules, "the trace names no module"
+    assert not any(m.startswith("jit_fn") for m in modules), modules
+    assert {f"jit_{n}" for n in launched} <= modules, (launched, modules)
 
-    monkeypatch.setenv("SPARK_RAPIDS_TPU_BENCH_PROGPROF", "1")
-    monkeypatch.setenv("TPU_ORACLE_CACHE", "0")
-    out = bench._run_query("q6", 65536)
-    assert out["query"] == "q6"
-    assert out["device"]["platform"] == "cpu" and "engine_s" in out
-    prof = out.get("prog_profile")
-    assert prof, out.keys()
-    assert all({"program", "launches", "ns", "rows"} <= set(e)
-               for e in prof)
-    ns = [e["ns"] for e in prof]
-    assert ns == sorted(ns, reverse=True), "not sorted by wall time"
+
+def test_run_suites_still_names_this_file():
+    from tools.run_suites import SUITES
+    here = "tests/" + os.path.basename(__file__)
+    assert here in SUITES["profile"][0]
+    assert here in SUITES["observability"][0]

@@ -263,13 +263,16 @@ def test_compile_budget_catches_injected_recompile(san_on):
     from spark_rapids_tpu.plan.execs.base import shared_jit
     stamp = time.monotonic_ns()     # keys must MISS the cross-test cache
     with san.compile_budget_scope(1):
-        shared_jit(f"sanit-{stamp}-0", lambda: (lambda x: x + 1))
+        shared_jit(f"sanit-{stamp}-0", lambda: (lambda x: x + 1),
+                   kind="sanit")
         with pytest.raises(SanitizerError) as ei:
-            shared_jit(f"sanit-{stamp}-1", lambda: (lambda x: x + 2))
+            shared_jit(f"sanit-{stamp}-1", lambda: (lambda x: x + 2),
+                       kind="sanit")
     assert "compile budget" in str(ei.value)
     assert f"sanit-{stamp}-1" in str(ei.value)
     # outside the scope the process-wide budget (0 = unlimited) rules
-    shared_jit(f"sanit-{stamp}-2", lambda: (lambda x: x + 3))
+    shared_jit(f"sanit-{stamp}-2", lambda: (lambda x: x + 3),
+               kind="sanit")
 
 
 # -- off-path overhead --------------------------------------------------------

@@ -63,8 +63,8 @@ SUITES = {
     # units, shed/ratelimit/breaker, drain handshake, tier-1 mini-soak
     "elasticity": (["tests/test_autoscaler.py", "tests/test_overload.py",
                     "tests/test_load_soak.py"], 600),
-    # per-program attribution (bench.py --profile) + the CACHE_ONLY
-    # range-view store it was built to validate
+    # launches by program name (launch_stats()["by_program"], the names
+    # the device trace shows) + the CACHE_ONLY range-view store
     "profile": (["tests/test_prog_profile.py",
                  "tests/test_range_views.py"], 900),
     # observability: the query-scoped plane (trace context + counter

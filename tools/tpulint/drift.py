@@ -275,10 +275,11 @@ def _check_trace_ranges(repo_root: str,
       * docs/trace_ranges.md must byte-match
         ``tracing.generate_ranges_doc()`` over the statically registered
         table (same docs-from-code contract as configs.md);
-      * every LITERAL span name used with ``trace_range(...)`` or
-        ``obs.span(...)`` in the package must be registered — an
-        unregistered range is invisible to the generated doc and to
-        anyone navigating a Perfetto timeline.
+      * every LITERAL span name used with ``trace_range(...)``,
+        ``obs.span(...)`` or ``timed(metric, name)`` (the range paired
+        with an exec's metric, plan/execs/base.py) in the package must be
+        registered — an unregistered range is invisible to the generated
+        doc and to anyone navigating a Perfetto timeline.
     """
     import ast as _ast
 
@@ -326,13 +327,18 @@ def _check_trace_ranges(repo_root: str,
             name = (func.attr if isinstance(func, _ast.Attribute)
                     else func.id if isinstance(func, _ast.Name)
                     else "")
-            if name not in ("trace_range", "span"):
+            if name in ("trace_range", "span"):
+                arg = node.args[0] if node.args else None
+            elif name == "timed":
+                arg = (node.args[1] if len(node.args) > 1 else next(
+                    (k.value for k in node.keywords if k.arg == "span"),
+                    None))
+            else:
                 continue
-            if not node.args or not isinstance(
-                    node.args[0], _ast.Constant) or not isinstance(
-                    node.args[0].value, str):
+            if not isinstance(arg, _ast.Constant) or not isinstance(
+                    arg.value, str):
                 continue
-            rng = node.args[0].value
+            rng = arg.value
             if rng not in registered:
                 out.append(Violation(
                     RULE, relf, node.lineno, "<trace-ranges>",

@@ -13,7 +13,11 @@ Format (the JSON dialect Perfetto ingests natively):
     named via ``process_name`` metadata events;
   * every span is a complete "X" event (ts/dur in MICROSECONDS of epoch
     time; spans from different processes align because QueryTrace
-    records epoch timestamps);
+    records epoch timestamps); a span that names its ``parent`` carries
+    ``span_id``/``parent`` in ``args``.  On one thread a child nests
+    under its parent by time; a child on ANOTHER thread (a task thread
+    under ``query.collect``, a decode under the ``scan.wait`` that
+    started it) is tied to its parent by a flow arrow;
   * the query's attributed counter snapshot rides as ``args`` on a
     process-wide summary event, so the numbers travel with the
     timeline.
@@ -69,6 +73,7 @@ def trace_events(trace_or_snapshot) -> List[dict]:
                        "args": {"name": f"{track} (query {qid})"}})
     #: thread ids per (track, thread name), stable within the export
     tids: Dict[tuple, int] = {}
+    placed: Dict[int, dict] = {}    # span id -> its "X" event
     for s in spans:
         track = s.get("track") or "local"
         pid = pids[track]
@@ -85,7 +90,12 @@ def trace_events(trace_or_snapshot) -> List[dict]:
               "dur": max((s["t1"] - s["t0"]) * 1e6, 1.0)}
         if s.get("tags"):
             ev["args"] = dict(s["tags"])
+        if s.get("id") is not None:
+            ev.setdefault("args", {}).update(
+                span_id=s["id"], parent=s.get("parent"))
+            placed[s["id"]] = ev
         events.append(ev)
+    events.extend(_flow_arrows(placed))
     # the per-query counter attribution travels with the timeline
     counters = {k: v for k, v in (snap.get("counters") or {}).items()
                 if v}
@@ -101,6 +111,25 @@ def trace_events(trace_or_snapshot) -> List[dict]:
             "args": {"counters": counters,
                      "dropped_spans": snap.get("dropped_spans", 0)}})
     return events
+
+
+def _flow_arrows(placed: Dict[int, dict]) -> List[dict]:
+    """A flow arrow from each parent to each child that ran on another
+    thread, bound to the slices that enclose its two ends."""
+    arrows: List[dict] = []
+    for sid, child in placed.items():
+        parent = placed.get(child["args"].get("parent"))
+        if parent is None or (parent["pid"], parent["tid"]) == (
+                child["pid"], child["tid"]):
+            continue
+        start = min(max(child["ts"], parent["ts"]),
+                    parent["ts"] + parent["dur"])
+        common = {"name": "spawn", "cat": "parent", "id": sid}
+        arrows.append({**common, "ph": "s", "pid": parent["pid"],
+                       "tid": parent["tid"], "ts": start})
+        arrows.append({**common, "ph": "f", "bp": "e", "pid": child["pid"],
+                       "tid": child["tid"], "ts": child["ts"]})
+    return arrows
 
 
 def export_trace(trace_or_snapshot, path: str) -> str:

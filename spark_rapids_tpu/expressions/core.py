@@ -108,6 +108,11 @@ class Expression:
     """Base class.  Subclasses are immutable; identity is structural."""
 
     children: Tuple["Expression", ...] = ()
+    #: a row's value depends on that row's inputs alone: not on its
+    #: position in the batch, the row count or another row.  A class that
+    #: reads any of those sets it False (plan/fused.py then compacts a
+    #: filter's rows before such an expression sees them).
+    row_local = True
 
     @property
     def dtype(self) -> T.DataType:

@@ -72,6 +72,7 @@ class WindowFunction(Expression):
     """Ranking / shift functions that only exist inside a window."""
 
     name = "winfn"
+    row_local = False
 
     def __repr__(self):
         return f"{self.name}()"
@@ -149,6 +150,8 @@ class Lag(Lead):
 
 
 class WindowExpression(Expression):
+    row_local = False
+
     def __init__(self, function: Expression, spec: WindowSpec):
         assert isinstance(function, (WindowFunction, AggregateFunction)), \
             f"not a window-capable function: {function!r}"

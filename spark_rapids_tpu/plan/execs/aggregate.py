@@ -330,6 +330,17 @@ def _global_m2_merge(m2col: DeviceColumn, scol: DeviceColumn,
     return m2, n > 0
 
 
+# update ops whose keyless form (the nkeys == 0 branch of _partial_step)
+# reads ``live`` as a boolean mask and nowhere as "the first num_rows rows":
+# each masks values to an identity, counts the mask, or takes the first/last
+# set position of it, so rows dropped in the middle of a batch read like
+# padding at its end.  An op not listed here keeps the filter's compaction.
+_MASK_UPDATE_OPS = frozenset(
+    (COUNT_STAR, COUNT_VALID, SUM, MIN, MAX, M2, SUM128, MIN128, MAX128,
+     HLL_UPDATE, COLLECT, TD_MEANS, TD_WEIGHTS, MAXBY_VAL, MINBY_VAL)
+    + PICK_OPS + BIT_OPS)
+
+
 class _AggDeviceSpec:
     """The aggregate's device-step parameters + pure step functions,
     detached from the exec so shared_jit-cached steps never pin the exec
@@ -431,9 +442,21 @@ class _AggDeviceSpec:
             return 0
         return SK.bucket_for(SK.max_live_bytes_multi(pairs))
 
-    def _partial_step(self, batch: ColumnarBatch,
-                      string_bucket: int = 0) -> ColumnarBatch:
-        """Raw rows -> one partial batch (keys + buffers), grouped in-batch."""
+    def reduces_under_mask(self) -> bool:
+        """True when ``_partial_step`` reads the live rows purely as a
+        boolean mask: no keys, and every slot's update op is one of
+        ``_MASK_UPDATE_OPS``.  A fused filter below such an aggregate hands
+        over its mask instead of compacting (plan/fused.py)."""
+        return not self.group_exprs and all(
+            slot.update_op in _MASK_UPDATE_OPS
+            for _, slot in self.slot_specs)
+
+    def _partial_step(self, batch: ColumnarBatch, string_bucket: int = 0,
+                      live: Optional[jax.Array] = None) -> ColumnarBatch:
+        """Raw rows -> one partial batch (keys + buffers), grouped in-batch.
+
+        ``live``: the rows that count, where they are not the prefix
+        ``batch.live_mask()`` (keyless only: ``reduces_under_mask``)."""
         ctx = EvalContext(batch)
         key_cols = tuple(e.eval(ctx) for e in self.group_exprs)
         agg_in = {}
@@ -444,7 +467,8 @@ class _AggDeviceSpec:
         nkeys = len(key_cols)
 
         if nkeys == 0:
-            live = batch.live_mask()
+            if live is None:
+                live = batch.live_mask()
             cols = []
             for ai, slot in self.slot_specs:
                 agg = self.aggregates[ai]
@@ -503,6 +527,7 @@ class _AggDeviceSpec:
             return ColumnarBatch(tuple(cols), host_scalar(1), self.partial_schema)
 
         # grouped: pack keys + inputs into a work batch, sort-group, reduce
+        assert live is None, "the grouped step sorts a prefix of live rows"
         work_cols = list(key_cols)
         col_of_agg = {}
         for agg in self.aggregates:

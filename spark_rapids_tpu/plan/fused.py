@@ -770,6 +770,7 @@ class TpuFusedSegmentExec(TpuExec):
 
         caps_key = None
         caps: Dict[str, int] = {}
+        kind = _program_kind(chain, slice_spec)
         for _ in range(24):
             new_key = f"{sig}|bkt={bucket}"
             if new_key != caps_key:      # first pass, or bucket escalated
@@ -782,7 +783,7 @@ class TpuFusedSegmentExec(TpuExec):
             fn = shared_jit(build_key,
                             lambda: self._make(bucket, caps, slice_spec,
                                                chain),
-                            kind=_program_kind(chain, slice_spec))
+                            kind=kind)
             out, counts, fb = invoke(fn)
             with trace_range("fused.feedback"):
                 # tpu-lint: allow-host-sync(overflow feedback must reach the host; one batched sync per attempt)
@@ -906,7 +907,7 @@ def _apply_build_chain(bc: List[TpuExec],
             cmap = bind_trace_consts(exprs, consts_)
             cur = batch
             for op in reversed(bc):   # bottom-up, like the fused chain
-                cur = _emit_one(op, 0, cur, (), {}, cmap, 0, {}, {})
+                cur, _ = _emit_one(op, 0, cur, (), {}, cmap, 0, {}, {})
             return cur
         return fn
     return shared_jit(key, make, kind="buildchain")(merged, consts)
@@ -976,6 +977,8 @@ def _make_program(chain: List[TpuExec], join_build_ix: Dict[int, int],
     are observed on the RAW build (a superset: the admitted ops never
     grow strings)."""
 
+    masked = _masked_filters(chain)
+
     def fn(stream, builds: tuple, consts: tuple):
         from spark_rapids_tpu.kernels.strings import max_live_string_bytes
         cmap = bind_trace_consts(exprs, consts)
@@ -1011,14 +1014,15 @@ def _make_program(chain: List[TpuExec], join_build_ix: Dict[int, int],
                 bc = build_chains[bi] if bi < len(build_chains) else []
                 cur_b = bl[bi]
                 for op in reversed(bc):
-                    cur_b = _emit_one(op, 0, cur_b, (), {}, cmap, bucket,
-                                      caps, feedback)
+                    cur_b, _ = _emit_one(op, 0, cur_b, (), {}, cmap, bucket,
+                                         caps, feedback)
                 bl[bi] = cur_b
             builds = tuple(bl)
-        cur = stream
+        cur, live = stream, None
         for pos in range(len(chain) - 1, -1, -1):
-            cur = _emit_one(chain[pos], pos, cur, builds, join_build_ix,
-                            cmap, bucket, caps, feedback)
+            cur, live = _emit_one(chain[pos], pos, cur, builds,
+                                  join_build_ix, cmap, bucket, caps,
+                                  feedback, live, pos in masked)
         if slice_spec is None:
             return cur, None, feedback
         keys, n_out, _sig = slice_spec
@@ -1039,16 +1043,57 @@ def _make_program(chain: List[TpuExec], join_build_ix: Dict[int, int],
     return fn
 
 
-def _node_kind(node) -> str:
+def _row_local(exprs) -> bool:
+    """True when no expression reads a row's position, the row count or a
+    neighbouring row: its value at a row is then the same whether or not
+    the rows a filter drops are still in the batch."""
+    def walk(e) -> bool:
+        return e.row_local and all(walk(c) for c in e.children)
+    return all(walk(e) for e in exprs)
+
+
+def _masked_filters(chain) -> frozenset:
+    """Positions of the chain's filters that hand their mask to a keyless
+    aggregate instead of compacting (the chain is top-down).
+
+    A keyless aggregate reduces over the whole capacity under a boolean
+    ``live`` mask and needs no prefix of live rows, so a filter whose rows
+    reach nothing but such an aggregate skips ``compaction_map`` and
+    ``gather_batch``.  Between the two only row-local projects and further
+    filters (ANDed into the mask) may sit.  Every other filter (grouped
+    aggregate, join or exchange slice above it, top of the chain) compacts.
+    Read from the chain's shape alone, which the program's cache key
+    already covers."""
+    from spark_rapids_tpu.plan.execs.aggregate import TpuHashAggregateExec
+    from spark_rapids_tpu.plan.execs.basic import (
+        TpuFilterExec, TpuProjectExec)
+    masked, pending = set(), []
+    for pos in range(len(chain) - 1, -1, -1):     # bottom-up, as emitted
+        node = chain[pos]
+        if isinstance(node, TpuFilterExec) and _row_local([node.condition]):
+            pending.append(pos)
+        elif isinstance(node, TpuHashAggregateExec):
+            if (node._spec.reduces_under_mask()
+                    and _row_local(node.group_exprs + node.agg_exprs)):
+                masked.update(pending)
+            pending = []
+        elif not (isinstance(node, TpuProjectExec)
+                  and _row_local(node.exprs)):
+            pending = []
+    return frozenset(masked)
+
+
+def _node_kind(node, masked: bool = False) -> str:
     """What one chain node is called in a program's name and in the scope
-    its operations carry in the device trace."""
+    its operations carry in the device trace.  ``masked``: a filter that
+    hands over its mask (``_masked_filters``)."""
     from spark_rapids_tpu.plan.execs.aggregate import TpuHashAggregateExec
     from spark_rapids_tpu.plan.execs.basic import (
         TpuFilterExec, TpuProjectExec)
     if isinstance(node, TpuProjectExec):
         return "project"
     if isinstance(node, TpuFilterExec):
-        return "filter"
+        return "mfilter" if masked else "filter"
     if isinstance(node, TpuHashAggregateExec):
         return "agg"
     return "join"
@@ -1057,7 +1102,8 @@ def _node_kind(node) -> str:
 def _program_kind(chain, slice_spec) -> str:
     """``fused_<chain kinds, top down>[_slice]``, repeats folded
     (``fused_agg_project_filter``), cut to a readable length."""
-    kinds = [_node_kind(n) for n in chain]
+    masked = _masked_filters(chain)
+    kinds = [_node_kind(n, i in masked) for i, n in enumerate(chain)]
     folded = [k for i, k in enumerate(kinds) if i == 0 or k != kinds[i - 1]]
     if slice_spec is not None:
         folded.append("slice")
@@ -1066,19 +1112,23 @@ def _program_kind(chain, slice_spec) -> str:
 
 def _emit_one(node, pos: int, cur: ColumnarBatch, builds: tuple,
               join_build_ix: Dict[int, int], cmap, bucket: int,
-              caps: Dict[str, int],
-              feedback: Dict[str, jax.Array]) -> ColumnarBatch:
+              caps: Dict[str, int], feedback: Dict[str, jax.Array],
+              live: Optional[jax.Array] = None, masked: bool = False
+              ) -> Tuple[ColumnarBatch, Optional[jax.Array]]:
     """One chain node's operations, under a scope that names the node's
-    kind in the device trace."""
-    with jax.named_scope(_node_kind(node)):
+    kind in the device trace.  Returns the node's output and, while a
+    masked filter's rows are on their way to their aggregate, the mask of
+    the rows still live in it (else None: the live rows are a prefix)."""
+    with jax.named_scope(_node_kind(node, masked)):
         return _emit_node(node, pos, cur, builds, join_build_ix, cmap,
-                          bucket, caps, feedback)
+                          bucket, caps, feedback, live, masked)
 
 
 def _emit_node(node, pos: int, cur: ColumnarBatch, builds: tuple,
                join_build_ix: Dict[int, int], cmap, bucket: int,
-               caps: Dict[str, int],
-               feedback: Dict[str, jax.Array]) -> ColumnarBatch:
+               caps: Dict[str, int], feedback: Dict[str, jax.Array],
+               live: Optional[jax.Array], masked: bool
+               ) -> Tuple[ColumnarBatch, Optional[jax.Array]]:
     from spark_rapids_tpu.plan.execs.aggregate import TpuHashAggregateExec
     from spark_rapids_tpu.plan.execs.basic import (
         TpuFilterExec, TpuProjectExec)
@@ -1088,24 +1138,29 @@ def _emit_node(node, pos: int, cur: ColumnarBatch, builds: tuple,
     if isinstance(node, TpuProjectExec):
         ctx = EvalContext(cur, trace_consts=cmap)
         cols = tuple(e.eval(ctx) for e in node.exprs)
-        return ColumnarBatch(cols, cur.num_rows, node.schema)
+        return ColumnarBatch(cols, cur.num_rows, node.schema), live
 
     if isinstance(node, TpuFilterExec):
         ctx = EvalContext(cur, trace_consts=cmap)
         pred = node.condition.eval(ctx)
-        mask = pred.data & pred.validity & cur.live_mask()
+        mask = pred.data & pred.validity & (
+            cur.live_mask() if live is None else live)
+        if masked:
+            return cur, mask
         indices, count = compaction_map(mask)
-        return gather_batch(cur, indices, count)
+        return gather_batch(cur, indices, count), None
 
     if isinstance(node, (TpuBroadcastHashJoinExec, TpuShuffledHashJoinExec)):
+        assert live is None, "a masked filter's rows reach no join"
         # the shuffled join lowers through the SAME gather-map emitter as
         # the broadcast join: its "build" is simply this reduce
         # partition's co-partition side instead of a global broadcast
         return _emit_join(node, pos, cur, builds[join_build_ix[id(node)]],
-                          bucket, caps, feedback)
+                          bucket, caps, feedback), None
 
     assert isinstance(node, TpuHashAggregateExec), type(node).__name__
-    return node._spec._partial_step(cur, string_bucket=bucket)
+    return node._spec._partial_step(cur, string_bucket=bucket,
+                                    live=live), None
 
 
 def _emit_join(node, pos: int, left: ColumnarBatch, right: ColumnarBatch,

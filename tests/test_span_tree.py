@@ -151,7 +151,7 @@ def test_span_is_self_time_not_the_childs(traced, name):
 
 
 def test_launches_by_program_sum_to_launches(traced):
-    for query, kinds in (("q6", {"fused_agg_filter", "agg_combine"}),
+    for query, kinds in (("q6", {"fused_agg_mfilter", "agg_combine"}),
                          ("q1", {"fused_agg_filter_slice", "agg_combine",
                                  "sort_local"})):
         stats = traced[query][1]
@@ -240,7 +240,7 @@ def test_program_names_are_the_same_in_every_process():
         assert p.returncode == 0, err[-2000:]
     names = [out.strip().splitlines()[-1] for out, _err in outs]
     assert names[0] == names[1]
-    assert "fused_agg_filter" in names[0] and "sort_local" in names[0]
+    assert "fused_agg_mfilter" in names[0] and "sort_local" in names[0]
 
 
 def test_drift_lint_sees_span_names_passed_to_timed():

@@ -59,8 +59,7 @@ class ThrottlingExecutor:
                     self._cv.notify_all()
         # write-behind work runs under the SUBMITTER's tenant/priority/
         # token (a cancelled query's queued encodes stop at their next
-        # blessed wait and surface here as the pending error); no
-        # semaphore cover — the task does not block on this write
+        # blessed wait and surface here as the pending error)
         submit_with_ambients(self._pool, run)
 
     def wait(self) -> None:

@@ -134,10 +134,8 @@ class TpuSemaphore:
         self._tls = threading.local()
 
     def held_count(self) -> int:
-        """This thread's reentrant hold count, INCLUDING a borrowed
-        cover (0 for non-task threads)."""
-        return (getattr(self._tls, "held", 0)
-                + getattr(self._tls, "covered", 0))
+        """This thread's reentrant hold count (0 for non-task threads)."""
+        return getattr(self._tls, "held", 0)
 
     def occupancy(self) -> dict:
         """Slot occupancy for the resource-plane sampler
@@ -149,19 +147,19 @@ class TpuSemaphore:
                 "semaphore_waiters": self._sem.waiting()}
 
     def acquire_if_necessary(self, priority: int = 0) -> None:
-        if getattr(self._tls, "covered", 0) > 0:
-            return   # riding the spawning task's slot (borrowed_cover)
-        if getattr(self._tls, "held", 0) == 0:
+        """Where a thread's hold begins (plan/engine.py, the only caller:
+        ``run_one`` for a task, ``execute`` for the caller's thread while
+        it sizes the plan): no other thread takes a permit.  A worker
+        thread doing device work for a task that waits for its output (a
+        pipeline's producer) works under that task's permit and takes
+        none."""
+        if self.held_count() == 0:
             self._sem.acquire(priority)
-        self._tls.held = getattr(self._tls, "held", 0) + 1
+            self._tls.priority = priority
+        self._tls.held = self.held_count() + 1
 
     def release_if_necessary(self) -> None:
-        if getattr(self._tls, "covered", 0) > 0:
-            # the slot belongs to the spawning task: a covered worker's
-            # release (e.g. a scan dropping the device during host work)
-            # must not free a permit this thread never took
-            return
-        held = getattr(self._tls, "held", 0)
+        held = self.held_count()
         if held <= 0:
             return
         self._tls.held = held - 1
@@ -169,32 +167,27 @@ class TpuSemaphore:
             self._sem.release()
 
     @contextmanager
-    def held(self, priority: int = 0):
-        self.acquire_if_necessary(priority)
+    def released(self):
+        """THE way to wait for something other than the device: give back
+        this thread's whole hold for the block and take it back on exit,
+        on the same thread, in the same frame (the block never spans a
+        ``yield``, so no generator ``finally`` touches the semaphore), at
+        the priority the hold began with.  Nothing is taken that was not
+        given: a thread that holds nothing passes straight through.  The
+        re-acquire is a cancellation point: when it raises, the thread
+        holds nothing and ``release_if_necessary`` finds nothing to give
+        back."""
+        held = self.held_count()
+        if held == 0:
+            yield
+            return
+        self._tls.held = 0
+        self._sem.release()
         try:
             yield
         finally:
-            self.release_if_necessary()
-
-    @contextmanager
-    def borrowed_cover(self):
-        """Mark this WORKER thread as covered by its spawning task's
-        slot: acquire_if_necessary/release_if_necessary become NO-OPS
-        for the block (no permit taken — and, critically, none
-        RELEASED: the cover is tracked separately from the real held
-        count so a covered scan's release-during-host-work can never
-        free the consumer task's permit).  For pipeline producer threads
-        (shuffle/pipeline.py) doing device work ON BEHALF of a task that
-        already holds a slot and is blocked waiting for this producer's
-        output — taking a second permit there deadlocks the moment every
-        permit is held by such blocked consumers (parquet scan inside a
-        pipelined exchange map side)."""
-        prev = getattr(self._tls, "covered", 0)
-        self._tls.covered = prev + 1
-        try:
-            yield
-        finally:
-            self._tls.covered = prev
+            self._sem.acquire(self._tls.priority)
+            self._tls.held = held
 
 
 #: thread-ambient device priority: the serving layer sets it around a

@@ -22,7 +22,6 @@ class TpuEngine:
 
     def execute(self, plan: TpuExec) -> List[List[ColumnarBatch]]:
         """Materialize all partitions (list of batches per partition)."""
-        nparts = plan.num_partitions()
         # partition tasks are PART of the submitting query: pool threads
         # must inherit its tenant ambient or their allocations would
         # escape the tenant's budget/spill accounting (memory/tenant.py),
@@ -46,10 +45,20 @@ class TpuEngine:
         trace = current_query_trace()
         parent_span = current_span_id()
 
+        # sizing the plan can be device work (an exchange, an AQE reader
+        # or an adaptive join materialises its child inside
+        # num_partitions()): this thread is a task meanwhile, with a
+        # permit like any other
+        sem = tpu_semaphore()
+        sem.acquire_if_necessary(priority)
+        try:
+            nparts = plan.num_partitions()
+        finally:
+            sem.release_if_necessary()
+
         def run_one(p: int) -> List[ColumnarBatch]:
             from spark_rapids_tpu.memory.task_completion import task_scope
             from spark_rapids_tpu.utils.obs import task_metrics_tee
-            sem = tpu_semaphore()
             # task_metrics_tee: this task's per-thread TaskMetrics
             # DELTA (semaphore wait below included) lands in the
             # per-query counter scope as task_* keys

@@ -19,11 +19,42 @@ from __future__ import annotations
 import collections
 import functools
 import hashlib
+import threading
 import time
 from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
 from spark_rapids_tpu.columnar.batch import ColumnarBatch, Schema
 from spark_rapids_tpu.utils.tracing import trace_range
+
+
+class MaterializeLock:
+    """The lock of a materialise-once block (``with self._lock: if
+    self._x is None: ...``), which a task holds across its child's whole
+    execution.  A task that finds it taken waits for it under
+    ``tpu_semaphore().released()``: blocked on a sibling's materialisation
+    it holds no device permit, so the sibling's scan, which gave its own
+    up to wait for a decoded chunk, can always take one back.
+    Uncontended, it costs one non-blocking ``acquire``."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+
+    def __enter__(self):
+        if not self._lock.acquire(blocking=False):
+            from spark_rapids_tpu.memory.semaphore import tpu_semaphore
+            got = False
+            try:
+                with tpu_semaphore().released():
+                    got = self._lock.acquire()
+            except BaseException:
+                # taking the permit back is a cancellation point, and
+                # __exit__ does not run when __enter__ raises
+                if got:
+                    self._lock.release()
+                raise
+
+    def __exit__(self, *exc):
+        self._lock.release()
 
 
 # -- cross-query jit sharing --------------------------------------------------
@@ -36,7 +67,7 @@ from spark_rapids_tpu.utils.tracing import trace_range
 
 _JIT_CACHE: "collections.OrderedDict[str, object]" = collections.OrderedDict()
 _JIT_CACHE_MAX = 512
-_JIT_CACHE_LOCK = __import__("threading").Lock()
+_JIT_CACHE_LOCK = threading.Lock()
 
 
 class _LaunchStats:
@@ -47,7 +78,7 @@ class _LaunchStats:
     Counts every shared_jit dispatch, never blocks;
     reset/read from bench.py around each timed run.  Lock-guarded: tasks
     dispatch from a thread pool and `+=` is not atomic bytecode."""
-    lock = __import__("threading").Lock()
+    lock = threading.Lock()
     count = 0
     by_program: Dict[str, int] = {}     # program name -> launches since reset
 

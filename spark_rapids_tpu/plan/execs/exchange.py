@@ -13,7 +13,6 @@ results agree with the CPU oracle row-for-row.
 """
 from __future__ import annotations
 
-import threading
 from typing import Iterator, List, Optional, Sequence, Tuple
 
 import jax
@@ -30,7 +29,8 @@ from spark_rapids_tpu.kernels.selection import (
     gather_batch,
 )
 from spark_rapids_tpu.memory.retry import with_capacity_retry, with_retry_no_split
-from spark_rapids_tpu.plan.execs.base import TpuExec, string_key_bucket, timed
+from spark_rapids_tpu.plan.execs.base import (
+    MaterializeLock, TpuExec, string_key_bucket, timed)
 from spark_rapids_tpu.utils.tracing import trace_range
 
 
@@ -88,7 +88,7 @@ class TpuShuffleExchangeExec(TpuExec):
         self.writer_threads = writer_threads
         self.codec = codec
         self.target_rows = max(int(target_rows), 1)
-        self._lock = threading.Lock()
+        self._lock = MaterializeLock()
         self._transport = None   # built lazily per query (the SPI seam)
         #: materialization generation: bumped on cleanup so epoch-keyed
         #: consumers (SharedCoalesceSpec) never serve groups computed from
@@ -256,7 +256,6 @@ class TpuShuffleExchangeExec(TpuExec):
         with self._lock:
             if self._transport is None:
                 SHUFFLE_COUNTERS.add(exchange_stages=1)
-                # tpu-lint: allow-lock-order(once-per-epoch map materialization: the lock IS the idempotence guard; transport construction's makedirs happens once per process)
                 t = make_transport(self.mode, self.out_partitions,
                                    self.schema, self.writer_threads,
                                    self.codec)
@@ -276,7 +275,6 @@ class TpuShuffleExchangeExec(TpuExec):
                         _spanned_writes(self._range_views()))
                 elif (t.supports_range_write and range_serialize_enabled()
                         and range_supported(self.schema)):
-                    # tpu-lint: allow-lock-order(the materialize lock deliberately covers the ONE map-side download per epoch; concurrent readers must wait for exactly this result)
                     gen = self._range_stream()
                     if pipe:
                         from spark_rapids_tpu.shuffle.pipeline import (
@@ -431,7 +429,7 @@ class SharedCoalesceSpec:
         self.exchanges: List[TpuShuffleExchangeExec] = []
         self._groups: Optional[List[List[int]]] = None
         self._epoch_key: Optional[tuple] = None
-        self._lock = threading.Lock()
+        self._lock = MaterializeLock()
 
     def register(self, ex: "TpuShuffleExchangeExec") -> None:
         ex._want_part_stats = True    # before any materialization (plan

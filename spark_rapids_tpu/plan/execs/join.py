@@ -12,7 +12,6 @@ side once (the broadcast) and streams the other side's partitions.
 """
 from __future__ import annotations
 
-import threading
 from functools import lru_cache
 from typing import Iterator, List, Optional, Sequence, Tuple
 
@@ -27,7 +26,7 @@ from spark_rapids_tpu.kernels.join import (
     apply_gather_maps, conditional_join_maps, join_expand, join_gather_maps,
     join_path, join_probe)
 from spark_rapids_tpu.memory.retry import with_capacity_retry, with_retry_no_split
-from spark_rapids_tpu.plan.execs.base import TpuExec, timed
+from spark_rapids_tpu.plan.execs.base import MaterializeLock, TpuExec, timed
 from spark_rapids_tpu.plan.execs.coalesce import coalesce_to_one
 
 
@@ -652,7 +651,7 @@ class TpuBroadcastHashJoinExec(TpuExec):
                                    left_schema=left.schema,
                                    right_schema=right.schema,
                                    condition=condition)
-        self._lock = threading.Lock()
+        self._lock = MaterializeLock()
         self._build: Optional[ColumnarBatch] = None
         self._build_done = False
 
@@ -754,7 +753,7 @@ class TpuAdaptiveJoinExec(TpuExec):
         self.aqe_coalesce = aqe_coalesce
         self.fuse_inner = fuse_inner
         self.fuse_across_shuffle = fuse_across_shuffle
-        self._lock = threading.Lock()
+        self._lock = MaterializeLock()
         self._inner: Optional[TpuExec] = None
         self.chosen: Optional[str] = None   # exposed for tests/explain
         #: (ClusterStatsClient, key) when distributed — the decision then
@@ -767,18 +766,13 @@ class TpuAdaptiveJoinExec(TpuExec):
         with self._lock:
             if self._inner is not None:
                 return self._inner
-            from spark_rapids_tpu.memory.semaphore import tpu_semaphore
             from spark_rapids_tpu.plan.execs.exchange import (
                 TpuShuffleExchangeExec)
             from spark_rapids_tpu.plan.execs.scan import TpuInMemoryScanExec
 
             right = self.children[1]
-            # materializing the build side is device work: hold the
-            # semaphore like any task would (the engine may reach here from
-            # num_partitions(), before its own per-task acquisition)
-            with tpu_semaphore().held():
-                right_parts = [list(right.execute_partition(p))
-                               for p in range(right.num_partitions())]
+            right_parts = [list(right.execute_partition(p))
+                           for p in range(right.num_partitions())]
             build_rows = sum(b.host_num_rows()
                              for part in right_parts for b in part)
             if self.cluster_stats is not None:
@@ -800,7 +794,6 @@ class TpuAdaptiveJoinExec(TpuExec):
                     # complete reduce read returns the full build side)
                     from spark_rapids_tpu.shuffle.transport import (
                         make_transport)
-                    # tpu-lint: allow-lock-order(once-per-join strategy decision: the decide lock is the idempotence guard; the transport's makedirs is once per process)
                     t = make_transport("MULTIPROCESS", 1,
                                        self.children[1].schema,
                                        self.writer_threads, self.codec)

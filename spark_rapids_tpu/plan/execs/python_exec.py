@@ -57,17 +57,13 @@ class TpuMapBatchesExec(TpuExec):
         for batch in self._input_batches(idx):
             with timed(self.op_time):
                 table = batch.to_arrow()     # device -> host Arrow
-                sem = tpu_semaphore()
                 # release the device while Python crunches host data
                 # (PythonWorkerSemaphore.scala analog)
-                sem.release_if_necessary()
-                try:
+                with tpu_semaphore().released():
                     if self.worker_pool is not None:
                         result = self.worker_pool.run(self.fn, table)
                     else:
                         result = self.fn(table)
-                finally:
-                    sem.acquire_if_necessary()
                 out = arrow_to_batch(result)  # host Arrow -> device
             self.output_rows.add(out.num_rows)
             yield self._count_out(out)

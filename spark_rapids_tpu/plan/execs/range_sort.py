@@ -20,7 +20,6 @@ which uses the same bucketing as a distribution sort within one partition
 """
 from __future__ import annotations
 
-import threading
 from typing import Callable, Iterator, List, Optional, Sequence, Tuple
 
 import jax
@@ -35,7 +34,8 @@ from spark_rapids_tpu.kernels.sort import SortOrder, _data_key_fixed, _null_key,
 from spark_rapids_tpu.kernels.groupby import normalize_key_column
 from spark_rapids_tpu.memory.retry import with_retry_no_split
 from spark_rapids_tpu.memory.spill import SpillableBatchHandle, make_spillable
-from spark_rapids_tpu.plan.execs.base import TpuExec, string_key_bucket, timed
+from spark_rapids_tpu.plan.execs.base import (
+    MaterializeLock, TpuExec, string_key_bucket, timed)
 from spark_rapids_tpu.plan.execs.coalesce import (
     coalesce_to_one, retry_over_spillable)
 from spark_rapids_tpu.plan.execs.sort import TpuSortExec
@@ -197,7 +197,7 @@ class TpuRangeSortExec(TpuExec):
         #: inputs at or under this (spark.rapids.sql.batchSizeRows) skip
         #: sampling/routing and sort as ONE local partition
         self.small_sort_rows = max(int(small_sort_rows), 1)
-        self._lock = threading.Lock()
+        self._lock = MaterializeLock()
         self._buckets: Optional[List[List[SpillableBatchHandle]]] = None
         self._local_sort = TpuSortExec(self.orders, child)  # reuse its jit
         #: (rank, world) when distributed — set by the cluster executor;
@@ -216,7 +216,7 @@ class TpuRangeSortExec(TpuExec):
         with self._lock:
             if self._cluster_transport is None:
                 self._cluster_transport = \
-                    self._materialize_cluster(*self.cluster)  # tpu-lint: allow-lock-order(once-per-exec cluster materialization: the lock is the idempotence guard for the one map-side download)
+                    self._materialize_cluster(*self.cluster)
 
     def num_partitions(self) -> int:
         return self.out_partitions
@@ -259,7 +259,7 @@ class TpuRangeSortExec(TpuExec):
             with self._lock:
                 if self._cluster_transport is None:
                     self._cluster_transport = \
-                        self._materialize_cluster(*self.cluster)  # tpu-lint: allow-lock-order(once-per-exec cluster materialization: the lock is the idempotence guard for the one map-side download)
+                        self._materialize_cluster(*self.cluster)
                 transport = self._cluster_transport
             with timed(self.op_time):
                 batches = transport.read(idx)

@@ -51,6 +51,7 @@ from spark_rapids_tpu.expressions.core import (
 from spark_rapids_tpu.kernels.selection import compaction_map, gather_batch
 from spark_rapids_tpu.memory.retry import with_retry_no_split
 from spark_rapids_tpu.plan.execs.base import (
+    MaterializeLock,
     TpuExec,
     bind_trace_consts,
     collect_trace_consts,
@@ -325,7 +326,7 @@ class TpuFusedSegmentExec(TpuExec):
         #: (an oversized raw build applies its chain eagerly and empties
         #: its slot); None until builds materialize
         self._fold_chains: Optional[List[List[TpuExec]]] = None
-        self._lock = threading.Lock()
+        self._lock = MaterializeLock()
         self._build_batches: Optional[List[Optional[ColumnarBatch]]] = None
         self._build_bytes = 0
         # join node -> build argument index, in chain order.  A SHUFFLED
@@ -477,7 +478,6 @@ class TpuFusedSegmentExec(TpuExec):
                             lambda: _apply_build_chain(fold[bi], merged))
                         fold[bi] = []
                     outs.append(merged)
-                    # tpu-lint: allow-lock-order(once-per-exec build materialization: the sync sizes the memoized build batches, and every waiter needs exactly those results before proceeding)
                     mb = max(mb, _max_live_bytes(merged))
                 self._build_batches = outs
                 self._build_bytes = mb

@@ -1797,9 +1797,9 @@ class BlockFetchIterator:
         from spark_rapids_tpu.utils.ambient import (Ambients,
                                                     spawn_with_ambients)
         # fetch workers act for the consuming reduce task: same tenant,
-        # priority and cancel token (they never touch the device, so no
-        # semaphore cover); captured ONCE, on the consumer's thread
-        amb = Ambients.capture(inherit_semaphore_cover=False)
+        # priority and cancel token; captured ONCE, on the consumer's
+        # thread
+        amb = Ambients.capture()
         threads = []
         with cv:
             for src_state in sources:

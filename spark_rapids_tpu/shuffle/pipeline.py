@@ -125,9 +125,9 @@ def pipelined(source: Iterable, nbytes_of: Callable[[object], int],
     (its device allocations charge the submitting query), task priority,
     the cancel token (a cancelled query's producer exits its loop at the
     next token check or hand-off wait instead of producing into a dead
-    hand-off), and the device-semaphore cover — the consumer blocks on
-    this queue while holding its slot, so a producer-side acquire would
-    deadlock once every slot is held by such blocked consumers (the
+    hand-off).  It takes no device permit: the consumer blocks on this
+    queue while holding its own, and a producer-side acquire would
+    deadlock once every permit is held by such blocked consumers (the
     reference's shuffle writer threads skip the GPU semaphore for the
     same reason).  Exceptions from the source re-raise at the consumer's
     next pull; an abandoned consumer (generator closed early) stops the

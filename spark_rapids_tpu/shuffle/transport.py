@@ -477,7 +477,7 @@ class KudoWireTransport(ShuffleTransport):
         # writer threads serialize for the map task: same tenant/
         # priority/token (a cancelled query's framing stops at the next
         # blessed wait); captured once for the whole batch of submits
-        amb = Ambients.capture(inherit_semaphore_cover=False)
+        amb = Ambients.capture()
         with ThreadPoolExecutor(max_workers=self.writer_threads) as pool:
             futures = [(p, submit_with_ambients(pool, serialize_batch,
                                                 piece, self.codec,
@@ -507,7 +507,7 @@ class KudoWireTransport(ShuffleTransport):
                 if block is not None:
                     self._buckets[p].append(block)
 
-        amb = Ambients.capture(inherit_semaphore_cover=False)
+        amb = Ambients.capture()
         pending = deque()
         with ThreadPoolExecutor(max_workers=self.writer_threads) as pool:
             for hb, counts in batches:

@@ -363,10 +363,10 @@ class TelemetrySampler:
                 interval = self.interval_ms / 1000.0
             self._wake.wait(interval if enabled else 2.0)
             self._wake.clear()
-            if not enabled:
+            if not self.enabled:    # re-read: configure() wakes this wait
                 continue
             try:
-                self.sample()
+                self.sample(only_if_enabled=True)
             except Exception:  # noqa: BLE001
                 # the sampler must never die to a transient read race;
                 # one missing tick beats a silent telemetry blackout
@@ -374,12 +374,15 @@ class TelemetrySampler:
 
     # -- ring ----------------------------------------------------------------
 
-    def sample(self) -> dict:
+    def sample(self, only_if_enabled: bool = False) -> dict:
         """Take one sample into the ring (also the deterministic test
-        entry point — callable regardless of the daemon)."""
+        entry point — callable regardless of the daemon, whose own ticks
+        land ``only_if_enabled``: none after configure() turned it
+        off)."""
         s = sample_now()
         with self._lock:
-            self._ring.append(s)
+            if self.enabled or not only_if_enabled:
+                self._ring.append(s)
         return s
 
     def latest(self) -> Optional[dict]:

@@ -102,6 +102,56 @@ def test_adaptive_join_differential(n_right):
     assert _normalize(build(tpu).collect()) == _normalize(build(cpu).collect())
 
 
+def test_concurrent_queries_decide_their_build_sides_under_a_permit(
+        monkeypatch):
+    """_decide materialises the build side on the device, as a rule from
+    num_partitions() on the caller's thread before any task has started:
+    engine.execute holds a permit there (PR 29), so four queries at once
+    put no more build sides on the device than the semaphore has
+    permits."""
+    import threading
+    import time
+
+    from spark_rapids_tpu.memory.semaphore import tpu_semaphore
+    from test_queries import _normalize
+    gauge = threading.Lock()
+    deciding, peak, held = [0], [0], []
+    decide = TpuAdaptiveJoinExec._decide
+
+    def watched(self):
+        if self._inner is not None:
+            return decide(self)
+        with gauge:
+            deciding[0] += 1
+            peak[0] = max(peak[0], deciding[0])
+            held.append(tpu_semaphore().held_count())
+        try:
+            time.sleep(0.05)
+            return decide(self)
+        finally:
+            with gauge:
+                deciding[0] -= 1
+    monkeypatch.setattr(TpuAdaptiveJoinExec, "_decide", watched)
+    cpu = TpuSession({"spark.rapids.sql.enabled": "false"})
+    want = _normalize(_build(cpu, 2000).collect())
+    got = [None] * 4
+
+    def query(i):
+        tpu = TpuSession({
+            "spark.rapids.sql.enabled": "true",
+            "spark.rapids.sql.join.broadcastRowThreshold": "256"})
+        got[i] = _normalize(_build(tpu, 2000).collect())
+    queries = [threading.Thread(target=query, args=(i,)) for i in range(4)]
+    for q in queries:
+        q.start()
+    for q in queries:
+        q.join(300)
+    assert not any(q.is_alive() for q in queries)
+    assert got == [want] * 4
+    assert held == [1] * 4, held
+    assert peak[0] <= tpu_semaphore()._sem._size, peak
+
+
 @pytest.mark.inject_oom
 def test_adaptive_join_inject_oom():
     cpu = TpuSession({"spark.rapids.sql.enabled": "false"})

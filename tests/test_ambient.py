@@ -3,7 +3,7 @@
 The contract tpu-lint's ambient-propagation rule points every spawn
 site at: a worker spawned through spawn_with_ambients /
 submit_with_ambients observes the SPAWNER's tenant scope, task
-priority, cancel token and (opt-in) device-semaphore cover — and the
+priority and cancel token (never a device permit) — and the
 snapshot is taken at spawn time on the spawning thread, so the worker
 keeps the ambients even after the spawner leaves its scopes.
 """
@@ -24,6 +24,7 @@ from spark_rapids_tpu.utils.ambient import (Ambients, spawn_with_ambients,
                                             submit_with_ambients)
 from spark_rapids_tpu.utils.cancel import (CancelToken, cancel_scope,
                                            current_cancel_token)
+from tests.test_memory import task_hold
 
 
 def _observe(out: dict, done: threading.Event):
@@ -60,12 +61,14 @@ def test_spawn_captures_at_spawn_time_not_thread_start():
     assert out["priority"] == 3
 
 
-def test_spawn_inherits_semaphore_cover_only_when_held():
+def test_spawned_worker_holds_no_permit_whether_or_not_the_spawner_does():
+    """The device permit is not an ambient: a worker works under the
+    permit of the task that waits for it and has no hold of its own."""
     out, done = {}, threading.Event()
-    with tpu_semaphore().held():
+    with task_hold(tpu_semaphore()):
         spawn_with_ambients(_observe, out, done)
         assert done.wait(5.0)
-    assert out["held"] > 0, "worker should ride the spawner's slot"
+    assert out["held"] == 0
 
     out2, done2 = {}, threading.Event()
     spawn_with_ambients(_observe, out2, done2)
@@ -73,22 +76,26 @@ def test_spawn_inherits_semaphore_cover_only_when_held():
     assert out2["held"] == 0
 
 
-def test_covered_worker_release_cannot_free_spawners_permit():
-    """A covered worker's release_if_necessary is a no-op — the slot
-    belongs to the spawning task (the PR 9 lesson encoded in
-    borrowed_cover, reachable through the helper)."""
+def test_worker_waiting_off_the_device_cannot_free_spawners_permit():
+    """A worker's released() (a scan under a pipelined exchange waiting
+    for a decoded chunk) and release_if_necessary() give back nothing:
+    the permit belongs to the spawning task (the PR 9 lesson)."""
     sem = tpu_semaphore()
     base = sem._sem.available()
     done = threading.Event()
+    seen = []
 
     def worker():
         sem.release_if_necessary()    # must NOT free the spawner's slot
+        with sem.released():
+            seen.append(sem._sem.available())
         done.set()
 
-    with sem.held():
+    with task_hold(sem):
         avail_held = sem._sem.available()
         spawn_with_ambients(worker)
         assert done.wait(5.0)
+        assert seen == [avail_held]
         assert sem._sem.available() == avail_held
     assert sem._sem.available() == base
 
@@ -107,22 +114,18 @@ def test_submit_with_ambients_inherits_on_pool_thread():
     assert tok is token
 
 
-def test_submit_cover_defaults_off():
-    """Pool tasks routinely outlive the submitting call; cover is only
-    sound while the spawner blocks holding its slot, so it is opt-in."""
+def test_pool_task_holds_no_permit():
+    """Pool tasks routinely outlive the submitting call: like every
+    worker they hold nothing of the submitter's."""
     with ThreadPoolExecutor(max_workers=1) as pool:
-        with tpu_semaphore().held():
+        with task_hold(tpu_semaphore()):
             fut = submit_with_ambients(
                 pool, lambda: tpu_semaphore().held_count())
             assert fut.result(timeout=5.0) == 0
-            fut2 = submit_with_ambients(
-                pool, lambda: tpu_semaphore().held_count(),
-                inherit_semaphore_cover=True)
-            assert fut2.result(timeout=5.0) > 0
 
 
 def test_ambients_scope_restores_previous_context():
-    amb = Ambients(tenant="x", priority=9, token=None, covered=False)
+    amb = Ambients(tenant="x", priority=9, token=None)
     with TENANTS.scope("outer"), task_priority(1):
         with amb.scope():
             assert TENANTS.current() == "x"

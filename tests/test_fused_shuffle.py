@@ -316,8 +316,8 @@ def test_pipelined_parquet_scan_does_not_deadlock(tmp_path):
     wire-mode exchange whose producer thread reaches a PARQUET scan used
     to acquire a SECOND device-semaphore slot — with every slot held by
     engine tasks blocked on the producer's own queue, the query
-    deadlocked.  Producers now ride the spawning task's slot
-    (TpuSemaphore.borrowed_cover)."""
+    deadlocked.  A producer works under the spawning task's permit and
+    takes none: only plan/engine.py acquires (PR 29)."""
     import pyarrow as pa
     import pyarrow.parquet as pq
 

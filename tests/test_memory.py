@@ -4,6 +4,9 @@ Models the reference's RmmSparkRetrySuiteBase-style units
 (tests/src/test/scala/.../RmmRapidsRetryIteratorSuite.scala in the
 reference) against the TPU arena/spill/retry stack.
 """
+import time
+from contextlib import contextmanager
+
 import numpy as np
 import pytest
 
@@ -339,3 +342,271 @@ def test_query_leaves_no_leaked_handles():
         assert not new, f"query leaked {len(new)} handles"
     finally:
         set_leak_audit(False)
+
+
+# -- the device permit: TpuSemaphore.released() and MaterializeLock ----------
+
+def _free(sem) -> int:
+    return sem._sem.available()
+
+
+@contextmanager
+def task_hold(sem):
+    """A task's hold, as plan/engine.py run_one takes and gives it."""
+    sem.acquire_if_necessary()
+    try:
+        yield
+    finally:
+        sem.release_if_necessary()
+
+
+@pytest.mark.parametrize("holds", [0, 1, 3])
+def test_released_gives_back_the_whole_hold_and_takes_back_only_that(holds):
+    """Nothing held: nothing given, nothing taken (the leak of
+    python_exec.py's hand-written pair at PR 28).  Held once or
+    re-entrantly: one permit goes back for the block and the hold count
+    is what it was afterwards."""
+    from spark_rapids_tpu.memory.semaphore import TpuSemaphore
+    sem = TpuSemaphore(2)
+    for _ in range(holds):
+        sem.acquire_if_necessary()
+    before = _free(sem)
+    assert before == (2 if holds == 0 else 1)
+    with sem.released():
+        assert _free(sem) == 2 and sem.held_count() == 0
+    assert _free(sem) == before and sem.held_count() == holds
+    for _ in range(holds):
+        sem.release_if_necessary()
+    assert _free(sem) == 2 and sem.held_count() == 0
+
+
+def test_released_on_a_tasks_worker_thread_leaves_the_tasks_permit():
+    """A pipeline's producer works under its consumer task's permit and
+    holds nothing itself: its scan's released() gives nothing back."""
+    import threading
+
+    from spark_rapids_tpu.memory.semaphore import TpuSemaphore
+    sem = TpuSemaphore(2)
+    seen = []
+
+    def producer():
+        with sem.released():
+            seen.append((_free(sem), sem.held_count()))
+    with task_hold(sem):
+        worker = threading.Thread(target=producer)
+        worker.start()
+        worker.join(30)
+        assert seen == [(1, 0)] and _free(sem) == 1
+    assert _free(sem) == 2
+
+
+def test_released_takes_the_permit_back_when_the_block_raises():
+    from spark_rapids_tpu.memory.semaphore import TpuSemaphore
+    sem = TpuSemaphore(2)
+    with task_hold(sem):
+        with pytest.raises(KeyError):
+            with sem.released():
+                raise KeyError("inside")
+        assert _free(sem) == 1 and sem.held_count() == 1
+    assert _free(sem) == 2
+
+
+def test_generator_using_released_closed_from_another_thread():
+    """The block never spans a yield, so closing the generator on a thread
+    that holds nothing touches no permit (scan.py's `restore` loops, which
+    ran in a generator's finally on whichever thread closed it, are gone)."""
+    import threading
+
+    from spark_rapids_tpu.memory.semaphore import TpuSemaphore
+    sem = TpuSemaphore(2)
+
+    def batches():
+        for i in range(3):
+            with sem.released():
+                item = i
+            yield item
+
+    gen = batches()
+    with task_hold(sem):
+        assert next(gen) == 0 and _free(sem) == 1
+        closer = threading.Thread(target=gen.close)
+        closer.start()
+        closer.join(30)
+        assert _free(sem) == 1 and sem.held_count() == 1
+    assert _free(sem) == 2
+
+
+def test_scan_on_a_thread_without_a_hold_uploads_without_a_permit(tmp_path):
+    """A scan on a thread that holds nothing (a pipeline's producer; a
+    caller driving execute_partition itself, as here) takes no permit for
+    its uploads and leaves the count whole, abandoned half-way too."""
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    from spark_rapids_tpu.api.session import TpuSession
+    from spark_rapids_tpu.memory.semaphore import tpu_semaphore
+    path = str(tmp_path / "t.parquet")
+    pq.write_table(pa.table({"a": list(range(4000))}), path,
+                   row_group_size=1000)
+    sess = TpuSession({"spark.rapids.sql.reader.batchSizeRows": "1000"})
+    scan = sess.read_parquet(path).physical_plan()
+    sem = tpu_semaphore()
+    total = _free(sem)
+    batches = scan.execute_partition(0)
+    assert next(batches).num_rows == 1000
+    assert _free(sem) == total and sem.held_count() == 0
+    batches.close()
+    assert _free(sem) == total and sem.held_count() == 0
+    assert sum(b.num_rows for b in scan.execute_partition(0)) == 4000
+    assert _free(sem) == total and sem.held_count() == 0
+    scan.cleanup()
+
+
+@pytest.mark.parametrize("contended", [False, True])
+def test_materialize_lock_waiter_holds_no_permit(contended, monkeypatch):
+    """Uncontended the lock never touches the semaphore; a task that finds
+    it taken gives its permit up until it has the lock."""
+    import threading
+
+    from spark_rapids_tpu.memory import semaphore as semaphore_mod
+    from spark_rapids_tpu.plan.execs.base import MaterializeLock
+    sem = semaphore_mod.TpuSemaphore(2)
+    monkeypatch.setattr(semaphore_mod, "_SEMAPHORE", sem)
+    lock = MaterializeLock()
+    inside, leave = threading.Event(), threading.Event()
+
+    def sibling():
+        with task_hold(sem), lock:
+            inside.set()
+            leave.wait(30)
+    other = threading.Thread(target=sibling)
+    if contended:
+        other.start()
+        assert inside.wait(30)
+    seen, has_permit = [], threading.Event()
+
+    def task():
+        with task_hold(sem):
+            has_permit.set()
+            with lock:
+                seen.append(_free(sem))
+    waiter = threading.Thread(target=task)
+    waiter.start()
+    assert has_permit.wait(30)      # both permits are out now
+    if contended:
+        deadline = time.monotonic() + 30
+        while _free(sem) != 1 and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert _free(sem) == 1, "the waiter kept its permit"
+        leave.set()
+        other.join(30)
+    waiter.join(30)
+    assert seen == [1] and _free(sem) == 2
+
+
+def test_materialize_lock_is_free_after_a_cancelled_reacquire(monkeypatch):
+    """Taking the permit back is a cancellation point.  A waiter that gets
+    the lock but not its permit (both held elsewhere, its query cancelled)
+    raises out of __enter__, where no __exit__ follows: the lock must be
+    free afterwards, or the query's own cleanup() blocks on it for good."""
+    import threading
+
+    from spark_rapids_tpu.memory import semaphore as semaphore_mod
+    from spark_rapids_tpu.plan.execs.base import MaterializeLock
+    from spark_rapids_tpu.utils.cancel import (CancelToken, QueryCancelled,
+                                               cancel_scope)
+    sem = semaphore_mod.TpuSemaphore(2)
+    monkeypatch.setattr(semaphore_mod, "_SEMAPHORE", sem)
+    lock = MaterializeLock()
+    token = CancelToken("waiter")
+    raised, entered = [], []
+
+    def task():
+        try:
+            with cancel_scope(token), task_hold(sem):
+                with lock:
+                    entered.append(True)
+        except QueryCancelled as exc:
+            raised.append(exc)
+            raised.append(sem.held_count())
+
+    with lock:                          # the sibling's materialisation
+        waiter = threading.Thread(target=task)
+        waiter.start()
+        deadline = time.monotonic() + 30
+        while sem._sem.waiting() or _free(sem) != 2:    # took one, gave it
+            assert time.monotonic() < deadline
+            time.sleep(0.01)
+        time.sleep(0.05)                # ...and now stands at the lock
+        sem._sem.acquire()              # both permits held elsewhere
+        sem._sem.acquire()
+        token.cancel()
+    waiter.join(30)
+    assert not waiter.is_alive() and not entered
+    assert len(raised) == 2 and raised[1] == 0
+    assert lock._lock.acquire(blocking=False), "the lock leaked"
+    lock._lock.release()
+    sem._sem.release()
+    sem._sem.release()
+    assert _free(sem) == 2 and sem._sem.waiting() == 0
+
+
+def test_released_takes_the_permit_back_at_the_holds_priority():
+    """A serving query's task re-enters the device queue where it stood,
+    not at the default priority (which would jump or lose the queue)."""
+    from spark_rapids_tpu.memory.semaphore import TpuSemaphore
+    sem = TpuSemaphore(2)
+    asked = []
+    acquire = sem._sem.acquire
+    sem._sem.acquire = lambda priority=0, **kw: (asked.append(priority),
+                                                 acquire(priority, **kw))[1]
+    sem.acquire_if_necessary(-7)
+    sem.acquire_if_necessary(3)         # re-entrant: the hold's own stays
+    with sem.released():
+        pass
+    sem.release_if_necessary()
+    sem.release_if_necessary()
+    assert asked == [-7, -7] and _free(sem) == 2
+
+
+def test_sizing_the_plan_is_metered_like_a_task(monkeypatch):
+    """Device work inside num_partitions() (an exchange's map side, an AQE
+    reader, an adaptive join's build side) runs on the caller's thread
+    before any task: engine.execute holds a permit for it, so N queries
+    at once put no more than the semaphore's size on the device."""
+    import threading
+
+    from spark_rapids_tpu.columnar.batch import Schema
+    from spark_rapids_tpu.memory import semaphore as semaphore_mod
+    from spark_rapids_tpu.plan import engine as engine_mod
+    from spark_rapids_tpu.plan.execs.base import TpuExec
+    sem = semaphore_mod.TpuSemaphore(2)
+    monkeypatch.setattr(semaphore_mod, "_SEMAPHORE", sem)
+    monkeypatch.setattr(engine_mod, "tpu_semaphore", lambda: sem)
+    gauge = threading.Lock()
+    inside, peak, held = [0], [0], []
+
+    class Sizing(TpuExec):
+        def num_partitions(self):
+            with gauge:
+                inside[0] += 1
+                peak[0] = max(peak[0], inside[0])
+                held.append(sem.held_count())
+            time.sleep(0.1)
+            with gauge:
+                inside[0] -= 1
+            return 1
+
+        def execute_partition(self, idx):
+            return iter(())
+
+    def query():
+        engine_mod.TpuEngine().execute(Sizing((), Schema((), ())))
+    queries = [threading.Thread(target=query) for _ in range(5)]
+    for q in queries:
+        q.start()
+    for q in queries:
+        q.join(60)
+    assert not any(q.is_alive() for q in queries)
+    assert held == [1] * 5 and peak[0] == 2
+    assert _free(sem) == 2 and sem.held_count() == 0

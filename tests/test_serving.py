@@ -363,6 +363,58 @@ def test_tenant_isolation_concurrent_queries():
     big_ballast.close()
 
 
+def test_concurrent_order_by_queries_from_parquet_complete(tmp_path,
+                                                          monkeypatch):
+    """Two ORDER BY queries over Parquet at once in one process: each
+    query's first task holds its range sort's lock and gives its permit
+    up in the scan; the scans here deliver nothing until both have, and
+    until the two other tasks have had time to take the permits.  At PR 28
+    those two kept them while they waited for the locks and no scan could
+    ever upload.  A task now waits for a materialise-once lock off the
+    semaphore."""
+    from concurrent.futures import TimeoutError as FutureTimeout
+
+    from spark_rapids_tpu.memory.semaphore import tpu_semaphore
+    from spark_rapids_tpu.plan.execs.scan import TpuParquetScanExec
+    from tests.test_range_sort import ordered_agg, write_two_parquet_files
+    paths = write_two_parquet_files(tmp_path)
+    both_in_scan = threading.Barrier(2)
+    host_iter = TpuParquetScanExec._host_iter
+
+    def gated_host_iter(self, idx):
+        if idx == 0:            # once a query: its first file's decode
+            both_in_scan.wait(60)
+            deadline = time.monotonic() + 1.0
+            while tpu_semaphore()._sem.available() \
+                    and time.monotonic() < deadline:
+                time.sleep(0.02)
+        return host_iter(self, idx)
+    monkeypatch.setattr(TpuParquetScanExec, "_host_iter", gated_host_iter)
+
+    runner = LocalSessionRunner(
+        {"spark.rapids.sql.reader.batchSizeRows": "1500"})
+    plan = ordered_agg(runner.session, paths).plan
+    q = QueryQueue(runner, conf={
+        "spark.rapids.serving.maxConcurrentQueries": "2",
+        "spark.rapids.serving.cache.enabled": "false"})
+    futs = [q.submit_async(plan, tenant=t, cacheable=False)
+            for t in ("a", "b")]
+    try:
+        rows = [f.result(timeout=180) for f in futs]
+    except FutureTimeout:
+        from tests.test_range_sort import thread_stacks
+        stacks = thread_stacks()
+        for f in futs:          # a cancelled wait for a permit wakes
+            q.cancel(f.query_id)
+        pytest.fail("concurrent queries deadlocked on the device permit:\n"
+                    + stacks)
+    finally:
+        q.close()
+    expected = ordered_agg(
+        TpuSession({"spark.rapids.sql.enabled": "false"}), paths).collect()
+    assert rows == [expected, expected] and len(expected) == 40
+
+
 # -- result cache -------------------------------------------------------------
 
 def _write_parquet(path, seed=0, n=500):

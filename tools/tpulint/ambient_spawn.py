@@ -1,10 +1,9 @@
 """ambient-propagation checker (flow-sensitive).
 
 A worker thread spawned on behalf of a running query must inherit the
-thread-ambient context -- tenant scope, task priority, CancelToken, and
-device-semaphore cover (utils/ambient.py docstring; the PR 9
-pipelined-producer deadlock and PR 10's hand-plumbed producer ambients
-are the motivating defects).  The blessed spawn points are
+thread-ambient context -- tenant scope, task priority, CancelToken
+(utils/ambient.py docstring; PR 10's hand-plumbed producer ambients are
+the motivating defect).  The blessed spawn points are
 ``utils/ambient.spawn_with_ambients`` / ``submit_with_ambients`` (or an
 explicit ``Ambients.capture()`` + ``bind``).
 
@@ -216,7 +215,7 @@ def check(sources: List[SourceFile]) -> List[Violation]:
                 RULE, src.path, hit["line"], hit["scope"],
                 f"bare {what} target '{tname}' reaches engine code "
                 f"({reason}) without inheriting the task ambients "
-                f"(tenant scope, task_priority, CancelToken, semaphore "
-                f"cover) — spawn through utils/ambient."
+                f"(tenant scope, task_priority, CancelToken) — spawn "
+                f"through utils/ambient."
                 f"spawn_with_ambients / submit_with_ambients"))
     return out

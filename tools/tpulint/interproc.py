@@ -245,7 +245,7 @@ def check_ambients(sources: List[SourceFile]) -> List[Violation]:
                 f"bare {what} target '{_bare(fid)}' reaches engine code "
                 f"only visible interprocedurally ({summ.engine}) "
                 f"without inheriting the task ambients (tenant scope, "
-                f"task_priority, CancelToken, semaphore cover) — spawn "
+                f"task_priority, CancelToken) — spawn "
                 f"through utils/ambient.spawn_with_ambients / "
                 f"submit_with_ambients"))
     return out
@@ -525,7 +525,7 @@ def _block_leaf(why: str) -> str:
 def _walk_held(eng: S.SummaryEngine, src: SourceFile, rec: FnRecord,
                body, held: List[tuple], resolver, edges,
                blocking: List[tuple], locky: Set[str]) -> None:
-    from tools.tpulint.locks import THROTTLE_CTORS
+    from tools.tpulint.locks import BLOCKING_EXPECTED_CTORS
     for stmt in body:
         if isinstance(stmt, (ast.FunctionDef, ast.AsyncFunctionDef)):
             continue
@@ -539,7 +539,8 @@ def _walk_held(eng: S.SummaryEngine, src: SourceFile, rec: FnRecord,
                        edges, blocking, locky)
             continue
         if held:
-            real_held = [h for h in held if h[1] not in THROTTLE_CTORS]
+            real_held = [h for h in held
+                         if h[1] not in BLOCKING_EXPECTED_CTORS]
             for sub in ast.walk(stmt):
                 if isinstance(sub, (ast.FunctionDef,
                                     ast.AsyncFunctionDef, ast.Lambda)):

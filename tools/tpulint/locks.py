@@ -31,12 +31,18 @@ from tools.tpulint.core import ScopedVisitor, SourceFile, Violation, dotted
 
 RULE = "lock-order"
 
-LOCK_CTORS = {"Lock", "RLock", "Condition", "BoundedSemaphore", "Semaphore"}
+LOCK_CTORS = {"Lock", "RLock", "Condition", "BoundedSemaphore", "Semaphore",
+              "MaterializeLock"}
 REENTRANT_CTORS = {"RLock"}
 #: semaphores bound concurrency rather than guard invariants: they appear
 #: as graph nodes but blocking calls under them are expected (that's their
 #: job) and are not reported
 THROTTLE_CTORS = {"BoundedSemaphore", "Semaphore"}
+#: blocking calls under these are not reported.  plan/execs/base.py's
+#: materialise-once lock is held across a child's whole execution by
+#: design and its waiters hold no device permit; order edges and
+#: self-deadlock still apply to it
+BLOCKING_EXPECTED_CTORS = THROTTLE_CTORS | {"MaterializeLock"}
 
 #: dotted-suffix -> blocking category
 BLOCKING_SUFFIXES = {
@@ -147,7 +153,10 @@ class _Analyzer(ScopedVisitor):
         params = {a.arg for a in args.args + args.kwonlyargs
                   + args.posonlyargs}
         self.param_stack.append(params)
+        held = len(self.held)
         ScopedVisitor._visit_def(self, node)
+        # an explicit .acquire() holds to the end of ITS function only
+        del self.held[held:]
         self.param_stack.pop()
 
     visit_FunctionDef = _visit_def
@@ -214,7 +223,11 @@ class _Analyzer(ScopedVisitor):
         bare = name.rsplit(".", 1)[-1]
         # explicit .acquire() counts as taking the lock for the rest of
         # the function (approximate: we don't track release())
-        if bare == "acquire" and isinstance(node.func, ast.Attribute):
+        # (a try-lock, blocking=False, never waits: not an acquisition)
+        if bare == "acquire" and isinstance(node.func, ast.Attribute) \
+                and not any(k.arg == "blocking"
+                            and getattr(k.value, "value", None) is False
+                            for k in node.keywords):
             hit = self.resolve(node.func.value)
             if hit is not None:
                 self._acquire(hit, node.lineno)
@@ -241,7 +254,7 @@ class _Analyzer(ScopedVisitor):
 
     def _innermost_real_lock(self) -> Optional[Tuple[str, str]]:
         for lock_id, ctor in reversed(self.held):
-            if ctor not in THROTTLE_CTORS:
+            if ctor not in BLOCKING_EXPECTED_CTORS:
                 return lock_id, ctor
         return None
 

@@ -282,12 +282,39 @@ def seg_max(col: DeviceColumn, layout: GroupedLayout) -> Tuple[jax.Array, jax.Ar
     return out, nvalid > 0
 
 
-def group_keys_output(layout: GroupedLayout, key_cols: Sequence[int]) -> List[DeviceColumn]:
-    """Gather the first row of each group for the key output columns."""
+def string_plane_capacity(col: DeviceColumn, rows: int,
+                          max_bytes: int) -> Optional[int]:
+    """Bytes that ``rows`` rows of a string column need when none is longer
+    than ``max_bytes`` (never more than the column has); None, "as the
+    source", for any other column and where no bound is known."""
+    if not (max_bytes and col.is_string_like):
+        return None
+    return min(col.byte_capacity, rows * max_bytes)
+
+
+def group_keys_output(layout: GroupedLayout, key_cols: Sequence[int],
+                      out_capacity: Optional[int] = None,
+                      string_max_bytes: int = 0) -> List[DeviceColumn]:
+    """Gather the first row of each group for the key output columns.
+
+    The columns have the sorted batch's capacity, or ``out_capacity`` rows
+    where one is given: the gather then reads the first ``out_capacity``
+    group starts only, and a string key's byte plane holds ``out_capacity
+    × string_max_bytes`` bytes (a string gather costs by its output byte
+    plane).  ``string_max_bytes`` is the bucket that ``group_rows`` sorted
+    and compared under, so no live key is longer.  Nobody here checks that
+    ``layout.num_groups`` fits ``out_capacity``: the caller reports the
+    group count to whoever chose the capacity and discards an output that
+    overflowed (``plan/fused.py``'s ``g<pos>`` feedback)."""
     indices, count = compaction_map(layout.boundary)
+    cols = [layout.sorted_batch.columns[ci] for ci in key_cols]
     return [
-        gather_column(layout.sorted_batch.columns[ci], indices, count)
-        for ci in key_cols
+        gather_column(
+            col, indices, count, out_capacity=out_capacity,
+            out_byte_capacity=(
+                None if out_capacity is None else
+                string_plane_capacity(col, out_capacity, string_max_bytes)))
+        for col in cols
     ]
 
 

@@ -330,6 +330,18 @@ def _global_m2_merge(m2col: DeviceColumn, scol: DeviceColumn,
     return m2, n > 0
 
 
+def _head_rows(col: DeviceColumn, capacity: int,
+               max_bytes: int) -> DeviceColumn:
+    """The first ``capacity`` rows of a buffer-slot column whose live rows
+    are a prefix (one a group): slices, no gather.  A string column keeps
+    ``capacity × max_bytes`` bytes where the caller knows that none of its
+    rows is longer; with ``max_bytes`` 0, and for an array column (collect,
+    HLL, t-digest), whose rows nothing bounds, the byte or element plane
+    stays the source's."""
+    return col.with_capacity(
+        capacity, G.string_plane_capacity(col, capacity, max_bytes))
+
+
 # update ops whose keyless form (the nkeys == 0 branch of _partial_step)
 # reads ``live`` as a boolean mask and nowhere as "the first num_rows rows":
 # each masks values to an identity, counts the mask, or takes the first/last
@@ -452,8 +464,22 @@ class _AggDeviceSpec:
             for _, slot in self.slot_specs)
 
     def _partial_step(self, batch: ColumnarBatch, string_bucket: int = 0,
-                      live: Optional[jax.Array] = None) -> ColumnarBatch:
+                      live: Optional[jax.Array] = None,
+                      group_capacity: Optional[int] = None) -> ColumnarBatch:
         """Raw rows -> one partial batch (keys + buffers), grouped in-batch.
+
+        The partial batch has one row a group.  Keyless, its capacity is 1.
+        Grouped, its capacity is the input's (every batch fits its own
+        groups: ``_jit_partial``, ``parallel/stage.py``) unless
+        ``group_capacity`` (static, at most the input's capacity) is given:
+        the batch then has that many rows, string keys and min/max string
+        buffers ``group_capacity × string_bucket`` bytes (they were
+        compared under the bucket; every other string or array buffer
+        keeps its source plane), and its ``num_rows`` is still the true
+        group count.  Nothing in here validates it: a caller
+        that passes a ``group_capacity`` reads ``num_rows`` back and
+        discards a batch whose ``num_rows`` exceeds its capacity, as
+        ``plan/fused.py`` does through its ``g<pos>`` feedback.
 
         ``live``: the rows that count, where they are not the prefix
         ``batch.live_mask()`` (keyless only: ``reduces_under_mask``)."""
@@ -544,7 +570,11 @@ class _AggDeviceSpec:
         layout = G.group_rows(work, list(range(nkeys)),
                               string_max_bytes=string_bucket,
                               allow_split_groups=True)
-        out_keys = G.group_keys_output(layout, list(range(nkeys)))
+        # keys are born at the group capacity; a buffer slot is reduced
+        # over the input's capacity and keeps its first rows (_head_rows)
+        out_keys = G.group_keys_output(layout, list(range(nkeys)),
+                                       out_capacity=group_capacity,
+                                       string_max_bytes=string_bucket)
         cols = list(out_keys)
         for ai, slot in self.slot_specs:
             agg = self.aggregates[ai]
@@ -602,6 +632,16 @@ class _AggDeviceSpec:
             cols.append(G.finalize_agg_column(
                 v.astype(slot.dtype.jnp_dtype), valid, layout.num_groups,
                 slot.dtype))
+        if group_capacity is not None:
+            # the bucket bounds a string buffer only where its input was
+            # order-compared under it (min/max, a max_by/min_by ordering
+            # key: plain column references, like the keys); a picked value
+            # (first/last, max_by/min_by of any expression) can be longer
+            ordered = set(self._string_order_slots())
+            cols[nkeys:] = [
+                _head_rows(c, group_capacity,
+                           string_bucket if si in ordered else 0)
+                for si, c in enumerate(cols[nkeys:])]
         return ColumnarBatch(tuple(cols), layout.num_groups, self.partial_schema)
 
     def _merge_step(self, partial: ColumnarBatch,

@@ -81,6 +81,8 @@ class _LaunchStats:
     lock = threading.Lock()
     count = 0
     by_program: Dict[str, int] = {}     # program name -> launches since reset
+    discarded: Dict[str, int] = {}      # reason -> launches whose output a
+                                        # fused segment threw away
 
 
 #: runtime-sanitizer compile-budget seam (utils/sanitizer.py): called
@@ -98,16 +100,33 @@ def reset_launch_stats() -> None:
     with _LaunchStats.lock:
         _LaunchStats.count = 0
         _LaunchStats.by_program = {}
+        _LaunchStats.discarded = {}
 
 
 def launch_stats() -> dict:
     """``by_program``: launches per program NAME — the name the jitted
     function carries (``program_name``), so the same string names the
-    program in the device trace's ``XLA Modules`` line."""
+    program in the device trace's ``XLA Modules`` line.
+
+    ``discarded``: of ``launches``, those whose output a fused segment
+    threw away and ran again larger (``plan/fused.py`` ``_converge``), by
+    what had been speculated too small: ``bucket`` (the string byte
+    window), ``join_cap`` (a join's output rows or gather bytes),
+    ``group_cap`` (a grouped partial aggregate's output rows).  A launch
+    short of two of them counts under both.  Empty once a plan's
+    capacities have converged."""
     with _LaunchStats.lock:
         return {"launches": _LaunchStats.count,
                 "programs": len(_LaunchStats.by_program),
-                "by_program": dict(_LaunchStats.by_program)}
+                "by_program": dict(_LaunchStats.by_program),
+                "discarded": dict(_LaunchStats.discarded)}
+
+
+def count_discarded_launch(*reasons: str) -> None:
+    with _LaunchStats.lock:
+        for reason in reasons:
+            _LaunchStats.discarded[reason] = \
+                _LaunchStats.discarded.get(reason, 0) + 1
 
 
 def program_name(kind: str, key: str) -> str:

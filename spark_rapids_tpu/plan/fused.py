@@ -22,9 +22,10 @@ recorded in CHANGES.md (PR 22):
     extra pytree arguments;
   * dynamic output sizes keep the engine's static-capacity contract: the
     fused program returns a feedback dict of true requirements (join rows,
-    per-plane gather bytes); the host escalates capacities and re-runs
-    (memory/retry.py discipline).  Converged capacities are cached per
-    plan signature so later batches and identical queries launch once;
+    per-plane gather bytes, a grouped partial aggregate's group count);
+    the host escalates capacities and re-runs (memory/retry.py
+    discipline).  Converged capacities are cached per plan signature so
+    later batches and identical queries launch once;
   * the jitted program is shared via shared_jit keyed on the canonical
     segment signature + capacities + string bucket, so identical plans
     reuse compiled programs across queries.
@@ -55,6 +56,7 @@ from spark_rapids_tpu.plan.execs.base import (
     TpuExec,
     bind_trace_consts,
     collect_trace_consts,
+    count_discarded_launch,
     shared_jit,
     timed,
     tree_uses_string_bucket,
@@ -75,6 +77,21 @@ _FUSED_CAPS_LOCK = threading.Lock()
 # accumulate entries forever in a long-lived session).
 _FUSED_BUCKET: "collections.OrderedDict[str, int]" = \
     collections.OrderedDict()
+
+
+#: rows a grouped partial aggregate's output starts at inside a fused
+#: program (a smaller input keeps its own capacity): the floor maybe_shrink
+#: cuts a batch to.  A batch with more groups re-runs at its input's
+#: capacity (_emit_node asks for it, _converge escalates) and the
+#: signature remembers that: one discarded launch and one more program,
+#: however the group counts of later batches grow.
+GROUP_CAP_DEFAULT = 4096
+
+#: what a caps / feedback key sizes, by its first letter ("j<pos>" and
+#: "j<pos>|b<i>": a join's output rows and gather bytes; "g<pos>": a
+#: grouped partial aggregate's output rows): the reason a launch that fell
+#: short of it is counted under in launch_stats()["discarded"]
+_CAP_REASON = {"j": "join_cap", "g": "group_cap"}
 
 
 def _remember_bucket(sig: str, bucket: int) -> None:
@@ -699,11 +716,16 @@ class TpuFusedSegmentExec(TpuExec):
     def _run(self, stream, builds, slice_spec=None, chain=None, sig=None):
         """One program call as one ``fused.batch`` span: everything the
         host does for it (``stream`` arrives already pulled)."""
-        with trace_range("fused.batch"):
-            return self._converge(stream, builds, slice_spec, chain, sig)
+        with trace_range("fused.batch") as span:
+            return self._converge(stream, builds, slice_spec, chain, sig,
+                                  span)
 
-    def _converge(self, stream, builds, slice_spec, chain, sig):
+    def _converge(self, stream, builds, slice_spec, chain, sig, span):
         """Converge-and-execute one program call.
+
+        Every launch but the last is discarded and counted by what was
+        too small (``launch_stats()["discarded"]``); ``span`` gets the
+        number of launches as its ``attempts`` tag.
 
         ``stream`` is a single ColumnarBatch (per-batch path) or a LIST
         of StreamPieces (across-shuffle path: the group concats inside
@@ -771,7 +793,7 @@ class TpuFusedSegmentExec(TpuExec):
         caps_key = None
         caps: Dict[str, int] = {}
         kind = _program_kind(chain, slice_spec)
-        for _ in range(24):
+        for attempt in range(1, 25):
             new_key = f"{sig}|bkt={bucket}"
             if new_key != caps_key:      # first pass, or bucket escalated
                 caps_key = new_key
@@ -799,15 +821,18 @@ class TpuFusedSegmentExec(TpuExec):
                     with _FUSED_CAPS_LOCK:
                         _remember_bucket(base_sig, need)
                     bucket = need
+                    count_discarded_launch("bucket")
                     continue
-            escalated = False
+            escalated = set()
             for k, v in fetched.items():
                 req = int(v)
                 if req > caps.get(k, 0):
                     caps[k] = round_up_pow2(max(req, 1))
-                    escalated = True
+                    escalated.add(_CAP_REASON[k[0]])
             if escalated:
+                count_discarded_launch(*escalated)
                 continue
+            span.tags = {"attempts": attempt}
             # tracing seeded the capacity defaults AFTER build_key was
             # formed; register the program under the converged key too so
             # the next batch (and the next identical query) hits the jit
@@ -975,7 +1000,16 @@ def _make_program(chain: List[TpuExec], join_build_ix: Dict[int, int],
     applied IN-TRACE to the (raw) build batch before the join reads it —
     the dim-build fold; the byte maxima feeding the speculative bucket
     are observed on the RAW build (a superset: the admitted ops never
-    grow strings)."""
+    grow strings).
+
+    Capacities: a join's output has ``caps["j<pos>"]`` rows, a grouped
+    partial aggregate's ``caps["g<pos>"]`` (``GROUP_CAP_DEFAULT`` at
+    first; the input's capacity where that is no larger), and so has
+    everything above it in the chain and the batch the slice partitions.
+    The program checks neither: it reports the rows each needs in ``fb``
+    under the same key (an aggregate whose groups did not fit: its
+    input's capacity), and ``_converge`` discards the output and runs
+    again larger when one did not fit."""
 
     masked = _masked_filters(chain)
 
@@ -1159,8 +1193,27 @@ def _emit_node(node, pos: int, cur: ColumnarBatch, builds: tuple,
                           bucket, caps, feedback), None
 
     assert isinstance(node, TpuHashAggregateExec), type(node).__name__
-    return node._spec._partial_step(cur, string_bucket=bucket,
-                                    live=live), None
+    spec = node._spec
+    if not spec.group_exprs:
+        # one row whatever the input: no capacity to speculate, no caps key
+        return spec._partial_step(cur, string_bucket=bucket, live=live), None
+    # a grouped partial aggregate hands on as many rows as it has groups:
+    # the capacity is speculated like a join's and validated by _converge
+    # from the rows the feedback asks for.  The default is the same
+    # whatever batch is traced first (it is part of the converged cache
+    # key); at the input's capacity or over it the step is the one every
+    # batch fits
+    ck = f"g{pos}"
+    cap = caps.setdefault(ck, GROUP_CAP_DEFAULT)
+    out = spec._partial_step(
+        cur, string_bucket=bucket,
+        group_capacity=cap if cap < cur.capacity else None)
+    # groups that did not fit ask for the input's capacity at once, not
+    # for the next power of two over this batch's count: batches whose
+    # group counts grow would each discard a launch and compile a program
+    n = jnp.asarray(out.num_rows, jnp.int64)
+    feedback[ck] = jnp.where(n > cap, cur.capacity, n)
+    return out, None
 
 
 def _emit_join(node, pos: int, left: ColumnarBatch, right: ColumnarBatch,

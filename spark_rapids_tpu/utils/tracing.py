@@ -92,12 +92,16 @@ class trace_range:
     A class and not a generator: a range with every sink off is two clock
     reads, a push and a pop."""
 
-    __slots__ = ("name", "t0", "t0_epoch", "span_id", "parent", "_ann")
+    __slots__ = ("name", "t0", "t0_epoch", "span_id", "parent", "_ann",
+                 "tags")
 
     def __init__(self, name: str, doc: Optional[str] = None):
         if doc is not None and name not in _registry:
             register_range(name, doc)
         self.name = name
+        #: attributes of this span in the per-query trace (``tags`` of its
+        #: entry in ``spans_snapshot()``), set by the code inside the range
+        self.tags: Optional[dict] = None
 
     def __enter__(self):
         global _TraceAnnotation
@@ -122,7 +126,8 @@ class trace_range:
         tr = obs.current_query_trace()
         if tr is not None:
             tr.record_span(self.name, self.t0_epoch, time.time(),
-                           span_id=self.span_id, parent=self.parent)
+                           tags=self.tags, span_id=self.span_id,
+                           parent=self.parent)
         return False
 
 

@@ -10,6 +10,7 @@ import pytest
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.api.session import TpuSession
 from spark_rapids_tpu.columnar.batch import ColumnarBatch, Schema
+from spark_rapids_tpu.columnar.column import round_up_pow2
 from spark_rapids_tpu.expressions import col, count, lit, sum_
 from tests.test_queries import assert_tpu_cpu_equal
 
@@ -384,18 +385,10 @@ def test_q6_program_holds_no_gather_and_no_scatter(monkeypatch):
 
 def _q1_sliced(s):
     """q1's map side: the segment and the slice the exchange folds in."""
-    from spark_rapids_tpu.plan.execs.exchange import TpuShuffleExchangeExec
     from spark_rapids_tpu.testing import tpch
     plan = tpch.q1(_lineitem_df(s)).order_by("l_linenumber").physical_plan()
-    stack = [plan]
-    while stack:
-        node = stack.pop()
-        if (isinstance(node, TpuShuffleExchangeExec)
-                and "FusedSegment" in type(node.children[0]).__name__):
-            return node.children[0], (tuple(node.keys), node.out_partitions,
-                                      "test")
-        stack.extend(node.children)
-    raise AssertionError(plan.tree_string())
+    seg, keys, n_out = _sliced_segment(plan)
+    return seg, (tuple(keys), n_out, "test")
 
 
 def _filter_topped(s):
@@ -446,3 +439,285 @@ def test_launches_count_under_the_engaged_kind():
     # conf): a digest of the cache key, which the mask is no part of
     assert by["fused_agg_filter_project_slice_d9b1c42e"] == 1, by
     assert not any("mfilter" in n for n in by)
+
+
+# -- a grouped partial aggregate's group capacity (g<pos> caps/feedback) ------
+
+GSCHEMA = Schema.of(a=T.STRING, b=T.STRING, k=T.INT, v=T.DOUBLE)
+
+
+def _group_batches(rows, n_batches, distinct_k):
+    """Batches of ``rows`` rows (capacity: the next power of two): two
+    seven-byte string keys with 3 × 2 values, an int key with
+    ``distinct_k`` values (None: every row its own)."""
+    out = []
+    for bi in range(n_batches):
+        rng = np.random.RandomState(17 + bi)
+        k = (np.arange(rows) + bi * rows if distinct_k is None
+             else rng.randint(0, distinct_k, rows))
+        out.append(ColumnarBatch.from_pydict(
+            {"a": [("A" * 7, "N" * 7, "R" * 7)[i]
+                   for i in rng.randint(0, 3, rows)],
+             "b": [("F" * 7, "O" * 7)[i] for i in rng.randint(0, 2, rows)],
+             "k": k.tolist(),
+             "v": np.round(rng.uniform(-5, 5, rows), 3).tolist()}, GSCHEMA))
+    return out
+
+
+_GROUP_CASES = {
+    # case: (rows a batch, batches, distinct k, group keys,
+    #        capacity of the partial batches, launches discarded by batch)
+    # six groups in a batch of capacity 16,384: born at the default, the
+    # keys' byte planes at 4,096 × 16 bytes for the input's 131,072
+    "low_cardinality_strings": (12000, 2, 1, ("a", "b"), 4096, [0, 0]),
+    # some 6,000 groups in a batch of capacity 16,384: the first batch runs
+    # again at the input's capacity (not at 8,192: one discarded launch and
+    # one more program however later batches' counts grow), the second
+    # batch of the same signature starts there
+    "more_groups_than_default": (12000, 2, 1000, ("a", "b", "k"), 16384,
+                                 [1, 0]),
+    # every key distinct: the step at the input's capacity fits them
+    "all_keys_distinct": (8192, 1, None, ("k",), 8192, [1]),
+    # a batch smaller than the default: its own capacity, nothing discarded
+    "batch_under_default": (700, 1, 50, ("a", "k"), 1024, [0]),
+}
+
+
+def _group_query(s, case):
+    rows, n_batches, distinct_k, keys, _, _ = _GROUP_CASES[case]
+    # two partitions, a batch each: the aggregate plans as partial + final
+    df = s.create_dataframe(_group_batches(rows, n_batches, distinct_k),
+                            num_partitions=2)
+    return (df.filter(col("v") > lit(-4.5)).group_by(*keys)
+            .agg(sum_("v").alias("sv"), count().alias("n")).order_by(*keys))
+
+
+def _sliced_segment(plan):
+    """The map side of the plan's exchange: the fused segment and the
+    slice the exchange folds into its program."""
+    from spark_rapids_tpu.plan.execs.exchange import TpuShuffleExchangeExec
+    from spark_rapids_tpu.plan.fused import TpuFusedSegmentExec
+    stack = [plan]
+    while stack:
+        node = stack.pop()
+        if (isinstance(node, TpuShuffleExchangeExec)
+                and isinstance(node.children[0], TpuFusedSegmentExec)):
+            return node.children[0], node.keys, node.out_partitions
+        stack.extend(node.children)
+    raise AssertionError(plan.tree_string())
+
+
+def _run_sliced(s, case):
+    """Every (partial batch, per-partition counts, launches discarded for
+    it) of the case's map side, on fresh program and capacity caches."""
+    from spark_rapids_tpu.plan.execs.base import (
+        launch_stats, reset_launch_stats)
+    seg, keys, n_out = _sliced_segment(_group_query(s, case).physical_plan())
+    out = []
+    reset_launch_stats()
+    for part in range(seg.num_partitions()):
+        for batch, counts in seg.execute_partition_sliced(part, keys, n_out,
+                                                          "t"):
+            out.append((batch, np.asarray(counts),
+                        launch_stats()["discarded"].get("group_cap", 0)))
+            reset_launch_stats()
+    seg.cleanup()
+    return out
+
+
+def _live_rows(batch):
+    return sorted(zip(*(c.to_pylist(batch.host_num_rows())
+                        for c in batch.columns)), key=repr)
+
+
+@pytest.fixture
+def fresh_program_caches(monkeypatch):
+    """Converged capacities and programs are remembered per signature for
+    the life of the process: a test that counts launches starts clean."""
+    import collections
+
+    from spark_rapids_tpu.plan import fused
+    from spark_rapids_tpu.plan.execs import base
+    monkeypatch.setattr(fused, "_FUSED_CAPS", collections.OrderedDict())
+    monkeypatch.setattr(fused, "_FUSED_BUCKET", collections.OrderedDict())
+    monkeypatch.setattr(base, "_JIT_CACHE", collections.OrderedDict())
+
+
+@pytest.mark.parametrize("case", list(_GROUP_CASES))
+def test_grouped_partial_agg_hands_on_its_group_capacity(
+        case, monkeypatch, fresh_program_caches):
+    """Under a sliced exchange the partial batches have the speculated
+    group capacity, a batch with more groups runs once more and the next
+    one of its signature once, and rows and per-partition counts are those
+    of the step at the input's capacity and of the CPU oracle."""
+    import collections
+
+    from spark_rapids_tpu.kernels.strings import MIN_BUCKET as bucket
+    from spark_rapids_tpu.plan import fused
+    from spark_rapids_tpu.plan.execs import base
+    rows, n_batches, _, _, want_cap, want_discarded = _GROUP_CASES[case]
+    s, _ = _sessions()
+    got = _run_sliced(s, case)
+    assert [b.capacity for b, _, _ in got] == [want_cap] * n_batches
+    assert [d for _, _, d in got] == want_discarded
+    with monkeypatch.context() as m:
+        # the parent's step: a default that no batch is larger than
+        m.setattr(fused, "GROUP_CAP_DEFAULT", 1 << 30)
+        m.setattr(fused, "_FUSED_CAPS", collections.OrderedDict())
+        m.setattr(base, "_JIT_CACHE", collections.OrderedDict())
+        parent = _run_sliced(s, case)
+    assert [b.capacity for b, _, _ in parent] == \
+        [round_up_pow2(rows)] * n_batches
+    assert [d for _, _, d in parent] == [0] * n_batches
+    for (b, counts, _), (pb, pcounts, _) in zip(got, parent):
+        assert counts.tolist() == pcounts.tolist()
+        assert _live_rows(b) == _live_rows(pb)
+        for c, pc in zip(b.columns, pb.columns):
+            if c.is_string_like and want_cap < pb.capacity:
+                assert c.byte_capacity == min(pc.byte_capacity,
+                                              want_cap * bucket)
+    assert_tpu_cpu_equal(lambda sess: _group_query(sess, case),
+                         ignore_order=False)
+
+
+def test_fused_batch_span_says_how_many_launches_it_took(
+        fresh_program_caches):
+    """``attempts`` on ``fused.batch`` and ``launch_stats()["discarded"]``:
+    2 and ``group_cap`` for the batch that outgrew the default, 1 and
+    nothing once the signature remembers its capacity."""
+    from spark_rapids_tpu.plan.execs.base import (
+        launch_stats, reset_launch_stats)
+    from spark_rapids_tpu.utils import tracing
+    s, _ = _sessions()
+    df = _group_query(s, "more_groups_than_default")
+    seen = []
+    tracing.span_log.enabled = True     # a sink on: collect() keeps a trace
+    try:
+        for _ in range(2):
+            reset_launch_stats()
+            assert df.collect()
+            spans = s.last_query_trace.spans_snapshot()
+            seen.append((sorted(sp["tags"]["attempts"] for sp in spans
+                                if sp["name"] == "fused.batch"),
+                         launch_stats()["discarded"]))
+    finally:
+        tracing.span_log.enabled = False
+        tracing.span_log.clear()
+    assert seen == [([1, 2], {"group_cap": 1}), ([1, 1], {})]
+
+
+BSCHEMA = Schema.of(k=T.INT, a=T.STRING, b=T.STRING, s=T.STRING, v=T.DOUBLE,
+                    x=T.INT)
+
+
+def _buffer_batches(rows=12000, groups=4000):
+    """Two batches of capacity 16,384 with ``groups`` groups each, just
+    under the default group capacity: ``a`` and ``b`` are 16-byte strings
+    that follow the key (so whichever row ``first`` picks, its value is the
+    group's), ``s`` a 16-byte string and ``v`` a double that vary by row."""
+    out = []
+    for bi in range(2):
+        rng = np.random.RandomState(29 + bi)
+        k = rng.randint(0, groups, rows)
+        out.append(ColumnarBatch.from_pydict(
+            {"k": k.tolist(),
+             "a": [f"a{i:015d}" for i in k], "b": [f"b{i:015d}" for i in k],
+             "s": [f"s{i:015d}" for i in rng.randint(0, 10**9, rows)],
+             "v": rng.permutation(rows).astype(float).tolist(),
+             "x": rng.randint(0, 50, rows).tolist()}, BSCHEMA))
+    return out
+
+
+def _buffer_aggs():
+    """Case -> aggregates whose partial buffers are strings or arrays."""
+    from spark_rapids_tpu.expressions import (
+        ConcatStrings, approx_count_distinct, approx_percentile,
+        collect_list, first, last, max_, max_by, min_, min_by)
+    ab = ConcatStrings(col("a"), col("b"))      # 32 bytes: twice the bucket
+    return {
+        # 4,000 picked strings of 32 bytes are 128,000 bytes, and 4,096 rows
+        # of the 16-byte bucket 65,536: a picked value keeps the source plane
+        "pick_grown_string": [first(ab), last(ab, ignore_nulls=True)],
+        "pick_by_grown_string": [max_by(ab, "v"), min_by(ab, "v")],
+        # order-compared under the bucket: the plane is cut to 65,536 bytes
+        "extreme_string": [min_("s"), max_("s"), max_by("v", "s")],
+        "collect": [collect_list("x")],
+        "sketches": [approx_count_distinct("x"),
+                     approx_percentile("v", 0.5)],
+    }
+
+
+def _buffer_query(s, case):
+    df = s.create_dataframe(_buffer_batches(), num_partitions=2)
+    aggs = [a.alias(f"a{i}") for i, a in enumerate(_buffer_aggs()[case])]
+    return df.group_by("k").agg(*aggs).order_by("k")
+
+
+@pytest.mark.parametrize("case", list(_buffer_aggs()))
+def test_group_capacity_keeps_string_and_array_buffers_whole(
+        case, fresh_program_caches):
+    """Near ``GROUP_CAP_DEFAULT`` groups, the partial batch at the group
+    capacity holds every string and array buffer whole: a picked string may
+    be longer than the bucket that bounds the keys and the min/max buffers,
+    so only those have their byte planes cut."""
+    from spark_rapids_tpu.kernels.strings import MIN_BUCKET as bucket
+    from spark_rapids_tpu.plan import fused
+    from tests.test_queries import _eq_val
+    # grouped approx_count_distinct plans for the device only where a
+    # batch's registers fit: batchSizeRows × 2^p <= 64 M
+    s = TpuSession({"spark.rapids.sql.enabled": "true",
+                    "spark.rapids.sql.batchSizeRows": "16384"})
+    df = _buffer_query(s, case)
+    seg, keys, n_out = _sliced_segment(df.physical_plan())
+    cap = fused.GROUP_CAP_DEFAULT
+    spec, = (n._spec for n in seg.chain if hasattr(n, "_spec"))
+    nkeys = len(spec.group_exprs)
+    ordered = set(spec._string_order_slots())
+    for part in range(seg.num_partitions()):
+        for batch, _ in seg.execute_partition_sliced(part, keys, n_out, "t"):
+            assert batch.capacity == cap
+            assert 3000 < batch.host_num_rows() <= cap
+            for si, c in enumerate(batch.columns[nkeys:]):
+                if not c.is_string_like:
+                    continue
+                live = int(c.offsets[batch.host_num_rows()])
+                assert live <= c.byte_capacity, (si, live, c.byte_capacity)
+                assert (c.byte_capacity == cap * bucket) == (si in ordered)
+    seg.cleanup()
+    got = df.collect()
+    want = _buffer_query(TpuSession({"spark.rapids.sql.enabled": "false"}),
+                         case).collect()
+    assert len(got) == len(want) > 3900
+    for g, w in zip(got, want):
+        assert _eq_val(tuple(g), tuple(w)), (g, w)
+
+
+@pytest.mark.parametrize("cell_name,qname,program", [
+    # keyless: no capacity, no caps key, the cache key the parent built
+    ("q6_parquet_sf1", "q6", "fused_agg_mfilter_f3e56bb9"),
+    # grouped: named after the key it is first built under, which holds no
+    # capacity yet; the program under the name is a new one
+    ("q1_parquet_sf1", "q1", "fused_agg_filter_slice_933012e0")])
+def test_spec_query_programs_keep_their_names(
+        tmp_path, fresh_program_caches, cell_name, qname, program):
+    """A program's name is a digest of its cache key and part of the
+    persistent compile cache's: the names the chip's cache holds (PERF.md
+    §5) are the names a fresh process gives."""
+    from benchmark import datagen, run as bench_run
+    from spark_rapids_tpu.plan import fused
+    from spark_rapids_tpu.plan.execs.base import (
+        launch_stats, reset_launch_stats)
+    cell = bench_run.load_cell(cell_name)
+    spec = cell.config["tables"]["lineitem"]
+    rows = 6000         # a small table, written as the benchmark writes it
+    files = datagen.write_table(
+        str(tmp_path), cell.tables["lineitem"], "lineitem", rows,
+        spec["files"], rows // spec["files"], 5,
+        spec["scale_factor"] * rows / spec["rows"])
+    client = bench_run.Client(cell, {"lineitem": files}, {"lineitem": rows})
+    reset_launch_stats()
+    assert client.frame(qname).collect()
+    by = launch_stats()["by_program"]
+    assert by.get(program) == 2, by      # a batch from each of two files
+    caps, = fused._FUSED_CAPS.values()
+    assert caps == ({} if qname == "q6" else {"g0": 4096})

@@ -63,7 +63,7 @@ def test_counting_needs_no_arming_and_resets():
     q.collect()
     reset_launch_stats()
     assert launch_stats() == {"launches": 0, "programs": 0,
-                              "by_program": {}}
+                              "by_program": {}, "discarded": {}}
     q.collect()
     once = launch_stats()
     q.collect()

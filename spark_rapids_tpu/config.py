@@ -155,27 +155,6 @@ STAGE_FUSION = conf("spark.rapids.sql.tpu.fuseStages").doc(
     "on a directly attached chip is not measured)."
 ).boolean_conf(True)
 
-FUSION_ACROSS_SHUFFLE = conf("spark.rapids.sql.fusion.acrossShuffle").doc(
-    "Extend stage-segment fusion THROUGH shuffled joins and shuffle "
-    "reads: a fused segment takes a shuffled join's streamed probe side "
-    "as its stream child (the co-partition build side enters the program "
-    "per reduce partition), segments and final aggregates over an "
-    "exchange consume RAW shuffle pieces and concat them inside their "
-    "one program, so reduce-side merge + probe + aggregate (+ the next "
-    "exchange's partition step) launch once per coalesced partition "
-    "group.  Escape hatch for the fused-across-shuffle reduce path; "
-    "per-op execution is identical with it off."
-).boolean_conf(True)
-
-SHUFFLE_PIPELINE_ENABLED = conf("spark.rapids.shuffle.pipeline.enabled").doc(
-    "Pipeline consecutive exchanges: run the map side's child iteration "
-    "(the previous stage's reduce fetch + compute) on a producer thread "
-    "bounded by the fetch in-flight byte window so wire framing/serialize "
-    "overlaps it, and prefetch the next coalesced stream group on the "
-    "fused reduce path.  Counter-proven by pipeline_overlap_ns / "
-    "stage_drain_ns (shuffle/stats.py)."
-).boolean_conf(True)
-
 CONCURRENT_TPU_TASKS = conf("spark.rapids.sql.concurrentGpuTasks").doc(
     "Number of tasks that can hold the device semaphore concurrently "
     "(reference: RapidsConf.scala:637, GpuSemaphore)."
@@ -225,29 +204,6 @@ SHUFFLE_WRITER_THREADS = conf("spark.rapids.shuffle.multiThreaded.writer.threads
 SHUFFLE_READER_THREADS = conf("spark.rapids.shuffle.multiThreaded.reader.threads").doc(
     "Deserializer/reader thread-pool size for the multithreaded shuffle."
 ).int_conf(4)
-
-SHUFFLE_RANGE_SERIALIZE = conf("spark.rapids.shuffle.write.rangeSerialize").doc(
-    "Map-side range serialization for the wire transports (MULTITHREADED/"
-    "MULTIPROCESS): download each partition-ordered map batch ONCE (a "
-    "single batched device-to-host transfer) and frame every partition's "
-    "wire block from host row ranges — no per-partition gather launches, "
-    "no per-column download syncs, no pow2-padded piece staging (the "
-    "reference serializes a row range of the contiguous-split table the "
-    "same way, GpuPartitioning.scala:66 + Kudo). Escape hatch, default "
-    "on; CACHE_ONLY always keeps device-resident spillable slices."
-).boolean_conf(True)
-
-SHUFFLE_CACHE_RANGE_VIEWS = conf("spark.rapids.shuffle.cacheOnly.rangeViews").doc(
-    "Device-resident range views for the CACHE_ONLY shuffle store — the "
-    "device twin of rangeSerialize: the map side stores ONE partition-"
-    "reordered spillable batch per map batch (plus host counts) and each "
-    "reduce partition's block is a (backing, start, count) range view; "
-    "fused consumers slice the view INSIDE their own program, so the "
-    "standalone per-partition slice/gather programs (slice_gather_"
-    "programs) never run.  Non-fused consumers (out-of-core joins, sort) "
-    "get a standalone slice at read time (range_view_materializes). "
-    "Escape hatch, default on; wire transports ignore it."
-).boolean_conf(True)
 
 SHUFFLE_COMPRESSION_CODEC = conf("spark.rapids.shuffle.compression.codec").doc(
     "Compression for shuffle wire buffers: none, zstd, lz4 (reference: "
@@ -990,14 +946,6 @@ class RapidsConf:
         return self.get(SHUFFLE_CHECKSUM_ENABLED)
 
     @property
-    def shuffle_range_serialize(self) -> bool:
-        return self.get(SHUFFLE_RANGE_SERIALIZE)
-
-    @property
-    def shuffle_cache_range_views(self) -> bool:
-        return self.get(SHUFFLE_CACHE_RANGE_VIEWS)
-
-    @property
     def spill_checksum_enabled(self) -> bool:
         return self.get(SPILL_CHECKSUM_ENABLED)
 
@@ -1108,14 +1056,6 @@ class RapidsConf:
     @property
     def fuse_stages(self) -> bool:
         return self.get(STAGE_FUSION)
-
-    @property
-    def fusion_across_shuffle(self) -> bool:
-        return self.get(FUSION_ACROSS_SHUFFLE)
-
-    @property
-    def shuffle_pipeline_enabled(self) -> bool:
-        return self.get(SHUFFLE_PIPELINE_ENABLED)
 
     @property
     def multithreaded_read_threads(self) -> int:

@@ -60,13 +60,7 @@ def initialize_memory(conf) -> None:
     set_network_retry(conf.network_retry_max_attempts,
                       conf.network_retry_base_delay,
                       conf.network_retry_max_delay)
-    from spark_rapids_tpu.shuffle.transport import (set_pipeline_enabled,
-                                                    set_range_serialize,
-                                                    set_range_views,
-                                                    set_replication)
-    set_range_serialize(conf.shuffle_range_serialize)
-    set_range_views(conf.shuffle_cache_range_views)
-    set_pipeline_enabled(conf.shuffle_pipeline_enabled)
+    from spark_rapids_tpu.shuffle.transport import set_replication
     set_replication(conf.shuffle_replication_factor,
                     conf.shuffle_persist_dir,
                     conf.cluster_drain_timeout)

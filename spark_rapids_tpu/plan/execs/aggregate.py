@@ -850,13 +850,7 @@ class TpuHashAggregateExec(TpuExec):
                  agg_exprs: Sequence[Expression],
                  aggregates: List[AggregateFunction],
                  child: TpuExec, schema: Schema, mode: str = "complete",
-                 target_capacity: int = 1 << 20,
-                 fuse_across_shuffle: bool = True):
-        #: final mode over an exchange/reader: consume RAW shuffle pieces
-        #: and run concat + merge + finalize as ONE program per reduce
-        #: partition (the reduce-side merge joins the aggregate program;
-        #: spark.rapids.sql.fusion.acrossShuffle)
-        self.fuse_across_shuffle = fuse_across_shuffle
+                 target_capacity: int = 1 << 20):
         self.group_exprs = tuple(group_exprs)
         self.agg_exprs = tuple(agg_exprs)
         self.aggregates = list(aggregates)
@@ -1042,8 +1036,11 @@ class TpuHashAggregateExec(TpuExec):
         yield self._count_out(out)
 
     def execute_partition(self, idx: int) -> Iterator[ColumnarBatch]:
-        if (self.mode == "final" and self.fuse_across_shuffle
+        if (self.mode == "final"
                 and hasattr(self.children[0], "stream_pieces")):
+            # over an exchange/reader: consume RAW shuffle pieces and run
+            # concat + merge + finalize as ONE program per reduce
+            # partition (the reduce-side merge joins the aggregate program)
             yield from self._execute_final_fused(idx)
             return
         yield from self._execute_default(idx)

@@ -3,10 +3,10 @@
 Reference: GpuShuffleExchangeExecBase.scala:174 (device-side partition/slice
 then hand off to the shuffle manager) + RapidsShuffleInternalManagerBase.
 This v1 is the CACHE_ONLY-mode analog (RapidsCachingWriter:1618): map tasks
-slice batches on device and park each partition's slice in the shuffle
-catalog as a *spillable* handle; reduce tasks concat their partition's
-slices.  The transport SPI seam for ICI/multi-host lives in shuffle/ and
-plugs in here without changing this exec.
+partition batches on device and park each partition-ordered batch in the
+shuffle catalog as ONE *spillable* handle; reduce tasks read their
+partition's row ranges of it.  The transport SPI seam for ICI/multi-host
+lives in shuffle/ and plugs in here without changing this exec.
 
 Partition routing is bit-exact Spark murmur3/pmod (kernels/partition.py), so
 results agree with the CPU oracle row-for-row.
@@ -59,9 +59,10 @@ class TpuShuffleExchangeExec(TpuExec):
     """Two shuffle manager modes, mirroring the reference's mode switch
     (RapidsShuffleInternalManagerBase.scala:1751):
 
-      * CACHE_ONLY: partition slices stay device-resident as spillable
-        handles in the in-process catalog (RapidsCachingWriter analog);
-      * MULTITHREADED: slices are serialized to the tpu-kudo host wire
+      * CACHE_ONLY: partition-ordered map batches stay device-resident
+        as spillable handles in the in-process catalog, each reduce
+        partition's block a range view (RapidsCachingWriter analog);
+      * MULTITHREADED: row ranges are serialized to the tpu-kudo host wire
         format on a writer thread pool and merged back on read
         (RapidsShuffleThreadedWriterBase/ReaderBase analog) — the mode
         that generalizes to multi-host transports.
@@ -176,14 +177,11 @@ class TpuShuffleExchangeExec(TpuExec):
                 self._part_rows[p] += int(host_counts[p])
 
     def _slices(self):
-        """Device-slice write path: (partition, device piece) per
-        non-empty partition of every input batch.  CACHE_ONLY only falls
-        back here when range views are off (its handles must stay
-        device-resident and spillable, so it never takes the wire range
-        path); wire transports fall back when range serialization is off
-        or the schema is nested.  Per-partition row counts are recorded
-        as they stream past — the MapStatus sizes AQE coalescing plans
-        from."""
+        """Device-slice write path of NESTED schemas on wire transports
+        (the range writer frames flat layouts only): (partition, device
+        piece) per non-empty partition of every input batch.  Per-
+        partition row counts are recorded as they stream past — the
+        MapStatus sizes AQE coalescing plans from."""
         from spark_rapids_tpu.plan.execs.out_of_core import slice_by_counts
         for reordered, counts in self._partitioned():
             with timed(self.op_time, "exchange.write"):
@@ -234,9 +232,15 @@ class TpuShuffleExchangeExec(TpuExec):
                             [0] * self.out_partitions))
 
     def _materialize(self):
-        """Run the map side once, writing slices through the transport SPI
+        """Run the map side once, writing through the transport SPI
         (RapidsShuffleTransport.scala:303 analog — the data plane is
-        pluggable; this exec never touches its storage).
+        pluggable; this exec never touches its storage).  The write shape
+        is the map side's one decision, made here from the transport's
+        type and the schema:
+
+          * CACHE_ONLY -> range views (``_range_views``);
+          * wire, flat schema -> range stream (``_range_stream``);
+          * wire, nested schema -> device slices (``_slices``).
 
         On wire transports the map generator (child compute + device
         partition + download — which includes the UPSTREAM exchange's
@@ -247,49 +251,36 @@ class TpuShuffleExchangeExec(TpuExec):
         (shuffle/pipeline.py; counter-proven by stage_drain_ns)."""
         import jax as _jax
 
+        from spark_rapids_tpu.shuffle.pipeline import pipelined
         from spark_rapids_tpu.shuffle.serializer import range_supported
         from spark_rapids_tpu.shuffle.stats import SHUFFLE_COUNTERS
         from spark_rapids_tpu.shuffle.transport import (
-            CacheOnlyTransport, fetch_window_bytes, make_transport,
-            pipeline_enabled, range_serialize_enabled,
-            range_views_enabled)
+            CacheOnlyTransport, fetch_window_bytes, make_transport)
+
         with self._lock:
             if self._transport is None:
-                SHUFFLE_COUNTERS.add(exchange_stages=1)
-                t = make_transport(self.mode, self.out_partitions,
-                                   self.schema, self.writer_threads,
-                                   self.codec)
-                pipe = (pipeline_enabled()
-                        and not isinstance(t, CacheOnlyTransport))
-
                 def nbytes(item) -> int:
                     return sum(getattr(x, "nbytes", 0)
                                for x in _jax.tree_util.tree_leaves(item))
 
-                if (isinstance(t, CacheOnlyTransport)
-                        and range_views_enabled()):
+                SHUFFLE_COUNTERS.add(exchange_stages=1)
+                t = make_transport(self.mode, self.out_partitions,
+                                   self.schema, self.writer_threads,
+                                   self.codec)
+                if isinstance(t, CacheOnlyTransport):
                     # device twin of the wire range path: one spillable
                     # backing per map batch, per-partition range views —
                     # zero slice/gather programs on the map side
                     t.write_partitioned(
                         _spanned_writes(self._range_views()))
-                elif (t.supports_range_write and range_serialize_enabled()
-                        and range_supported(self.schema)):
-                    gen = self._range_stream()
-                    if pipe:
-                        from spark_rapids_tpu.shuffle.pipeline import (
-                            pipelined)
-                        gen = pipelined(gen, nbytes, fetch_window_bytes(),
-                                        name="exchange-map-range")
-                    t.write_batches(_spanned_writes(gen))
+                elif t.supports_range_write and range_supported(self.schema):
+                    t.write_batches(_spanned_writes(pipelined(
+                        self._range_stream(), nbytes, fetch_window_bytes(),
+                        name="exchange-map-range")))
                 else:
-                    gen = self._slices()
-                    if pipe:
-                        from spark_rapids_tpu.shuffle.pipeline import (
-                            pipelined)
-                        gen = pipelined(gen, nbytes, fetch_window_bytes(),
-                                        name="exchange-map-slices")
-                    t.write(_spanned_writes(gen))
+                    t.write(_spanned_writes(pipelined(
+                        self._slices(), nbytes, fetch_window_bytes(),
+                        name="exchange-map-slices")))
                 self._transport = t
             return self._transport
 

@@ -731,8 +731,7 @@ class TpuAdaptiveJoinExec(TpuExec):
                  condition: Optional[Expression] = None,
                  shuffle_mode: str = "CACHE_ONLY",
                  aqe_coalesce: bool = True,
-                 fuse_inner: bool = False,
-                 fuse_across_shuffle: bool = True):
+                 fuse_inner: bool = False):
         super().__init__((left, right), schema)
         self.left_keys = list(left_keys)
         self.right_keys = list(right_keys)
@@ -752,7 +751,6 @@ class TpuAdaptiveJoinExec(TpuExec):
         #: partition while the rest of the plan is fused
         self.aqe_coalesce = aqe_coalesce
         self.fuse_inner = fuse_inner
-        self.fuse_across_shuffle = fuse_across_shuffle
         self._lock = MaterializeLock()
         self._inner: Optional[TpuExec] = None
         self.chosen: Optional[str] = None   # exposed for tests/explain
@@ -840,8 +838,7 @@ class TpuAdaptiveJoinExec(TpuExec):
                     # reduce side runs fused (across the shuffle when the
                     # join qualifies) instead of per-op
                     from spark_rapids_tpu.plan.fused import fuse_segments
-                    inner = fuse_segments(
-                        inner, across_shuffle=self.fuse_across_shuffle)
+                    inner = fuse_segments(inner)
                 self._inner = inner
             return self._inner
 

@@ -63,8 +63,8 @@ def slice_by_counts(
 
     ``count_stat``: record the gather program dispatches in the
     slice_gather_programs shuffle counter — set by the exchange's
-    device-slice map path, the count the CACHE_ONLY range-view store
-    drives to 0 (its views fold the slice into the consumer's program).
+    device-slice map path (the CACHE_ONLY range-view store runs none:
+    its views fold the slice into the consumer's program).
     OOC sub-partitioning keeps its own slicing uncounted: that path is
     not a map-side piece gather.
     """

@@ -7,7 +7,7 @@ runs entirely device-side with no host round-trips between operators
 columnar batch inside the same task).  The per-op task engine here pays a
 program launch per operator per batch; at TPC-DS q3 shape that is ~dozens
 of launches per batch.  What a launch costs on a directly attached chip is
-not measured (BENCH_r04's q3 at 0.47x oracle predates this module).
+not measured.
 
 Design — the middle point between per-op execution and whole-query SPMD
 fusion (parallel/stage.py), whose one-program compile at bench scale is
@@ -173,21 +173,18 @@ def _fusable_shuffled_join(node: TpuExec) -> bool:
                                    "left_anti"))
 
 
-def fuse_segments(root: TpuExec, conf=None,
-                  across_shuffle: Optional[bool] = None) -> TpuExec:
+def fuse_segments(root: TpuExec) -> TpuExec:
     """Planner post-pass: wrap maximal fusable chains (top-down greedy).
 
     Runs after AQE reader insertion and before LORE wrapping.  Skipped for
     ICI/SPMD sessions (parallel/stage.py fuses the whole query instead).
 
-    ``across_shuffle`` (spark.rapids.sql.fusion.acrossShuffle): extend
-    segments THROUGH shuffled joins — the join becomes the chain's tail,
-    its streamed probe side the segment's stream child and its
-    co-partition build a per-partition program argument — and let
-    segments whose stream child is an exchange/reader consume RAW shuffle
-    pieces, so reduce-side merge + probe + aggregate (+ the next
-    exchange's partition step) run as ONE program per coalesced
-    partition group (ROADMAP open item 1)."""
+    Segments extend THROUGH shuffled joins — the join becomes the chain's
+    tail, its streamed probe side the segment's stream child and its
+    co-partition build a per-partition program argument — and segments
+    whose stream child is an exchange/reader consume RAW shuffle pieces,
+    so reduce-side merge + probe + aggregate (+ the next exchange's
+    partition step) run as ONE program per coalesced partition group."""
     from spark_rapids_tpu.plan.execs.join import TpuBroadcastHashJoinExec
 
     from spark_rapids_tpu.plan.execs.exchange import (
@@ -195,10 +192,6 @@ def fuse_segments(root: TpuExec, conf=None,
         TpuSinglePartitionExec)
     from spark_rapids_tpu.plan.execs.join import (
         TpuAdaptiveJoinExec, TpuShuffledHashJoinExec)
-
-    if across_shuffle is None:
-        across_shuffle = (conf.fusion_across_shuffle
-                          if conf is not None else True)
 
     # a stream child on the far side of a shuffle: fusing even a single
     # op above it is worth a segment — the reduce side then runs ONE
@@ -218,8 +211,7 @@ def fuse_segments(root: TpuExec, conf=None,
     _BUILD_CHAIN_OPS = (TpuProjectExec, TpuFilterExec)
 
     def visit(node: TpuExec, under_exchange: bool = False) -> TpuExec:
-        fusable_top = _fusable(node) or (
-            across_shuffle and _fusable_shuffled_join(node))
+        fusable_top = _fusable(node) or _fusable_shuffled_join(node)
         if fusable_top:
             chain = [node]
             cur = node
@@ -227,7 +219,7 @@ def fuse_segments(root: TpuExec, conf=None,
                 while cur.children and _fusable(cur.children[0]):
                     cur = cur.children[0]
                     chain.append(cur)
-                if (across_shuffle and cur.children
+                if (cur.children
                         and _fusable_shuffled_join(cur.children[0])):
                     # the shuffled join joins the chain as its TAIL: its
                     # probe (left) child becomes the stream child, its
@@ -269,7 +261,6 @@ def fuse_segments(root: TpuExec, conf=None,
                     builds.append(visit(broot))
                     build_chains.append(bchain)
                 return TpuFusedSegmentExec(chain, stream_child, builds,
-                                           across_shuffle=across_shuffle,
                                            build_chains=build_chains)
         is_exchange = isinstance(node, TpuShuffleExchangeExec)
         node.children = tuple(visit(c, under_exchange=is_exchange)
@@ -325,13 +316,12 @@ class TpuFusedSegmentExec(TpuExec):
     """
 
     def __init__(self, chain: List[TpuExec], stream_child: TpuExec,
-                 builds: List[TpuExec], across_shuffle: bool = True,
+                 builds: List[TpuExec],
                  build_chains: Optional[List[List[TpuExec]]] = None):
         from spark_rapids_tpu.plan.execs.join import (
             TpuBroadcastHashJoinExec, TpuShuffledHashJoinExec)
         super().__init__((stream_child,) + tuple(builds), chain[0].schema)
         self.chain = chain
-        self.across_shuffle = across_shuffle
         #: per build slot: top-down project/filter chain applied IN-TRACE
         #: to the materialized raw build before the join consumes it (the
         #: dim-build fold — those ops previously ran as standalone
@@ -525,9 +515,8 @@ class TpuFusedSegmentExec(TpuExec):
     def _uses_stream_pieces(self) -> bool:
         """True when the stream child is an exchange/reader whose RAW
         pieces this segment can concat inside its own program (the
-        reduce-side merge joins the fused program; across-shuffle path)."""
-        return (self.across_shuffle
-                and hasattr(self.children[0], "stream_pieces"))
+        reduce-side merge joins the fused program)."""
+        return hasattr(self.children[0], "stream_pieces")
 
     def _stream_groups(self, idx: int, extra_pieces=()):
         """Coalesced piece groups of stream partition ``idx``, bounded by
@@ -541,16 +530,13 @@ class TpuFusedSegmentExec(TpuExec):
         residency degrade check must see the COMBINED pinned set, shared
         backings deduped, or two half-budget checks could jointly pin a
         full budget."""
-        from spark_rapids_tpu.shuffle.transport import (fetch_window_bytes,
-                                                        pipeline_enabled)
+        from spark_rapids_tpu.shuffle.pipeline import pipelined
+        from spark_rapids_tpu.shuffle.transport import fetch_window_bytes
         target = max(int(getattr(self.children[0], "coalesce_target_rows",
                                  1 << 20)), 1)
-        pieces = self.children[0].stream_pieces(idx)
-        if pipeline_enabled():
-            from spark_rapids_tpu.shuffle.pipeline import pipelined
-            pieces = pipelined(pieces, lambda p: p.nbytes,
-                               fetch_window_bytes(),
-                               name="fused-stream-prefetch")
+        pieces = pipelined(self.children[0].stream_pieces(idx),
+                           lambda p: p.nbytes, fetch_window_bytes(),
+                           name="fused-stream-prefetch")
         group, acc = [], 0
         for piece in pieces:
             if group and acc + piece.capacity > target:
@@ -569,7 +555,7 @@ class TpuFusedSegmentExec(TpuExec):
         for bi, root in enumerate(self.children[1:]):
             if self._build_kind[bi] != "part":
                 continue
-            if self.across_shuffle and hasattr(root, "stream_pieces"):
+            if hasattr(root, "stream_pieces"):
                 pieces = list(root.stream_pieces(idx))
             else:
                 pieces = [StreamPiece.of_batch(b)

@@ -994,8 +994,7 @@ class PlanMeta:
                 # post-passes over the tree it builds (plan-time fusion
                 # cannot see it); same gating as plan_query's fusion pass
                 fuse_inner=(self.conf.fuse_stages
-                            and self.conf.shuffle_mode != "ICI"),
-                fuse_across_shuffle=self.conf.fusion_across_shuffle)
+                            and self.conf.shuffle_mode != "ICI"))
         if p.join_type == "cross" or not p.left_keys:
             # cartesian / nested-loop: candidate pairs must see every
             # right row, so both sides collapse to one partition
@@ -1039,8 +1038,7 @@ class PlanMeta:
             exchange = TpuSinglePartitionExec(partial)
         return TpuHashAggregateExec(
             p.group_exprs, p.agg_exprs, p.aggregates, exchange, p.schema,
-            mode="final", target_capacity=self.conf.batch_size_rows,
-            fuse_across_shuffle=self.conf.fusion_across_shuffle)
+            mode="final", target_capacity=self.conf.batch_size_rows)
 
     def _exchange(self, nparts, keys, child) -> TpuExec:
         mode = self.conf.shuffle_mode
@@ -1169,7 +1167,7 @@ def plan_query(plan: L.LogicalPlan, conf: Optional[RapidsConf] = None
         # SPMD compiler must still compile, VERDICT r5 #1a), and ICI
         # sessions fuse the whole query in the SPMD compiler instead.
         from spark_rapids_tpu.plan.fused import fuse_segments
-        exec_plan = fuse_segments(exec_plan, conf)
+        exec_plan = fuse_segments(exec_plan)
     _reset_adaptive_decisions(exec_plan)
     # LORE id assignment + dump wrapping (GpuLore.tagForLore analog,
     # GpuOverrides.scala:5149)

@@ -50,8 +50,9 @@ _FIELDS = (
                               # fused program (no standalone gather)
     "slice_gather_programs",  # standalone map-side piece-gather program
                               # dispatches (slice_by_counts on the
-                              # exchange's device-slice path — the count
-                              # range views drive to 0 on CACHE_ONLY)
+                              # exchange's device-slice path: nested
+                              # schemas on wire transports; 0 on
+                              # CACHE_ONLY, which stores range views)
     "range_view_materializes",  # views sliced by a standalone gather for
                               # a non-fused consumer (the materialize
                               # fallback: OOC joins, sort, per-op reads)

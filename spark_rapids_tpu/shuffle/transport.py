@@ -109,12 +109,12 @@ class StreamPiece:
 
     The fused-across-shuffle reduce path (plan/fused.py) concats pieces
     INSIDE its one program per coalesced partition, so the transport's own
-    merge/concat pass never runs.  A piece wraps a spillable handle
-    (CACHE_ONLY — the piece stays spillable between uses; consumers
-    materialize pin-balanced via coalesce.retry_over_stream_pieces), an
-    already-device batch (wire transports pay their host->device upload in
-    read_iter regardless), or a RANGE VIEW of a shared spillable backing
-    batch (CACHE_ONLY range-view store): materialize_pinned then returns a
+    merge/concat pass never runs.  A piece is an already-device batch
+    (wire transports pay their host->device upload in read_iter
+    regardless) or a RANGE VIEW of a shared spillable backing batch (the
+    CACHE_ONLY store — the backing stays spillable between uses;
+    consumers materialize pin-balanced via
+    coalesce.retry_over_stream_pieces): materialize_pinned then returns a
     RangeView the consumer's program slices in-trace, and pin balancing
     dedupes by ``backing_key`` so a backing batch shared by several views
     pins exactly once per attempt."""
@@ -123,7 +123,9 @@ class StreamPiece:
 
     def __init__(self, capacity: int, nbytes: int, handle=None, batch=None,
                  range_: Optional[Tuple[int, int]] = None):
-        assert (handle is None) != (batch is None)
+        # a device batch, or a backing handle with the view's row range
+        assert (batch is None) == (handle is not None
+                                   and range_ is not None)
         self.capacity = int(capacity)   # static row capacity (grouping)
         self.nbytes = int(nbytes)       # in-flight byte accounting
         self._handle = handle
@@ -133,10 +135,6 @@ class StreamPiece:
     @classmethod
     def of_batch(cls, batch: ColumnarBatch) -> "StreamPiece":
         return cls(batch.capacity, batch.device_size_bytes(), batch=batch)
-
-    @classmethod
-    def of_handle(cls, handle, capacity: int) -> "StreamPiece":
-        return cls(capacity, handle.size_bytes, handle=handle)
 
     @classmethod
     def of_range_view(cls, handle, start: int, count: int,
@@ -171,24 +169,22 @@ class StreamPiece:
         return self._handle.size_bytes
 
     def materialize_pinned(self):
-        """Device data for this piece; a spillable handle gains a pin the
-        caller MUST return via unpin() before its retry attempt ends.
-        Range-view pieces return a RangeView (slice folds into the
+        """Device data for this piece; a view's backing handle gains a
+        pin the caller MUST return via unpin() before its retry attempt
+        ends.  Range-view pieces return a RangeView (slice folds into the
         consumer's program); others return the device batch."""
-        if self._handle is not None:
-            batch = self._handle.materialize()
-            if self._range is not None:
-                try:
-                    return self.as_view(batch)
-                except BaseException:
-                    # the caller only owns the pin once the view is
-                    # RETURNED: a raise in view construction must give
-                    # the materialize pin back or the backing stays
-                    # unspillable with no owner to unpin it
-                    self._handle.unpin()
-                    raise
-            return batch
-        return self._batch
+        if self._handle is None:
+            return self._batch
+        batch = self._handle.materialize()
+        try:
+            return self.as_view(batch)
+        except BaseException:
+            # the caller only owns the pin once the view is RETURNED: a
+            # raise in view construction must give the materialize pin
+            # back or the backing stays unspillable with no owner to
+            # unpin it
+            self._handle.unpin()
+            raise
 
     def as_view(self, backing: ColumnarBatch):
         """The same value materialize_pinned would return, built from an
@@ -314,8 +310,8 @@ class ShuffleTransport(abc.ABC):
     #: True when the transport implements write_batches — the range-
     #: serialization write path (one download per map batch, partition
     #: blocks framed from host row ranges).  CacheOnlyTransport stays
-    #: False: its handles must remain device-resident and spillable, so
-    #: it keeps the device-slice write.
+    #: False: its backings must remain device-resident and spillable, so
+    #: it is written by write_partitioned instead.
     supports_range_write = False
 
     @abc.abstractmethod
@@ -346,8 +342,8 @@ class ShuffleTransport(abc.ABC):
         """Unmerged piece stream for the fused reduce path: StreamPiece
         items the consumer concats INSIDE its own program.  Default wraps
         read_iter's (already merged/uploaded) batches; CACHE_ONLY
-        overrides with the raw spillable handles so nothing merges or
-        pins ahead of the consumer's pin-balanced attempt."""
+        overrides with range views of its spillable backings so nothing
+        merges or pins ahead of the consumer's pin-balanced attempt."""
         for b in self.read_iter(partition, target_rows=target_rows):
             yield StreamPiece.of_batch(b)
 
@@ -361,21 +357,18 @@ class ShuffleTransport(abc.ABC):
 
 
 class CacheOnlyTransport(ShuffleTransport):
-    """Device-resident spillable handles (CACHE_ONLY mode).
+    """Device-resident spillable range views (CACHE_ONLY mode).
 
-    Two write shapes share the store:
-
-      * legacy device-slice blocks (``write``): one spillable handle per
-        non-empty (map batch, partition) gather — the fallback when range
-        views are off;
-      * RANGE-VIEW blocks (``write_partitioned``): ONE spillable handle
-        per map batch (the partition-reordered batch, exactly what the
-        device partition step already produced) plus host counts; each
-        partition's block is a (backing, start, count) view.  No gather
-        programs run on the map side at all — fused consumers slice the
-        view inside their own program (StreamPiece/RangeView), and
-        non-fused consumers get a standalone slice at read time (the
-        materialize fallback, counted range_view_materializes).
+    Written by ``write_partitioned`` only: ONE spillable handle per map
+    batch (the partition-reordered batch, exactly what the device
+    partition step already produced) plus host counts; each partition's
+    block is a (backing, start, count) view.  No gather programs run on
+    the map side at all — fused consumers slice the view inside their own
+    program (StreamPiece/RangeView), and non-fused consumers get a
+    standalone slice at read time (the materialize fallback, counted
+    range_view_materializes).  ``write`` raises: per-partition device
+    pieces are the wire transports' shape, and a second store for them
+    would have no writer.
 
     A backing handle is shared by every partition's view over its map
     batch (partial handle reuse across partitions): the store owns it
@@ -383,19 +376,14 @@ class CacheOnlyTransport(ShuffleTransport):
     matter how many views were consumed, pinned, or never read."""
 
     def __init__(self, num_partitions: int):
-        #: per partition: (handle, static row capacity) — the capacity is
-        #: recorded at write time so the piece stream can group to the
-        #: consumer's coalesce target without materializing anything
-        self._buckets: List[List] = [[] for _ in range(num_partitions)]
         #: per partition: (backing handle, start row, row count, nbytes)
         self._views: List[List] = [[] for _ in range(num_partitions)]
         #: backing handles owned by the view store, one per map batch
         self._backings: List = []
 
     def write(self, pieces):
-        from spark_rapids_tpu.memory.spill import make_spillable
-        for p, piece in pieces:
-            self._buckets[p].append((make_spillable(piece), piece.capacity))
+        raise NotImplementedError(
+            "CacheOnlyTransport stores range views: use write_partitioned")
 
     def write_partitioned(self, batches) -> None:
         """Range-view write path (instead of write()): consume
@@ -407,9 +395,9 @@ class CacheOnlyTransport(ShuffleTransport):
         for reordered, host_counts in batches:
             total = int(host_counts.sum())
             if total == 0:
-                # no live rows: store nothing (the slice path dropped
-                # such batches too — a backing handle nobody views would
-                # hold dead spillable residency until cleanup)
+                # no live rows: store nothing (a backing handle nobody
+                # views would hold dead spillable residency until
+                # cleanup)
                 continue
             h = make_spillable(reordered)
             self._backings.append(h)
@@ -425,30 +413,17 @@ class CacheOnlyTransport(ShuffleTransport):
             SHUFFLE_COUNTERS.add(range_view_blocks=nblocks)
 
     def read(self, partition: int) -> List[ColumnarBatch]:
-        # the returned batches ALIAS the handles' device buffers, so the
-        # pins deliberately hold until cleanup() closes the store —
-        # unpinning would let spill free data the consumer still reads,
-        # and a failed read tears down the whole query (cleanup closes
-        # pinned handles fine)
-        # tpu-lint: allow-pin-balance(CACHE_ONLY read hands out aliases of the handles' device batches; the pin IS the lifetime contract, released by cleanup/close)
-        out = [h.materialize() for h, _cap in self._buckets[partition]]
-        for h, start, cnt, nbytes in self._views[partition]:
-            out.append(materialize_view_batch(
-                StreamPiece.of_range_view(h, start, cnt, nbytes)))
-        return out
+        # each view is sliced into an INDEPENDENT batch, its backing pin
+        # taken and returned inside the slice's own retry attempt
+        return [materialize_view_batch(p)
+                for p in self.read_pieces(partition)]
 
     def read_pieces(self, partition: int,
                     target_rows: Optional[int] = None):
-        for h, cap in self._buckets[partition]:
-            yield StreamPiece.of_handle(h, cap)
         for h, start, cnt, nbytes in self._views[partition]:
             yield StreamPiece.of_range_view(h, start, cnt, nbytes)
 
     def cleanup(self) -> None:
-        for bucket in self._buckets:
-            for h, _cap in bucket:
-                h.close()
-            bucket.clear()
         for h in self._backings:
             h.close()
         self._backings.clear()
@@ -665,54 +640,6 @@ _completeness_timeout_s: float = 120.0
 def set_completeness_timeout(seconds: float) -> None:
     global _completeness_timeout_s
     _completeness_timeout_s = float(seconds)
-
-
-#: map-side range serialization (spark.rapids.shuffle.write.rangeSerialize):
-#: frame partition wire blocks from row ranges of ONE downloaded batch
-#: instead of downloading a gathered device slice per partition.  Escape
-#: hatch, default on; CACHE_ONLY ignores it (device-resident handles).
-_RANGE_SERIALIZE = [True]
-
-
-def set_range_serialize(enabled: bool) -> None:
-    _RANGE_SERIALIZE[0] = bool(enabled)
-
-
-def range_serialize_enabled() -> bool:
-    return _RANGE_SERIALIZE[0]
-
-
-#: CACHE_ONLY range-view store (spark.rapids.shuffle.cacheOnly.rangeViews):
-#: store ONE partition-reordered spillable batch per map batch and hand
-#: consumers (backing, start, count) range views instead of running a
-#: standalone slice/gather program per partition — the device twin of the
-#: wire path's rangeSerialize.  Escape hatch, default on; wire transports
-#: ignore it.
-_RANGE_VIEWS = [True]
-
-
-def set_range_views(enabled: bool) -> None:
-    _RANGE_VIEWS[0] = bool(enabled)
-
-
-def range_views_enabled() -> bool:
-    return _RANGE_VIEWS[0]
-
-
-#: pipelined exchanges (spark.rapids.shuffle.pipeline.enabled): run the
-#: map side's child iteration (stage k's reduce fetch + compute) on a
-#: producer thread bounded by the fetch in-flight byte window so the
-#: transport's framing/serialize overlaps it, and prefetch the next
-#: stream group on the fused reduce path.  Escape hatch, default on.
-_PIPELINE = [True]
-
-
-def set_pipeline_enabled(enabled: bool) -> None:
-    _PIPELINE[0] = bool(enabled)
-
-
-def pipeline_enabled() -> bool:
-    return _PIPELINE[0]
 
 
 def fetch_window_bytes() -> int:

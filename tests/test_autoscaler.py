@@ -418,6 +418,18 @@ def test_registry_staleness_shares_the_predicate():
 
 # -- real-driver drain handshake + the full loop -------------------------------
 
+@pytest.fixture
+def no_stale_shuffle_node():
+    """``executor_main`` installs its shuffle node process-wide; a test
+    that runs it on a thread of its own takes the node out again, or the
+    next MULTIPROCESS exchange of this process asks a closed driver for
+    its shuffle id."""
+    yield
+    from spark_rapids_tpu.shuffle.transport import (
+        set_process_shuffle_executor)
+    set_process_shuffle_executor(None)
+
+
 def _spawn_executor(driver, eid, stop):
     from spark_rapids_tpu.cluster.executor import executor_main
     t = threading.Thread(
@@ -429,7 +441,7 @@ def _spawn_executor(driver, eid, stop):
     return t
 
 
-def test_request_drain_graceful_handshake():
+def test_request_drain_graceful_handshake(no_stale_shuffle_node):
     """``request_drain`` → the executor's next get_task poll carries
     ``drain: true`` → it leaves gracefully (re-replicates, deregisters,
     thread EXITS) — and a scale-in never costs a scoped resubmit."""
@@ -457,7 +469,7 @@ def test_request_drain_graceful_handshake():
             t.join(timeout=5.0)
 
 
-def test_autoscaler_full_loop_scale_out_join_idle_drain():
+def test_autoscaler_full_loop_scale_out_join_idle_drain(no_stale_shuffle_node):
     """The tentpole end to end over a REAL driver: pressure scales out
     a real executor rank (it registers), sustained idle drains it
     gracefully, counters and flight-recorder events tell the story, and

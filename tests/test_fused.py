@@ -435,9 +435,11 @@ def test_launches_count_under_the_engaged_kind():
     reset_launch_stats()
     assert tpch.q1(df).order_by("l_linenumber").collect()
     by = launch_stats()["by_program"]
-    # the name PR 27's tree gave this program (same data, same session
-    # conf): a digest of the cache key, which the mask is no part of
-    assert by["fused_agg_filter_project_slice_d9b1c42e"] == 1, by
+    # the name this program has since PR 31 took `fuse_across_shuffle` out
+    # of the aggregate's key (`_d9b1c42e` from PR 27 until then; same data,
+    # same session conf): a digest of the cache key, which the mask is no
+    # part of
+    assert by["fused_agg_filter_project_slice_21306a13"] == 1, by
     assert not any("mfilter" in n for n in by)
 
 
@@ -694,10 +696,10 @@ def test_group_capacity_keeps_string_and_array_buffers_whole(
 
 @pytest.mark.parametrize("cell_name,qname,program", [
     # keyless: no capacity, no caps key, the cache key the parent built
-    ("q6_parquet_sf1", "q6", "fused_agg_mfilter_f3e56bb9"),
+    ("q6_parquet_sf1", "q6", "fused_agg_mfilter_43815208"),
     # grouped: named after the key it is first built under, which holds no
     # capacity yet; the program under the name is a new one
-    ("q1_parquet_sf1", "q1", "fused_agg_filter_slice_933012e0")])
+    ("q1_parquet_sf1", "q1", "fused_agg_filter_slice_2051f78e")])
 def test_spec_query_programs_keep_their_names(
         tmp_path, fresh_program_caches, cell_name, qname, program):
     """A program's name is a digest of its cache key and part of the

@@ -2,8 +2,8 @@
 across-shuffle path; ROADMAP open item 1).
 
 Differential discipline: every fused-across-shuffle result is checked
-against the per-op engine (fuseStages=false), against the segment path
-with the across-shuffle hatch closed, and against the CPU oracle.  The
+against the per-op engine (fuseStages=false, under which a final
+aggregate still folds its exchange's pieces) and against the CPU oracle.  The
 counter-pinned tests prove the perf CLAIM: one fused program per
 coalesced reduce partition group (merge + probe + aggregate + the next
 exchange's partition step), and a stage hand-off that never drains.
@@ -57,15 +57,11 @@ SHUFFLED = {"spark.rapids.sql.enabled": "true",
 
 
 def _sessions():
+    """(fused, per-op) engines over the same forced-shuffle conf."""
     return (
         TpuSession(dict(SHUFFLED)),
         TpuSession(dict(SHUFFLED,
-                        **{"spark.rapids.sql.fusion.acrossShuffle":
-                           "false"})),
-        TpuSession(dict(SHUFFLED,
-                        **{"spark.rapids.sql.tpu.fuseStages": "false",
-                           "spark.rapids.sql.fusion.acrossShuffle":
-                           "false"})),
+                        **{"spark.rapids.sql.tpu.fuseStages": "false"})),
     )
 
 
@@ -93,24 +89,17 @@ def _norm(rows):
     "sk",                                        # variant (richer path)
 ])
 def test_shuffled_join_agg_differential(key):
-    """Fused-across-shuffle vs hatch-closed vs per-op vs oracle, over
-    null-heavy int and STRING join keys."""
+    """Fused-across-shuffle vs per-op vs oracle, over null-heavy int and
+    STRING join keys."""
     fact = [_fact(seed=1), _fact(seed=2, n=3000)]
     dim = [_dim(seed=3)]
-    fused_s, hatch_s, perop_s = _sessions()
+    fused_s, perop_s = _sessions()
     rows_f = _join_agg_query(fused_s, fact, dim, key=key).collect()
-    rows_h = _join_agg_query(hatch_s, fact, dim, key=key).collect()
     rows_p = _join_agg_query(perop_s, fact, dim, key=key).collect()
-    assert _norm(rows_f) == _norm(rows_h) == _norm(rows_p)
+    assert _norm(rows_f) == _norm(rows_p)
     assert rows_f
     assert_tpu_cpu_equal(
-        lambda s: _join_agg_query(
-            TpuSession(dict(SHUFFLED,
-                            **{"spark.rapids.sql.enabled":
-                               s.conf.get_raw("spark.rapids.sql.enabled")
-                               if hasattr(s.conf, "get_raw") else "true"})),
-            fact, dim, key=key)
-        if False else _join_agg_query(s, fact, dim, key=key),
+        lambda s: _join_agg_query(s, fact, dim, key=key),
         ignore_order=False)
 
 
@@ -118,7 +107,7 @@ def test_shuffled_join_skew_differential():
     """A hot build-side key (skew) through the fused path."""
     fact = [_fact(seed=21, skew_frac=0.5)]
     dim = [_dim(seed=22)]
-    fused_s, _hatch_s, perop_s = _sessions()
+    fused_s, perop_s = _sessions()
     rows_f = _join_agg_query(fused_s, fact, dim).collect()
     rows_p = _join_agg_query(perop_s, fact, dim).collect()
     assert _norm(rows_f) == _norm(rows_p)
@@ -129,7 +118,7 @@ def test_shuffled_join_skew_differential():
 def test_shuffled_join_types_across_shuffle(how):
     fact = [_fact(seed=31, n=2500)]
     dim = [_dim(seed=32, n=900)]
-    fused_s, _hatch_s, perop_s = _sessions()
+    fused_s, perop_s = _sessions()
     rows_f = _join_agg_query(fused_s, fact, dim, how=how).collect()
     rows_p = _join_agg_query(perop_s, fact, dim, how=how).collect()
     assert _norm(rows_f) == _norm(rows_p)
@@ -139,19 +128,15 @@ def test_shuffled_join_types_across_shuffle(how):
         ignore_order=False)
 
 
-def test_plan_fuses_shuffled_join_and_hatch_closes():
-    fused_s, hatch_s, _perop_s = _sessions()
+def test_plan_fuses_shuffled_join():
+    fused_s, _perop_s = _sessions()
     fact = [_fact(seed=41)]
     dim = [_dim(seed=42)]
     plan_f = _join_agg_query(fused_s, fact, dim).physical_plan()
     tree_f = plan_f.tree_string()
     assert "TpuFusedSegment" in tree_f
-    # the shuffled join is INSIDE a segment (a chain "* ..." member)...
+    # the shuffled join is INSIDE a segment (a chain "* ..." member)
     assert "* TpuShuffledHashJoin" in tree_f
-    # ...and with the hatch closed it stands alone again
-    tree_h = _join_agg_query(hatch_s, fact, dim).physical_plan() \
-        .tree_string()
-    assert "* TpuShuffledHashJoin" not in tree_h
 
 
 def test_q25_shape_one_program_per_reduce_partition():
@@ -169,7 +154,7 @@ def test_q25_shape_one_program_per_reduce_partition():
     dim = [_dim(seed=53, n=4000, null_frac=0.0)]
 
     stats = {}
-    for name, s in (("fused", _sessions()[0]), ("perop", _sessions()[2])):
+    for name, s in zip(("fused", "perop"), _sessions()):
         q = _join_agg_query(s, fact, dim)
         q.collect()                    # warm: compile + converge caps
         reset_launch_stats()
@@ -213,8 +198,7 @@ def test_oversized_build_falls_back_out_of_core():
     sc = local_shuffle_counters()
     assert sc["fused_reduce_fallbacks"] >= 1, sc
     perop_s = TpuSession(dict(
-        conf, **{"spark.rapids.sql.tpu.fuseStages": "false",
-                 "spark.rapids.sql.fusion.acrossShuffle": "false"}))
+        conf, **{"spark.rapids.sql.tpu.fuseStages": "false"}))
     rows_p = _join_agg_query(perop_s, fact, [hot]).collect()
     assert _norm(rows_f) == _norm(rows_p)
     assert rows_f
@@ -266,24 +250,6 @@ def test_pipelined_exchange_overlap_counters():
     # ≈0: an order of magnitude under the proven overlap (scheduling
     # jitter allowance; a barriered hand-off would dwarf the overlap)
     assert sc["stage_drain_ns"] < max(sc["pipeline_overlap_ns"], 10**7), sc
-
-
-@pytest.mark.slow
-def test_pipeline_escape_hatch():
-    conf = dict(SHUFFLED,
-                **{"spark.rapids.shuffle.mode": "MULTITHREADED",
-                   "spark.rapids.shuffle.pipeline.enabled": "false"})
-    from spark_rapids_tpu.cluster.stats import (
-        local_shuffle_counters, reset_local_shuffle_counters)
-    fact = [_fact(seed=91, n=4000)]
-    dim = [_dim(seed=92)]
-    s = TpuSession(conf)
-    reset_local_shuffle_counters()
-    rows_off = _join_agg_query(s, fact, dim).collect()
-    sc = local_shuffle_counters()
-    assert sc["pipeline_overlap_ns"] == 0 and sc["stage_drain_ns"] == 0, sc
-    rows_on = _join_agg_query(_sessions()[0], fact, dim).collect()
-    assert _norm(rows_off) == _norm(rows_on)
 
 
 def test_adaptive_join_runtime_decision_fuses():
@@ -431,8 +397,7 @@ def test_dim_build_fold_gated_by_raw_build_size():
     assert not any(k.startswith("buildchain_") for k in prof_small), \
         sorted(k for k in prof_small if k.startswith("buildchain"))
     perop = TpuSession(dict(
-        conf, **{"spark.rapids.sql.tpu.fuseStages": "false",
-                 "spark.rapids.sql.fusion.acrossShuffle": "false"}))
+        conf, **{"spark.rapids.sql.tpu.fuseStages": "false"}))
     assert _norm(rows_big) == _norm(q(perop, 3000).collect())
     assert _norm(rows_small) == _norm(q(perop, 600).collect())
     assert rows_big and rows_small

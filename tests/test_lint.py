@@ -619,7 +619,8 @@ def test_retry_over_stream_pieces_is_pin_balanced():
     handles = [make_spillable(mkbatch(0)), make_spillable(mkbatch(4))]
     for h in handles:
         h.unpin()
-    pieces = [StreamPiece.of_handle(h, 4) for h in handles]
+    pieces = [StreamPiece.of_range_view(h, 0, 4, h.size_bytes)
+              for h in handles]
     base_pins = [h._pins for h in handles]
     attempts = [0]
 
@@ -628,7 +629,7 @@ def test_retry_over_stream_pieces_is_pin_balanced():
         assert len(mats) == 1 and len(mats[0]) == 2
         if attempts[0] == 1:
             raise TpuRetryOOM("injected mid-attempt")
-        return sum(int(m.num_rows) for m in mats[0])
+        return sum(int(m.count) for m in mats[0])
 
     assert retry_over_stream_pieces([pieces], body) == 8
     assert attempts[0] == 2

@@ -593,6 +593,11 @@ def test_real_executor_traced_roundtrip(tmp_path):
     finally:
         stop_ev.set()
         worker.join(timeout=10)
+        # executor_main installed its node process-wide (as
+        # tests/test_cancel.py resets it)
+        from spark_rapids_tpu.shuffle.transport import (
+            set_process_shuffle_executor)
+        set_process_shuffle_executor(None)
         driver.close()
 
 

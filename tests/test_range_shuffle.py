@@ -11,7 +11,7 @@ row range of the packed table).  These tests pin the TPU analog:
   * counters: exactly ONE device-to-host sync and zero extra gather
     launches per map batch on the MULTITHREADED and MULTIPROCESS write
     paths (shuffle/stats.py map_* counters + launch_stats);
-  * the rangeSerialize escape hatch restores the device-slice path;
+  * the map side's one decision (transport type x schema), as a table;
   * round-robin start rotation spreads remainder rows across batches;
   * KudoWireTransport.read_iter chunks oversized reduce partitions by
     target_rows (whole-merge fallback when a codec hides the header).
@@ -32,8 +32,7 @@ from spark_rapids_tpu.plan.execs.scan import TpuInMemoryScanExec
 from spark_rapids_tpu.shuffle import serializer as ser
 from spark_rapids_tpu.shuffle.stats import (reset_shuffle_counters,
                                             shuffle_counters)
-from spark_rapids_tpu.shuffle.transport import (KudoWireTransport,
-                                                set_range_serialize)
+from spark_rapids_tpu.shuffle.transport import KudoWireTransport
 
 SCHEMA = Schema.of(k=T.INT, v=T.LONG, s=T.STRING)
 FIXED_SCHEMA = Schema.of(k=T.INT, v=T.DOUBLE)
@@ -170,29 +169,56 @@ def test_map_side_one_sync_zero_gathers(mode):
         ex.cleanup()
 
 
-def test_range_serialize_escape_hatch():
-    """rangeSerialize=false restores the device-slice piece path (same
-    rows; per-piece serializer downloads show up in the sync counter)."""
-    batches = [_batch(0, 40), _batch(40, 80)]
+NESTED_SCHEMA = Schema.of(
+    st=T.StructType((T.StructField("a", T.INT),
+                     T.StructField("b", T.STRING))),
+    ar=T.ArrayType(T.LONG))
+
+
+def _nested_batch(lo, hi):
+    return ColumnarBatch.from_pydict(
+        {"st": [{"a": i, "b": f"x{i}"} if i % 3 else None
+                for i in range(lo, hi)],
+         "ar": [list(range(i % 4)) if i % 5 else None
+                for i in range(lo, hi)]}, NESTED_SCHEMA)
+
+
+@pytest.mark.parametrize("mode,nested,written_as", [
+    ("CACHE_ONLY", False, "range_views"),
+    ("MULTITHREADED", False, "range_stream"),
+    # why _slices stays: the range writer frames flat layouts only
+    ("MULTITHREADED", True, "slices")])
+def test_map_side_write_shape_by_transport_and_schema(mode, nested,
+                                                      written_as):
+    """The map side's one decision reads the transport's type and
+    range_supported(schema): CACHE_ONLY stores range views, a wire
+    transport is written by ranges where the schema is flat and by
+    device slices where it is nested; the rows are the input's."""
+    if nested:
+        schema, keys = NESTED_SCHEMA, []          # round-robin routing
+        batches = [_nested_batch(0, 20), _nested_batch(20, 50)]
+    else:
+        schema, keys = SCHEMA, [BoundReference(0, T.INT, "k")]
+        batches = [_batch(0, 40), _batch(40, 80)]
+    assert ser.range_supported(schema) == (not nested)
+    scan = TpuInMemoryScanExec([[b] for b in batches], schema)
+    ex = TpuShuffleExchangeExec(4, keys, scan, mode=mode)
+    assert ex.mode == mode
     try:
-        set_range_serialize(False)
-        scan = TpuInMemoryScanExec([[b] for b in batches], SCHEMA)
-        ex = TpuShuffleExchangeExec(4, [BoundReference(0, T.INT, "k")],
-                                    scan, mode="MULTITHREADED")
         reset_shuffle_counters()
-        rows = []
+        got = []
         for p in range(4):
             for b in ex.execute_partition(p):
-                rows += b.to_pydict()["v"]
+                got += _rows(b)
         c = shuffle_counters()
-        assert sorted(rows) == list(range(80))
-        assert c["map_range_batches"] == 0
-        # piece path: one batched download per non-empty piece, more
-        # syncs than batches — exactly what the range path removes
-        assert c["map_d2h_syncs"] > len(batches)
-        ex.cleanup()
     finally:
-        set_range_serialize(True)
+        ex.cleanup()
+    assert sorted(got, key=str) == sorted(
+        (r for b in batches for r in _rows(b)), key=str)
+    assert (c["range_view_blocks"] > 0) == (written_as == "range_views"), c
+    assert (c["map_range_batches"] > 0) == (written_as == "range_stream"), c
+    # per-piece serializer downloads: more syncs than map batches
+    assert (c["map_d2h_syncs"] > len(batches)) == (written_as == "slices"), c
 
 
 def test_round_robin_start_rotates_across_batches():
@@ -260,14 +286,7 @@ def _partitioned(batch, nparts=2):
 def test_nested_serializer_single_download():
     """Satellite: the nested wire path (which the range writer doesn't
     take) downloads each piece in ONE batched device_get."""
-    schema = Schema.of(st=T.StructType((T.StructField("a", T.INT),
-                                        T.StructField("b", T.STRING))),
-                       ar=T.ArrayType(T.LONG))
-    batch = ColumnarBatch.from_pydict(
-        {"st": [{"a": i, "b": f"x{i}"} if i % 3 else None
-                for i in range(20)],
-         "ar": [list(range(i % 4)) if i % 5 else None for i in range(20)]},
-        schema)
+    schema, batch = NESTED_SCHEMA, _nested_batch(0, 20)
     assert not ser.range_supported(schema)
     reset_shuffle_counters()
     block = ser.serialize_batch(batch)

@@ -3,8 +3,8 @@
 ISSUE 11 tentpole).
 
 Differential discipline: the range-view path must be row-identical to
-the legacy device-slice (`_slices`/slice_by_counts) path and to the CPU
-oracle over skewed / null-heavy / string-keyed / empty-partition inputs.
+the CPU oracle over skewed / null-heavy / string-keyed / empty-partition
+inputs, and the store's blocks to slice_by_counts of the same batch.
 The counter-pinned tests prove the perf CLAIM: a CACHE_ONLY reduce group
 is ONE fused program with the per-partition slices folded in-trace
 (slice_gather_programs == 0, range_view_folds > 0), and the spill/retry
@@ -23,10 +23,15 @@ from tests.test_queries import assert_tpu_cpu_equal
 
 FACT = Schema.of(k=T.INT, sk=T.STRING, v=T.DOUBLE, tag=T.STRING)
 
-RV_ON = {"spark.rapids.sql.enabled": "true",
-         "spark.rapids.shuffle.cacheOnly.rangeViews": "true"}
-RV_OFF = {"spark.rapids.sql.enabled": "true",
-          "spark.rapids.shuffle.cacheOnly.rangeViews": "false"}
+CONF = {"spark.rapids.sql.enabled": "true"}
+
+#: the four options ISSUE 31 deleted: a conf map that still carries them
+#: is read like any map with unregistered keys
+OLD_HATCHES_CLOSED = {
+    "spark.rapids.sql.fusion.acrossShuffle": "false",
+    "spark.rapids.shuffle.pipeline.enabled": "false",
+    "spark.rapids.shuffle.write.rangeSerialize": "false",
+    "spark.rapids.shuffle.cacheOnly.rangeViews": "false"}
 
 
 def _fact(n=5000, seed=7, nkeys=37, skew_frac=0.0, null_frac=0.15,
@@ -67,9 +72,9 @@ def _agg_query(s, batches, key="k"):
 
 @pytest.mark.parametrize("shape", ["plain", "skewed", "null_heavy",
                                    "string_keyed", "empty_partitions"])
-def test_range_view_vs_slices_differential(shape):
-    """Row-identical: rangeViews on vs off (the `_slices` path) vs the
-    CPU oracle, across the adversarial input shapes."""
+def test_range_view_exchange_matches_oracle(shape):
+    """Row-identical to the CPU oracle across the adversarial input
+    shapes, rows in order."""
     key = "k"
     kwargs = {}
     if shape == "skewed":
@@ -81,15 +86,7 @@ def test_range_view_vs_slices_differential(shape):
     elif shape == "empty_partitions":
         kwargs = {"empty_tail": True, "null_frac": 0.0}
     batches = [_fact(seed=41, **kwargs), _fact(seed=42, n=2500, **kwargs)]
-    # construct each session right before its run: the rangeViews knob is
-    # applied process-wide via initialize_memory (like rangeSerialize)
-    rows_on = _agg_query(TpuSession(dict(RV_ON)), batches,
-                         key=key).collect()
-    rows_off = _agg_query(TpuSession(dict(RV_OFF)), batches,
-                          key=key).collect()
-    assert _norm(rows_on) == _norm(rows_off)
-    assert rows_on
-    assert_tpu_cpu_equal(
+    assert assert_tpu_cpu_equal(
         lambda s: _agg_query(s, batches, key=key), ignore_order=False)
 
 
@@ -100,7 +97,7 @@ def test_q25_shape_counters_one_program_no_slice_gathers():
     and zero materialize fallbacks."""
     from spark_rapids_tpu.cluster.stats import (
         local_shuffle_counters, reset_local_shuffle_counters)
-    conf = dict(RV_ON, **{
+    conf = dict(CONF, **{
         "spark.rapids.sql.join.broadcastRowThreshold": "1",
         "spark.rapids.sql.join.adaptive.enabled": "false"})
     s = TpuSession(conf)
@@ -126,41 +123,71 @@ def test_q25_shape_counters_one_program_no_slice_gathers():
     assert sc["range_view_materializes"] == 0, sc
 
 
-def test_escape_hatch_restores_slice_path():
-    """rangeViews=false restores the legacy device-slice path exactly:
-    slice gathers run, no view blocks exist."""
+def test_a_sessions_conf_stays_that_sessions():
+    """Session B, built with the four deleted keys set to "false", changes
+    nothing for session A beside it, nor for itself: the exchange has no
+    process-wide switch left, and the keys are unregistered."""
     from spark_rapids_tpu.cluster.stats import (
         local_shuffle_counters, reset_local_shuffle_counters)
-    batches = [_fact(seed=61)]
-    s = TpuSession(dict(RV_OFF))
-    q = _agg_query(s, batches)
-    q.collect()
-    reset_local_shuffle_counters()
-    rows = q.collect()
-    sc = local_shuffle_counters()
-    assert rows
-    assert sc["range_view_blocks"] == 0, sc
-    assert sc["range_view_folds"] == 0, sc
-    assert sc["slice_gather_programs"] > 0, sc
+    batches = [_fact(seed=61), _fact(seed=62, n=1800)]
+    want = _norm(_agg_query(
+        TpuSession({"spark.rapids.sql.enabled": "false"}),
+        batches).collect())
+    assert want
+    a = TpuSession(dict(CONF))
+    qa = _agg_query(a, batches)
+    assert _norm(qa.collect()) == want
+    b = TpuSession(dict(CONF, **OLD_HATCHES_CLOSED))
+    for q in (qa, _agg_query(b, batches)):
+        reset_local_shuffle_counters()
+        rows = q.collect()
+        sc = local_shuffle_counters()
+        assert _norm(rows) == want
+        assert sc["range_view_blocks"] > 0, sc
+        assert sc["range_view_folds"] > 0, sc
+        assert sc["slice_gather_programs"] == 0, sc
+
+
+def test_deleted_options_and_switches_are_gone():
+    from spark_rapids_tpu import config
+    from spark_rapids_tpu.shuffle import transport
+    keys = {e.key for e in config.all_entries()}
+    assert not keys & set(OLD_HATCHES_CLOSED), keys & set(OLD_HATCHES_CLOSED)
+    for name in ("set_range_serialize", "range_serialize_enabled",
+                 "set_range_views", "range_views_enabled",
+                 "set_pipeline_enabled", "pipeline_enabled"):
+        assert not hasattr(transport, name), name
 
 
 def test_materialize_fallback_for_per_op_consumers():
-    """With fusion off the reduce side is a per-op consumer: views slice
-    through the standalone-gather fallback (counted) and rows still
-    match the fused path."""
+    """With fusion off a shuffled join is a per-op consumer of its
+    exchanges: their views slice through the standalone-gather fallback
+    (counted) and rows still match the fused path."""
     from spark_rapids_tpu.cluster.stats import (
         local_shuffle_counters, reset_local_shuffle_counters)
-    batches = [_fact(seed=71), _fact(seed=72, n=1800)]
-    rows_fused = _agg_query(TpuSession(dict(RV_ON)), batches).collect()
-    perop = TpuSession(dict(
-        RV_ON, **{"spark.rapids.sql.tpu.fuseStages": "false",
-                  "spark.rapids.sql.fusion.acrossShuffle": "false"}))
-    q = _agg_query(perop, batches)
+    conf = dict(CONF, **{
+        "spark.rapids.sql.join.broadcastRowThreshold": "1",
+        "spark.rapids.sql.join.adaptive.enabled": "false"})
+
+    def query(s):
+        fact = s.create_dataframe(
+            [_fact(seed=71, null_frac=0.0), _fact(seed=72, n=1800)],
+            num_partitions=2)
+        dim = s.create_dataframe([_fact(seed=73, n=600, null_frac=0.0)],
+                                 num_partitions=2)
+        return (fact.join(dim.select(col("k").alias("dk"),
+                                     col("v").alias("w")),
+                          on=([col("k")], [col("dk")]))
+                .select("k", "tag", "v", "w"))
+
+    rows_fused = query(TpuSession(dict(conf))).collect()
+    q = query(TpuSession(dict(
+        conf, **{"spark.rapids.sql.tpu.fuseStages": "false"})))
     q.collect()
     reset_local_shuffle_counters()
     rows_perop = q.collect()
     sc = local_shuffle_counters()
-    assert _norm(rows_fused) == _norm(rows_perop)
+    assert rows_fused and _norm(rows_fused) == _norm(rows_perop)
     assert sc["range_view_blocks"] > 0, sc
     assert sc["range_view_materializes"] > 0, sc
     assert sc["slice_gather_programs"] == 0, sc
@@ -351,24 +378,21 @@ def test_residency_guard_counts_deduped_backings_against_budget():
     t.cleanup()
 
 
-def test_write_partitioned_blocks_match_slice_path_rows():
-    """Unit differential: the view store serves byte/row-identical data
-    to the legacy slice path for the SAME reordered batch + counts."""
+def test_write_partitioned_blocks_match_slice_by_counts_rows():
+    """Unit differential: the view store serves row-identical data to
+    slice_by_counts of the SAME reordered batch + counts."""
     from spark_rapids_tpu.plan.execs.out_of_core import slice_by_counts
     from spark_rapids_tpu.shuffle.transport import CacheOnlyTransport
     counts = np.asarray([5, 0, 3], np.int64)
     reordered = _mkbatch(100, 8)
     t = CacheOnlyTransport(3)
     t.write_partitioned([(reordered, counts)])
-    legacy = CacheOnlyTransport(3)
-    legacy.write((p, piece) for p, piece in
-                 enumerate(slice_by_counts(reordered, counts, 3))
-                 if piece is not None)
-    for part in range(3):
-        a = [int(x) for b in t.read(part)
-             for x in np.asarray(b.columns[0].data)[:b.host_num_rows()]]
-        b = [int(x) for bb in legacy.read(part)
-             for x in np.asarray(bb.columns[0].data)[:bb.host_num_rows()]]
-        assert a == b, (part, a, b)
+
+    def rows(batches):
+        return [int(x) for b in batches
+                for x in np.asarray(b.columns[0].data)[:b.host_num_rows()]]
+
+    for part, piece in enumerate(slice_by_counts(reordered, counts, 3)):
+        want = rows([piece] if piece is not None else [])
+        assert rows(t.read(part)) == want, part
     t.cleanup()
-    legacy.cleanup()

@@ -96,7 +96,6 @@ def test_soak_kill_revive_delay_under_replication_and_speculation():
     from spark_rapids_tpu.cluster.driver import TpuClusterDriver
     driver = TpuClusterDriver(
         conf={"spark.rapids.shuffle.replication.factor": "2",
-              "spark.rapids.shuffle.pipeline.enabled": "true",
               "spark.rapids.cluster.speculation.enabled": "true",
               "spark.rapids.cluster.speculation.minTasks": "2",
               "spark.rapids.cluster.speculation.multiplier": "3.0"},

@@ -100,8 +100,17 @@ def group_rows(
     key_cols: Sequence[int],
     string_max_bytes: Optional[int] = None,
     allow_split_groups: bool = False,
+    live: Optional[jax.Array] = None,
 ) -> GroupedLayout:
     """Sort rows by keys and delimit groups.
+
+    ``live``: bool [capacity], the rows that count where they are not the
+    prefix ``batch.live_mask()`` (a fused filter's mask, taken in the place
+    of its compaction).  The sort sinks every other row to the end and is
+    stable, so the sorted batch holds the rows that count as a prefix of
+    ``sum(live)`` rows, in the order a compaction before the sort would
+    have left them in: everything that reads the layout reads what it
+    would have read of a compacted batch.
 
     string_max_bytes must cover the longest live string key or distinct
     groups silently merge; None derives it from the data (host sync).
@@ -128,10 +137,12 @@ def group_rows(
 
     orders = [SortOrder(True, True) for _ in key_cols]
     idx = sort_indices(nb, key_cols, orders, string_max_bytes,
-                       hash_string_keys=allow_split_groups)
-    sb = gather_batch(nb, idx, nb.num_rows)
+                       hash_string_keys=allow_split_groups, live=live)
+    count = (nb.num_rows if live is None
+             else jnp.sum(live.astype(jnp.int32)))
+    sb = gather_batch(nb, idx, count)
 
-    live = sb.live_mask()
+    live = sb.live_mask()       # from here on the rows that count: a prefix
     eq = jnp.ones((sb.capacity,), dtype=jnp.bool_)
     for ci in key_cols:
         col = sb.columns[ci]

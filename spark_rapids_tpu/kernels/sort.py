@@ -204,9 +204,15 @@ def sort_indices(
     orders: Sequence[SortOrder],
     string_max_bytes: Optional[int] = None,
     hash_string_keys: bool = False,
+    live: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Stable argsort of live rows by the given keys; padding rows at end.
     Returns int32 [capacity] gather indices.
+
+    ``live``: bool [capacity], the rows that count where they are not the
+    prefix ``batch.live_mask()`` (a fused filter's mask): every other row
+    sinks to the end like padding, and the rows that count keep their
+    order among equal keys.
 
     string_max_bytes must cover the longest live string key or ordering
     truncates; None derives it from the data (host sync).
@@ -234,7 +240,8 @@ def sort_indices(
         else:
             keys.append(_data_key_fixed(col, order))
         keys.append(_null_key(col, order))
-    live = batch.live_mask()
+    if live is None:
+        live = batch.live_mask()
     keys.append(jnp.where(live, jnp.uint8(0), jnp.uint8(1)))
     return jnp.lexsort(tuple(keys)).astype(jnp.int32)
 

@@ -455,11 +455,18 @@ class _AggDeviceSpec:
         return SK.bucket_for(SK.max_live_bytes_multi(pairs))
 
     def reduces_under_mask(self) -> bool:
-        """True when ``_partial_step`` reads the live rows purely as a
-        boolean mask: no keys, and every slot's update op is one of
-        ``_MASK_UPDATE_OPS``.  A fused filter below such an aggregate hands
-        over its mask instead of compacting (plan/fused.py)."""
-        return not self.group_exprs and all(
+        """True when ``_partial_step`` takes the rows that count as a
+        boolean ``live`` mask, so that a fused filter below the aggregate
+        hands over its mask instead of compacting (plan/fused.py).
+
+        Keyless, every slot's update op has to be one of
+        ``_MASK_UPDATE_OPS``: each op reads the mask itself.  Grouped,
+        always: the grouping sort takes the mask as its liveness key and
+        leaves the rows that count as a prefix in their own order
+        (``group_rows``), and everything after the sort is the step a
+        compacted batch runs, whatever the ops, so no per-op list is needed
+        there."""
+        return bool(self.group_exprs) or all(
             slot.update_op in _MASK_UPDATE_OPS
             for _, slot in self.slot_specs)
 
@@ -482,7 +489,8 @@ class _AggDeviceSpec:
         ``plan/fused.py`` does through its ``g<pos>`` feedback.
 
         ``live``: the rows that count, where they are not the prefix
-        ``batch.live_mask()`` (keyless only: ``reduces_under_mask``)."""
+        ``batch.live_mask()`` (``reduces_under_mask``): keyless, every
+        reduction reads it; grouped, the grouping sort does."""
         ctx = EvalContext(batch)
         key_cols = tuple(e.eval(ctx) for e in self.group_exprs)
         agg_in = {}
@@ -553,7 +561,6 @@ class _AggDeviceSpec:
             return ColumnarBatch(tuple(cols), host_scalar(1), self.partial_schema)
 
         # grouped: pack keys + inputs into a work batch, sort-group, reduce
-        assert live is None, "the grouped step sorts a prefix of live rows"
         work_cols = list(key_cols)
         col_of_agg = {}
         for agg in self.aggregates:
@@ -569,7 +576,7 @@ class _AggDeviceSpec:
         # what a batch boundary does anyway); boundaries stay byte-exact
         layout = G.group_rows(work, list(range(nkeys)),
                               string_max_bytes=string_bucket,
-                              allow_split_groups=True)
+                              allow_split_groups=True, live=live)
         # keys are born at the group capacity; a buffer slot is reduced
         # over the input's capacity and keeps its first rows (_head_rows)
         out_keys = G.group_keys_output(layout, list(range(nkeys)),

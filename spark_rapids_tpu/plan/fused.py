@@ -1073,17 +1073,21 @@ def _row_local(exprs) -> bool:
 
 
 def _masked_filters(chain) -> frozenset:
-    """Positions of the chain's filters that hand their mask to a keyless
+    """Positions of the chain's filters that hand their mask to an
     aggregate instead of compacting (the chain is top-down).
 
-    A keyless aggregate reduces over the whole capacity under a boolean
-    ``live`` mask and needs no prefix of live rows, so a filter whose rows
-    reach nothing but such an aggregate skips ``compaction_map`` and
-    ``gather_batch``.  Between the two only row-local projects and further
-    filters (ANDed into the mask) may sit.  Every other filter (grouped
-    aggregate, join or exchange slice above it, top of the chain) compacts.
-    Read from the chain's shape alone, which the program's cache key
-    already covers."""
+    The rule: a filter whose rows reach nothing but an aggregate that
+    takes a mask (``reduces_under_mask``) skips ``compaction_map`` and
+    ``gather_batch``.  A keyless aggregate reduces over the whole capacity
+    under the boolean ``live`` mask; a grouped one gives it to its grouping
+    sort as the liveness key, which moves the rows once where a compaction
+    and then the sort moved them twice.  Between the two only row-local
+    projects and further filters (ANDed into the mask) may sit, and the
+    aggregate's own expressions have to be row-local: they are evaluated
+    on rows the filter dropped.  Every other filter (join or exchange slice
+    above it with no aggregate between, top of the chain) compacts.  Read
+    from the chain's shape alone, which the program's cache key already
+    covers."""
     from spark_rapids_tpu.plan.execs.aggregate import TpuHashAggregateExec
     from spark_rapids_tpu.plan.execs.basic import (
         TpuFilterExec, TpuProjectExec)
@@ -1179,6 +1183,8 @@ def _emit_node(node, pos: int, cur: ColumnarBatch, builds: tuple,
                           bucket, caps, feedback), None
 
     assert isinstance(node, TpuHashAggregateExec), type(node).__name__
+    # either way a masked filter's mask is spent here: what the aggregate
+    # hands on has its live rows as a prefix again
     spec = node._spec
     if not spec.group_exprs:
         # one row whatever the input: no capacity to speculate, no caps key
@@ -1192,7 +1198,7 @@ def _emit_node(node, pos: int, cur: ColumnarBatch, builds: tuple,
     ck = f"g{pos}"
     cap = caps.setdefault(ck, GROUP_CAP_DEFAULT)
     out = spec._partial_step(
-        cur, string_bucket=bucket,
+        cur, string_bucket=bucket, live=live,
         group_capacity=cap if cap < cur.capacity else None)
     # groups that did not fit ask for the input's capacity at once, not
     # for the next power of two over this batch's count: batches whose

@@ -261,13 +261,17 @@ _MASK_CASES = {
     # split into two TpuFilterExecs after planning: _stack_filters
     "stacked_filters": (True, lambda df: df.filter(_PASS)),
     # filter, row-local project, filter again over the projected column
-    "project_between": (True, lambda df: (
-        df.filter(col("v") > lit(-400))
-        .select(col("k"), (col("v") + lit(0)).alias("v"),
-                (col("x") * lit(1.0)).alias("x"), col("s"), col("d"),
-                col("w"))
-        .filter(col("x") < lit(1.2)))),
+    "project_between": (True, lambda df: _project_between(
+        df, MASK_SCHEMA.names)),
 }
+
+
+def _project_between(df, names):
+    projected = {"v": (col("v") + lit(0)).alias("v"),
+                 "x": (col("x") * lit(1.0)).alias("x")}
+    return (df.filter(col("v") > lit(-400))
+            .select(*[projected.get(c, col(c)) for c in names])
+            .filter(col("x") < lit(1.2)))
 
 
 def _segment(plan):
@@ -343,23 +347,118 @@ def test_every_mask_update_op_has_a_case():
     assert set(_masked_aggs()) == set(_MASK_UPDATE_OPS)
 
 
+# The same five shapes over a GROUPED aggregate: the filter's mask goes to the
+# grouping sort as its liveness key (kernels/groupby.py group_rows), and after
+# the sort the step is the one a compacted batch runs.  Against the unfused
+# engine (the per-op filter compacts) and the CPU oracle, rows in key order.
+
+GMASK_SCHEMA = Schema.of(k=T.INT, kn=T.INT, a=T.STRING, b=T.STRING,
+                         v=T.LONG, x=T.DOUBLE, s=T.STRING)
+
+
+def _gmask_batches(n, nulls, seed=13):
+    """Two batches of n // 2 rows: an int key, an int key with NULLs, two
+    one-byte string keys (q1's shape), and inputs with NULLs when asked.
+    ``x`` has no two equal values, so ``max_by`` over it has no tie."""
+    rng = np.random.RandomState(seed)
+    data = {"k": rng.randint(0, 7, n).tolist(),
+            "kn": rng.randint(0, 5, n).tolist(),
+            "a": ["RAN"[i] for i in rng.randint(0, 3, n)],
+            "b": ["FO"[i] for i in rng.randint(0, 2, n)],
+            "v": rng.randint(-1000, 1000, n).tolist(),
+            "x": (rng.permutation(n) / (n / 4.0) - 2.0).tolist(),
+            "s": [f"s{int(i) % 23}-{'y' * (int(i) % 5)}"
+                  for i in rng.randint(0, 100, n)]}
+    for c in ("kn",) + (("v", "x", "s") if nulls else ()):
+        for i in rng.choice(n, n // 6, replace=False):
+            data[c][i] = None
+    half = n // 2
+    return [ColumnarBatch.from_pydict(
+        {c: vals[lo:hi] for c, vals in data.items()}, GMASK_SCHEMA)
+        for lo, hi in ((0, half), (half, n))]
+
+
+_GMASK_KEYS = {"int": ("k",), "two_strings": ("a", "b"), "null_key": ("kn",)}
+
+
+def _gmask_aggs():
+    """Ops that depend on the order of the rows that pass, or on how many
+    of them there are."""
+    from spark_rapids_tpu.expressions import (
+        avg, collect_list, first, last, max_, max_by, min_, min_by)
+    return {
+        "sum_count_avg": [sum_("x"), sum_("v"), count(), avg("x")],
+        "min_max": [min_("x"), max_("v"), min_("s"), max_("s")],
+        "first_last": [first("v"), last("s"),
+                       first("x", ignore_nulls=True),
+                       last("v", ignore_nulls=True)],
+        "collect_list": [collect_list("x")],
+        "max_by": [max_by("v", "x"), max_by("s", "x"), min_by("s", "x")],
+    }
+
+
+def _gmask_query(s, case, keys, aggs, n=700):
+    nulls, shape = _MASK_CASES[case]
+    if case == "project_between":
+        def shape(df):
+            return _project_between(df, GMASK_SCHEMA.names)
+    df = s.create_dataframe(_gmask_batches(n, nulls), num_partitions=2)
+    return (shape(df).group_by(*keys)
+            .agg(*[a.alias(f"a{i}") for i, a in enumerate(aggs)])
+            .order_by(*keys))
+
+
+@pytest.mark.parametrize("case", list(_MASK_CASES))
+@pytest.mark.parametrize("keys", list(_GMASK_KEYS))
+@pytest.mark.parametrize("ops", list(_gmask_aggs()))
+def test_masked_filter_under_grouped_agg_matches_compaction_and_oracle(
+        ops, keys, case):
+    from spark_rapids_tpu.plan.engine import TpuEngine
+    from spark_rapids_tpu.plan.fused import _masked_filters, _program_kind
+    from tests.test_queries import _eq_val
+    aggs, keys = _gmask_aggs()[ops], _GMASK_KEYS[keys]
+    fused_s, unfused_s = _sessions()
+    cpu_s = TpuSession({"spark.rapids.sql.enabled": "false"})
+
+    plan = _gmask_query(fused_s, case, keys, aggs).physical_plan()
+    seg, xkeys, n_out = _sliced_segment(plan)
+    if case == "stacked_filters":
+        _stack_filters(seg)
+    kinds = _program_kind(seg.chain, (tuple(xkeys), n_out, "t")).split("_")
+    assert kinds[:3] == ["fused", "agg", "mfilter"] and "filter" not in kinds
+    n_filters = {"stacked_filters": 2, "project_between": 2}.get(case, 1)
+    assert len(_masked_filters(seg.chain)) == n_filters
+
+    got = TpuEngine(fused_s.conf).collect(plan)
+    compacted = _gmask_query(unfused_s, case, keys, aggs).collect()
+    want = _gmask_query(cpu_s, case, keys, aggs).collect()
+    assert len(got) == len(compacted) == len(want)
+    assert (len(got) == 0) == (case == "no_row_passes")
+    for g, c, w in zip(got, compacted, want):
+        assert _eq_val(tuple(g), tuple(c)), (g, c)
+        assert _eq_val(tuple(g), tuple(w)), (g, w)
+
+
 def _lineitem_df(s, rows=4096):
     from spark_rapids_tpu.testing import tpch
     return s.create_dataframe(tpch.gen_lineitem(rows, seed=3),
                               num_partitions=2)
 
 
-def _lowered_text(seg, batch, slice_spec=None, scopes=False):
+def _lowered_text(seg, batch, slice_spec=None, scopes=False, bucket=0,
+                  builds=()):
     """StableHLO of the segment's program for one stream batch, as
-    ``_converge`` builds it (no builds, bucket 0); with ``scopes`` also
-    the locations that carry each operation's named scopes."""
+    ``_converge`` builds it (``bucket`` 0 where no string is compared,
+    ``builds`` where the chain joins); with ``scopes`` also the locations
+    that carry each operation's named scopes."""
     import jax
     import jax.numpy as jnp
     from spark_rapids_tpu.plan.execs.base import collect_trace_consts
     consts = tuple(jnp.asarray(a)
                    for a in collect_trace_consts(seg._all_exprs()))
-    fn = seg._make(0, {}, slice_spec, None)
-    return jax.jit(fn).lower(batch, (), consts).as_text(debug_info=scopes)
+    fn = seg._make(bucket, {}, slice_spec, None)
+    return jax.jit(fn).lower(batch, tuple(builds),
+                             consts).as_text(debug_info=scopes)
 
 
 def _compacting(monkeypatch):
@@ -383,45 +482,123 @@ def test_q6_program_holds_no_gather_and_no_scatter(monkeypatch):
     assert "gather" in text and "scatter" in text
 
 
-def _q1_sliced(s):
-    """q1's map side: the segment and the slice the exchange folds in."""
-    from spark_rapids_tpu.testing import tpch
-    plan = tpch.q1(_lineitem_df(s)).order_by("l_linenumber").physical_plan()
+def _spec_client(tmp_path, cell_name, rows):
+    """The benchmark's client for a cell over a small table of its own,
+    written as the benchmark writes it: two files of one row group."""
+    from benchmark import datagen, run as bench_run
+    cell = bench_run.load_cell(cell_name)
+    spec = cell.config["tables"]["lineitem"]
+    files = datagen.write_table(
+        str(tmp_path), cell.tables["lineitem"], "lineitem", rows,
+        spec["files"], rows // spec["files"], 5,
+        spec["scale_factor"] * rows / spec["rows"])
+    return bench_run.Client(cell, {"lineitem": files}, {"lineitem": rows})
+
+
+def _spec_q1_sliced(tmp_path, rows=20000):
+    """The benchmark's q1 (two char(1) string keys): the segment, its slice
+    and one scan batch, of capacity 16,384, over the default group
+    capacity."""
+    client = _spec_client(tmp_path, "q1_parquet_sf1", rows)
+    seg, keys, n_out = _sliced_segment(client.frame("q1").physical_plan())
+    batch = next(iter(seg.children[0].execute_partition(0)))
+    return seg, (tuple(keys), n_out, "test"), batch
+
+
+def _string_gather_loops(text, capacity):
+    """Calls of the ``searchsorted`` loop (one a gathered string column)
+    over an offsets plane of ``capacity`` rows."""
+    import re
+    return len(re.findall(
+        rf"call @searchsorted\w*\([^)]*\) : \(tensor<{capacity + 1}xi32>",
+        text))
+
+
+def test_q1_program_moves_its_rows_once(monkeypatch, tmp_path):
+    """The filter under q1's grouped aggregate hands its mask to the
+    grouping sort: of the four string gathers at the batch's capacity
+    (two key columns, gathered by the filter and again by ``group_rows``)
+    two are left, and the filter's ``compaction_map`` and ``gather_batch``
+    are gone."""
+    import re
+
+    from spark_rapids_tpu.kernels.strings import MIN_BUCKET
+    from spark_rapids_tpu.plan import fused
+    seg, slice_spec, batch = _spec_q1_sliced(tmp_path)
+    cap = batch.capacity
+    assert cap == 16384 > fused.GROUP_CAP_DEFAULT
+    assert fused._program_kind(seg.chain, slice_spec) == \
+        "fused_agg_mfilter_slice"
+    text = _lowered_text(seg, batch, slice_spec, scopes=True,
+                         bucket=MIN_BUCKET)
+    _compacting(monkeypatch)
+    assert fused._program_kind(seg.chain, slice_spec) == \
+        "fused_agg_filter_slice"
+    compacting = _lowered_text(seg, batch, slice_spec, scopes=True,
+                               bucket=MIN_BUCKET)
+    assert _string_gather_loops(compacting, cap) == 4
+    assert _string_gather_loops(text, cap) == 2
+    # the keys born at the group capacity and the slice's gather: as before
+    small = fused.GROUP_CAP_DEFAULT
+    assert _string_gather_loops(text, small) == \
+        _string_gather_loops(compacting, small) == 4
+    assert "/mfilter/" in text and "/filter/" not in text
+    assert "/filter/compaction_map" in compacting
+    assert "/filter/gather_batch" in compacting
+    # the compaction's scatter went with it, and nothing came in its place
+    scatters = [len(re.findall(r"stablehlo\.scatter", t))
+                for t in (text, compacting)]
+    assert scatters[0] < scatters[1], scatters
+    assert "/group_rows/gather_batch" in text
+
+
+def _filter_under_join(s):
+    """A filter under a join under a grouped aggregate: the join reads a
+    prefix of live rows, so this filter compacts."""
+    fact = s.create_dataframe([_fact()], num_partitions=2)
+    dim = s.create_dataframe([_dim()], num_partitions=1)
+    plan = (fact.filter(col("v") > lit(-4.0))
+            .join(dim, on=([col("k")], [col("dk")]))
+            .group_by("name").agg(sum_("v").alias("sv")).physical_plan())
     seg, keys, n_out = _sliced_segment(plan)
-    return seg, (tuple(keys), n_out, "test")
+    return seg, (tuple(keys), n_out, "test"), _fact()
 
 
 def _filter_topped(s):
+    from spark_rapids_tpu.testing import tpch
     df = _lineitem_df(s)
     # a filter over a computed column stays above the project
     return _segment(
         df.select((col("l_quantity") + col("l_tax")).alias("q"), "l_shipdate")
-        .filter(col("q") > lit(2500, D12_2)).physical_plan()), None
+        .filter(col("q") > lit(2500, D12_2)).physical_plan()), None, \
+        tpch.gen_lineitem(4096, seed=3, batch_rows=4096)[0]
 
 
 @pytest.mark.parametrize("shape,kind", [
-    (_q1_sliced, "fused_agg_filter_project_slice"),
+    (_filter_under_join, "fused_agg_join_filter_project_slice"),
     (_filter_topped, "fused_filter_project")])
 def test_other_chains_lower_as_before(monkeypatch, shape, kind):
-    """A grouped aggregate sorts a prefix of live rows, and a filter at
-    the top hands its rows on: both compact, operation for operation."""
+    """A join reads a prefix of live rows, and a filter at the top hands
+    its rows on: both compact, operation for operation."""
     from spark_rapids_tpu.plan.fused import _masked_filters, _program_kind
-    from spark_rapids_tpu.testing import tpch
     s, _ = _sessions()
-    seg, slice_spec = shape(s)
+    seg, slice_spec, batch = shape(s)
     assert _masked_filters(seg.chain) == frozenset()
     assert _program_kind(seg.chain, slice_spec) == kind
-    batch = tpch.gen_lineitem(4096, seed=3, batch_rows=4096)[0]
-    text = _lowered_text(seg, batch, slice_spec)
+    builds = tuple(seg._materialize_builds())
+    text = _lowered_text(seg, batch, slice_spec, bucket=32, builds=builds)
     assert "gather" in text
     _compacting(monkeypatch)
-    assert _lowered_text(seg, batch, slice_spec) == text
+    assert _lowered_text(seg, batch, slice_spec, bucket=32,
+                         builds=builds) == text
+    seg.cleanup()
 
 
 def test_launches_count_under_the_engaged_kind():
     """The counter that says the mask was handed over: q6's launches are
-    ``fused_agg_mfilter…``'s, q1's keep the name they had (a renamed
-    program compiles cold once: 350 s for q1's on the chip)."""
+    ``fused_agg_mfilter…``'s and so are q1's, none is counted under a
+    ``…_filter…`` name (a renamed program compiles cold once: 350 s for
+    q1's on the chip)."""
     from spark_rapids_tpu.plan.execs.base import (
         launch_stats, reset_launch_stats)
     from spark_rapids_tpu.testing import tpch
@@ -435,12 +612,12 @@ def test_launches_count_under_the_engaged_kind():
     reset_launch_stats()
     assert tpch.q1(df).order_by("l_linenumber").collect()
     by = launch_stats()["by_program"]
-    # the name this program has since PR 31 took `fuse_across_shuffle` out
-    # of the aggregate's key (`_d9b1c42e` from PR 27 until then; same data,
-    # same session conf): a digest of the cache key, which the mask is no
-    # part of
-    assert by["fused_agg_filter_project_slice_21306a13"] == 1, by
-    assert not any("mfilter" in n for n in by)
+    # the digest this program has since PR 31 took `fuse_across_shuffle` out
+    # of the aggregate's key (same data, same session conf): a digest of the
+    # cache key, which the mask is no part of; the kind before it names the
+    # chain, and says `mfilter` since the grouped aggregate takes the mask
+    assert by["fused_agg_mfilter_project_slice_21306a13"] == 1, by
+    assert not any("_filter" in n for n in by)
 
 
 # -- a grouped partial aggregate's group capacity (g<pos> caps/feedback) ------
@@ -582,6 +759,42 @@ def test_grouped_partial_agg_hands_on_its_group_capacity(
                          ignore_order=False)
 
 
+@pytest.mark.parametrize("case", list(_GROUP_CASES))
+def test_masked_grouped_agg_under_a_slice_matches_the_compacting_lowering(
+        case, monkeypatch, fresh_program_caches):
+    """``_group_query``'s filter hands over its mask.  Under a sliced
+    exchange the partial batches' rows and per-partition counts are those
+    of the lowering whose filter compacts, and so is what a batch with
+    more groups than the default capacity costs: one discarded launch,
+    then the rows of the step at the input's capacity."""
+    import collections
+
+    from spark_rapids_tpu.plan import fused
+    from spark_rapids_tpu.plan.execs import base
+    _, n_batches, _, _, want_cap, want_discarded = _GROUP_CASES[case]
+    s, _ = _sessions()
+    seg, keys, n_out = _sliced_segment(_group_query(s, case).physical_plan())
+    # (a project under the filter where the scan's columns are pruned)
+    assert fused._program_kind(seg.chain, (tuple(keys), n_out, "t")) in (
+        "fused_agg_mfilter_slice", "fused_agg_mfilter_project_slice")
+    got = _run_sliced(s, case)
+    assert [d for _, _, d in got] == want_discarded
+    with monkeypatch.context() as m:
+        # both lowerings are built under one cache key (the mask is no
+        # part of it: a chain has one lowering outside this test)
+        _compacting(m)
+        m.setattr(fused, "_FUSED_CAPS", collections.OrderedDict())
+        m.setattr(base, "_JIT_CACHE", collections.OrderedDict())
+        compacted = _run_sliced(s, case)
+    assert [d for _, _, d in compacted] == want_discarded
+    assert len(got) == len(compacted) == n_batches
+    for (b, counts, _), (cb, ccounts, _) in zip(got, compacted):
+        assert b.capacity == cb.capacity == want_cap
+        assert b.host_num_rows() == cb.host_num_rows() > 0
+        assert counts.tolist() == ccounts.tolist()
+        assert _live_rows(b) == _live_rows(cb)
+
+
 def test_fused_batch_span_says_how_many_launches_it_took(
         fresh_program_caches):
     """``attempts`` on ``fused.batch`` and ``launch_stats()["discarded"]``:
@@ -698,25 +911,19 @@ def test_group_capacity_keeps_string_and_array_buffers_whole(
     # keyless: no capacity, no caps key, the cache key the parent built
     ("q6_parquet_sf1", "q6", "fused_agg_mfilter_43815208"),
     # grouped: named after the key it is first built under, which holds no
-    # capacity yet; the program under the name is a new one
-    ("q1_parquet_sf1", "q1", "fused_agg_filter_slice_2051f78e")])
+    # capacity yet; `mfilter` since its filter hands over the mask (the
+    # digest is the one `fused_agg_filter_slice_2051f78e` had)
+    ("q1_parquet_sf1", "q1", "fused_agg_mfilter_slice_2051f78e")])
 def test_spec_query_programs_keep_their_names(
         tmp_path, fresh_program_caches, cell_name, qname, program):
     """A program's name is a digest of its cache key and part of the
     persistent compile cache's: the names the chip's cache holds (PERF.md
     §5) are the names a fresh process gives."""
-    from benchmark import datagen, run as bench_run
     from spark_rapids_tpu.plan import fused
     from spark_rapids_tpu.plan.execs.base import (
         launch_stats, reset_launch_stats)
-    cell = bench_run.load_cell(cell_name)
-    spec = cell.config["tables"]["lineitem"]
-    rows = 6000         # a small table, written as the benchmark writes it
-    files = datagen.write_table(
-        str(tmp_path), cell.tables["lineitem"], "lineitem", rows,
-        spec["files"], rows // spec["files"], 5,
-        spec["scale_factor"] * rows / spec["rows"])
-    client = bench_run.Client(cell, {"lineitem": files}, {"lineitem": rows})
+    # a small table, written as the benchmark writes it
+    client = _spec_client(tmp_path, cell_name, 6000)
     reset_launch_stats()
     assert client.frame(qname).collect()
     by = launch_stats()["by_program"]

@@ -281,6 +281,48 @@ def test_groupby_string_keys():
     assert dict(zip(keys, sums)) == {None: 4, "aa": 10, "bb": 7}
 
 
+@pytest.mark.parametrize("keys,hashed", [
+    (("k",), False), (("s",), False), (("s",), True), (("f", "k"), False),
+    (("s", "k"), True)])
+def test_group_rows_under_a_mask_is_group_rows_of_the_compacted_batch(
+        keys, hashed):
+    """``live`` in the place of ``batch.live_mask()``: the layout is the
+    one the compacted batch gets, row for row (the stable sort keeps the
+    rows that count in their order), whatever sat in the rows between."""
+    import jax.numpy as jnp
+    rng = np.random.RandomState(5)
+    n = 300                              # capacity 512: padding after them
+    schema = Schema.of(k=T.INT, s=T.STRING, f=T.DOUBLE, v=T.LONG)
+    data = {"k": rng.randint(0, 5, n).tolist(),
+            "s": [("R", "A", "N", "a longer one")[i]
+                  for i in rng.randint(0, 4, n)],
+            "f": rng.choice([0.0, -0.0, 1.5, float("nan")], n).tolist(),
+            "v": rng.randint(-99, 99, n).tolist()}
+    for c in data:
+        for i in rng.choice(n, n // 8, replace=False):
+            data[c][i] = None
+    batch = ColumnarBatch.from_pydict(data, schema)
+    kept = rng.rand(batch.capacity) < 0.6
+    mask = jnp.asarray(kept) & batch.live_mask()
+    key_cols = [schema.names.index(k) for k in keys]
+    got = gb.group_rows(batch, key_cols, string_max_bytes=16,
+                        allow_split_groups=hashed, live=mask)
+    want = gb.group_rows(sel.filter_batch(batch, mask), key_cols,
+                         string_max_bytes=16, allow_split_groups=hashed)
+    rows = int(want.sorted_batch.num_rows)
+    assert int(got.sorted_batch.num_rows) == rows == int(kept[:n].sum())
+    assert int(got.num_groups) == int(want.num_groups)
+    # NaN keys: compare as text
+    assert repr(got.sorted_batch.to_pydict()) == \
+        repr(want.sorted_batch.to_pydict())
+    assert got.segment_ids.tolist() == want.segment_ids.tolist()
+    assert got.boundary.tolist() == want.boundary.tolist()
+    # and the rows past the prefix are canonical padding, as after a compaction
+    for c, wc in zip(got.sorted_batch.columns, want.sorted_batch.columns):
+        assert not bool(c.validity[rows:].any())
+        assert repr(c.data.tolist()) == repr(wc.data.tolist())
+
+
 # -- partition --------------------------------------------------------------
 
 def test_hash_partition_matches_oracle_routing():

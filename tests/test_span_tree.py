@@ -152,7 +152,7 @@ def test_span_is_self_time_not_the_childs(traced, name):
 
 def test_launches_by_program_sum_to_launches(traced):
     for query, kinds in (("q6", {"fused_agg_mfilter", "agg_combine"}),
-                         ("q1", {"fused_agg_filter_slice", "agg_combine",
+                         ("q1", {"fused_agg_mfilter_slice", "agg_combine",
                                  "sort_local"})):
         stats = traced[query][1]
         assert sum(stats["by_program"].values()) == stats["launches"] > 0

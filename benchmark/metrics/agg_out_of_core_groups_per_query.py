@@ -1,0 +1,16 @@
+"""Reduce groups per query in the traced slice that took the out-of-core
+sub-partition merge: the program's ``agg.out_of_core`` spans (one a group
+past the in-core bound) over the queries completed.  0 where every group
+was one program's work; None where the program has no such span (it names
+its spans in ``tracing.static_ranges()``)."""
+from benchmark.span_sums import intervals
+
+SPAN = "agg.out_of_core"
+SPANS = (SPAN,)
+
+
+def read(ctx):
+    from spark_rapids_tpu.utils import tracing
+    if not ctx.slice_queries or SPAN not in tracing.static_ranges():
+        return None
+    return len(intervals(ctx, SPAN)) / len(ctx.slice_queries)

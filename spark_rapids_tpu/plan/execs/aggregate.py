@@ -16,6 +16,7 @@ group count is dynamic, capacities static.
 """
 from __future__ import annotations
 
+import itertools
 from typing import Iterator, List, Optional, Sequence, Tuple
 
 import jax
@@ -912,9 +913,12 @@ class TpuHashAggregateExec(TpuExec):
         # a launch costs on a directly attached chip is not measured).
         # OOC paths keep the split functions (they need merge sans
         # finalize).
-        def combine(partials, string_bucket: int = 0):
+        def combine(partials, out_capacity=None, string_bucket: int = 0):
             # partials may be CACHE_ONLY RangeViews (the final-fused
-            # reduce path): the map-side slice folds into THIS program
+            # reduce path): the map-side slice folds into THIS program.
+            # ``out_capacity`` (static): the rows the caller knows the
+            # partials to hold, rounded up; without it the sum of their
+            # capacities, which bounds them
             from spark_rapids_tpu.shuffle.transport import (
                 piece_batch_in_trace)
             partials = tuple(piece_batch_in_trace(p) for p in partials)
@@ -923,7 +927,7 @@ class TpuHashAggregateExec(TpuExec):
             else:
                 from spark_rapids_tpu.kernels.selection import (
                     concat_batches_device)
-                cap = round_up_pow2(
+                cap = out_capacity or round_up_pow2(
                     max(sum(p.capacity for p in partials), 1))
                 # tpu-lint: allow-retry-discipline(traced body of _jit_combine; its one call site runs under with_retry_no_split)
                 merged_in, _ = concat_batches_device(
@@ -942,10 +946,11 @@ class TpuHashAggregateExec(TpuExec):
                 return 0
             return SK.bucket_for(SK.max_live_bytes_multi(pairs))
 
-        self._jit_combine = lambda ps, _k=key: shared_jit(
+        self._jit_combine = lambda ps, out_capacity=None, _k=key: shared_jit(
             f"{_k}|combine|{len(ps)}|{(bkt := _combine_bucket(ps))}",
             lambda: _partial(combine, string_bucket=bkt),
-            kind="agg_combine")(tuple(ps))
+            kind="agg_combine", static_argnums=(1,))(
+                tuple(ps), out_capacity)
 
     # -- host-side orchestration -------------------------------------------
 
@@ -991,56 +996,63 @@ class TpuHashAggregateExec(TpuExec):
             lambda: self._jit_merge(concat_batches_jit(partials, cap)))
 
     def _execute_final_fused(self, idx: int) -> Iterator[ColumnarBatch]:
-        """Final mode over a shuffle: ONE program per reduce partition —
-        the partition's raw wire/cache pieces concat + merge + finalize
-        inside _jit_combine, pin-balanced per attempt
+        """Final mode over a shuffle: ONE program per reduce group — the
+        group's raw wire/cache pieces concat + merge + finalize inside
+        _jit_combine, pin-balanced per attempt
         (coalesce.retry_over_stream_pieces), instead of the exchange
         merging groups first and the combine concatenating them again.
-        Oversized partitions fall back to the default path (out-of-core
-        sub-partition merge)."""
+        What is one program's work is ``reduce_group_in_core``'s to say,
+        the rule the coalescing reader built the group by: a group that
+        breaks it (a single oversized partition) takes the default path's
+        out-of-core sub-partition merge."""
         from spark_rapids_tpu.plan.execs.coalesce import (
             retry_over_stream_pieces)
+        from spark_rapids_tpu.plan.execs.exchange import reduce_group_in_core
         from spark_rapids_tpu.shuffle.stats import SHUFFLE_COUNTERS
-        with timed(self.op_time):
+        from spark_rapids_tpu.shuffle.transport import (
+            views_at_one_capacity, views_over_memory_budget)
+        stream = iter(self.children[0].stream_pieces(idx))
+        # the first pull runs the exchange's map side where nothing has
+        # yet: the child's work, outside this layer's span
+        first = list(itertools.islice(stream, 1))
+        out = None
+        with timed(self.op_time, "agg.final"):
             # accumulate with an INCREMENTAL size check: the moment the
-            # partition exceeds the in-core bound, stop pulling, DROP
-            # what was pulled (wire pieces hold real device batches —
-            # keeping them across the re-read would double residency on
-            # exactly the oversized path the fallback protects), and let
-            # the default path's out-of-core merge re-read the partition
-            pieces, total, oversized = [], 0, False
-            for p in self.children[0].stream_pieces(idx):
+            # group passes the in-core bound, stop pulling, DROP what was
+            # pulled (wire pieces hold real device batches — keeping them
+            # across the re-read would double residency on exactly the
+            # oversized path the fallback protects), and let the default
+            # path's out-of-core merge re-read the partition
+            pieces, rows = [], 0
+            for p in itertools.chain(first, stream):
                 pieces.append(p)
-                total += p.capacity
-                if total > self.target_capacity:
-                    oversized = True
-                    del pieces, p
+                rows += p.rows
+                if not reduce_group_in_core(rows, self.target_capacity):
                     break
-            if not oversized and pieces:
-                # range-view residency guard: one attempt pins each
-                # view's FULL backing batch (deduped), which no spill can
-                # reclaim mid-attempt — near the arena's byte budget the
-                # default path (its reads slice views pin-balanced and
-                # release the backing) must run instead of the fold
-                from spark_rapids_tpu.shuffle.transport import (
-                    views_over_memory_budget)
-                oversized = views_over_memory_budget([pieces])
-        if oversized:
+            # range-view residency guard: one attempt pins each view's
+            # FULL backing batch (deduped), which no spill can reclaim
+            # mid-attempt — near the arena's byte budget the default path
+            # (its reads slice views pin-balanced and release the
+            # backing) must run instead of the fold
+            in_core = (reduce_group_in_core(rows, self.target_capacity)
+                       and not views_over_memory_budget([pieces]))
+            if not in_core:
+                pieces = first = p = None
+            elif pieces:
+                n_views = sum(1 for p in pieces if p.is_range_view)
+                if n_views:
+                    # CACHE_ONLY range views sliced INSIDE _jit_combine
+                    SHUFFLE_COUNTERS.add(range_view_folds=n_views)
+                cap = round_up_pow2(max(rows, 1))
+                out = retry_over_stream_pieces(
+                    [pieces], lambda mats: self._jit_combine(
+                        views_at_one_capacity(mats[0]), cap))
+        if not in_core:
             yield from self._execute_default(idx)
-            return
-        if not pieces:
-            return
-        n_views = sum(1 for p in pieces
-                      if getattr(p, "is_range_view", False))
-        if n_views:
-            # CACHE_ONLY range views sliced INSIDE _jit_combine
-            SHUFFLE_COUNTERS.add(range_view_folds=n_views)
-        with timed(self.op_time):
-            out = retry_over_stream_pieces(
-                [pieces], lambda mats: self._jit_combine(mats[0]))
-        SHUFFLE_COUNTERS.add(fused_reduce_programs=1)
-        self.output_rows.add(out.num_rows)
-        yield self._count_out(out)
+        elif out is not None:
+            SHUFFLE_COUNTERS.add(fused_reduce_programs=1, reduce_groups=1)
+            self.output_rows.add(out.num_rows)
+            yield self._count_out(out)
 
     def execute_partition(self, idx: int) -> Iterator[ColumnarBatch]:
         if (self.mode == "final"
@@ -1071,11 +1083,16 @@ class TpuHashAggregateExec(TpuExec):
                     partials = [self._identity_partial()]
                 else:
                     return
+        from spark_rapids_tpu.plan.execs.exchange import reduce_group_in_core
+        from spark_rapids_tpu.shuffle.stats import SHUFFLE_COUNTERS
+        SHUFFLE_COUNTERS.add(reduce_groups=1)
+        # batches and not pieces: their capacities are what the host knows
+        # of their rows (StreamPiece.rows says the same of an uploaded one)
         total = sum(p.capacity for p in partials)
-        if total > self.target_capacity:
+        if not reduce_group_in_core(total, self.target_capacity):
             yield from self._execute_out_of_core(partials, total)
             return
-        with timed(self.op_time):
+        with timed(self.op_time, "agg.final"):
             out = with_retry_no_split(lambda: self._jit_combine(partials))
         self.output_rows.add(out.num_rows)
         yield self._count_out(out)
@@ -1094,36 +1111,42 @@ class TpuHashAggregateExec(TpuExec):
         Global (no keys): tree-merge in chunks of target_capacity rows.
         """
         from spark_rapids_tpu.memory.spill import make_spillable
+        from spark_rapids_tpu.plan.execs.exchange import reduce_group_in_core
         from spark_rapids_tpu.plan.execs.out_of_core import (
             close_all, num_sub_buckets, sub_partition_spillable)
+        from spark_rapids_tpu.shuffle.stats import SHUFFLE_COUNTERS
 
+        SHUFFLE_COUNTERS.add(reduce_groups_out_of_core=1)
         nkeys = len(self.group_exprs)
         if nkeys == 0:
             # chunks bounded by accumulated ROW capacity, not batch count:
             # each merge's concat stays within one capacity bucket
-            while len(partials) > 1:
-                nxt, group, acc = [], [], 0
-                for p in partials + [None]:
-                    if p is not None and (
-                            not group
-                            or acc + p.capacity <= self.target_capacity):
-                        group.append(p)
-                        acc += p.capacity
-                        continue
-                    with timed(self.op_time):
+            with timed(self.op_time, "agg.out_of_core"):
+                while len(partials) > 1:
+                    nxt, group, acc = [], [], 0
+                    for p in partials + [None]:
+                        if p is not None and (
+                                not group or reduce_group_in_core(
+                                    acc + p.capacity, self.target_capacity)):
+                            group.append(p)
+                            acc += p.capacity
+                            continue
                         nxt.append(self._merge_partials(group))
-                    if p is not None:
-                        group, acc = [p], p.capacity
-                partials = nxt
-            with timed(self.op_time):
+                        if p is not None:
+                            group, acc = [p], p.capacity
+                    partials = nxt
                 out = with_retry_no_split(
                     lambda: self._jit_finalize(partials[0]))
             self.output_rows.add(out.num_rows)
             yield self._count_out(out)
             return
 
+        # one ``agg.out_of_core`` span a group, around what only this path
+        # does before it hands anything on: the sub-partition.  A span
+        # never stays open across a yield (the parent's work would be in
+        # it), so each bucket's merge and finalize is an ``agg.final``
         n_b = num_sub_buckets(total, self.target_capacity)
-        with timed(self.op_time):
+        with timed(self.op_time, "agg.out_of_core"):
             handles = [make_spillable(p) for p in partials]
             del partials
             buckets = sub_partition_spillable(
@@ -1133,7 +1156,7 @@ class TpuHashAggregateExec(TpuExec):
             for q in buckets:
                 if not q:
                     continue
-                with timed(self.op_time):
+                with timed(self.op_time, "agg.final"):
                     # pinned-ledger unwind: a raise in materialize or
                     # the merge must still unpin what WAS materialized,
                     # or the handles stay unspillable until close

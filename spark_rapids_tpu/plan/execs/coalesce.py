@@ -12,6 +12,7 @@ from typing import List, Optional
 from spark_rapids_tpu.columnar.batch import ColumnarBatch, host_scalar
 from spark_rapids_tpu.columnar.column import round_up_pow2
 from spark_rapids_tpu.kernels.selection import concat_batches_device
+from spark_rapids_tpu.utils.tracing import trace_range
 
 
 def _shape_key(batches: List[ColumnarBatch]) -> str:
@@ -49,6 +50,14 @@ def maybe_shrink(batch: ColumnarBatch,
     cap = batch.capacity
     if cap <= min_capacity:
         return batch
+    with trace_range("batch.shrink"):
+        return _shrink_over_floor(batch, cap, min_capacity)
+
+
+def _shrink_over_floor(batch: ColumnarBatch, cap: int,
+                       min_capacity: int) -> ColumnarBatch:
+    """``maybe_shrink`` of a batch over the floor capacity: the wait on its
+    row count and the regather, one ``batch.shrink`` span."""
     import jax
     import jax.numpy as jnp
 

@@ -170,6 +170,8 @@ class TpuShuffleExchangeExec(TpuExec):
                 yield reordered, counts
 
     def _record_part_rows(self, host_counts) -> None:
+        from spark_rapids_tpu.shuffle.stats import SHUFFLE_COUNTERS
+        SHUFFLE_COUNTERS.add(exchange_rows_written=int(host_counts.sum()))
         if self._want_part_stats:
             # host_counts is already on host; a per-piece host_num_rows
             # would re-sync per partition
@@ -399,6 +401,26 @@ def _estimated_row_bytes(schema: Schema) -> int:
     return total
 
 
+def reduce_group_in_core(rows: int, bound: int) -> bool:
+    """THE size rule of the reduce side, read by both of its ends: a group
+    of reduce partitions is one program's work while the ROWS its pieces
+    hold do not pass ``bound`` (the batch capacity the consumer was planned
+    with).  ``SharedCoalesceSpec.groups`` closes a group before the next
+    partition would break it; ``TpuHashAggregateExec._execute_final_fused``
+    takes a group that keeps it as one program and sends any other, which
+    the reader can only have built from a single oversized partition, to
+    the out-of-core merge.
+
+    Rows and not the pieces' capacities: the map side's statistics are row
+    counts (summed over map batches, over ranks in a cluster), a range
+    view's count is exact on the host, and the combine's concat compacts
+    live rows, so its capacity has to cover the rows alone.  A view's
+    capacity is its count rounded up to a power of two, piece by piece:
+    the same partition would read as up to twice its size, and as another
+    size from one data set to the next."""
+    return rows <= bound
+
+
 class SharedCoalesceSpec:
     """ONE contiguous-partition grouping computed from the COMBINED
     materialized sizes of every exchange feeding a consumer.
@@ -407,8 +429,10 @@ class SharedCoalesceSpec:
     GpuCustomShuffleReaderExec.scala:82 reading CoalescedPartitionSpec):
     co-partitioned join sides must merge with the same spec, or partition
     i on the left no longer holds the same key space as partition i on
-    the right.  Greedy merge of adjacent partitions until the combined
-    row count reaches the target."""
+    the right.  Greedy merge of adjacent partitions for as long as the
+    combined row count stays in core (``reduce_group_in_core``): a group
+    is closed BEFORE the partition that would take it past the target, so
+    only a single partition can be a group that is larger."""
 
     def __init__(self, target_rows: int, target_bytes: int = 0):
         self.target_rows = max(int(target_rows), 1)
@@ -471,12 +495,11 @@ class SharedCoalesceSpec:
             cur: List[int] = []
             acc = 0
             for p, n in enumerate(counts):
+                if cur and not reduce_group_in_core(acc + n, target):
+                    groups.append(cur)
+                    cur, acc = [], 0
                 cur.append(p)
                 acc += n
-                if acc >= target:
-                    groups.append(cur)
-                    cur = []
-                    acc = 0
             if cur:
                 groups.append(cur)
             if not groups:

@@ -40,6 +40,7 @@ from __future__ import annotations
 
 import collections
 import threading
+import time
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -61,7 +62,7 @@ from spark_rapids_tpu.plan.execs.base import (
     timed,
     tree_uses_string_bucket,
 )
-from spark_rapids_tpu.utils.tracing import trace_range
+from spark_rapids_tpu.utils.tracing import record_range, trace_range
 
 
 # converged-capacity memory, keyed by segment signature (+ bucket): the
@@ -702,16 +703,16 @@ class TpuFusedSegmentExec(TpuExec):
     def _run(self, stream, builds, slice_spec=None, chain=None, sig=None):
         """One program call as one ``fused.batch`` span: everything the
         host does for it (``stream`` arrives already pulled)."""
-        with trace_range("fused.batch") as span:
-            return self._converge(stream, builds, slice_spec, chain, sig,
-                                  span)
+        with trace_range("fused.batch"):
+            return self._converge(stream, builds, slice_spec, chain, sig)
 
-    def _converge(self, stream, builds, slice_spec, chain, sig, span):
+    def _converge(self, stream, builds, slice_spec, chain, sig):
         """Converge-and-execute one program call.
 
-        Every launch but the last is discarded and counted by what was
-        too small (``launch_stats()["discarded"]``); ``span`` gets the
-        number of launches as its ``attempts`` tag.
+        Every launch but the last is discarded: counted by what was too
+        small (``launch_stats()["discarded"]``) and recorded as one
+        ``fused.discard`` span, from its dispatch to the feedback that
+        condemned it.
 
         ``stream`` is a single ColumnarBatch (per-batch path) or a LIST
         of StreamPieces (across-shuffle path: the group concats inside
@@ -776,10 +777,19 @@ class TpuFusedSegmentExec(TpuExec):
                 return fn(s, tuple(bs), self._consts)
             return retry_over_stream_pieces(piece_lists, body)
 
+        # rows in, for the per-query counters: a scan batch's own count
+        # rides in the feedback's one transfer; a group's pieces say theirs
+        rows_in = (sum(p.rows for p in stream) if group_mode
+                   else stream.num_rows)
+
+        def discard(launched, *reasons):
+            count_discarded_launch(*reasons)
+            record_range("fused.discard", *launched)
+
         caps_key = None
         caps: Dict[str, int] = {}
         kind = _program_kind(chain, slice_spec)
-        for attempt in range(1, 25):
+        for _ in range(24):
             new_key = f"{sig}|bkt={bucket}"
             if new_key != caps_key:      # first pass, or bucket escalated
                 caps_key = new_key
@@ -792,10 +802,13 @@ class TpuFusedSegmentExec(TpuExec):
                             lambda: self._make(bucket, caps, slice_spec,
                                                chain),
                             kind=kind)
+            launched = time.perf_counter(), time.time()
             out, counts, fb = invoke(fn)
             with trace_range("fused.feedback"):
                 # tpu-lint: allow-host-sync(overflow feedback must reach the host; one batched sync per attempt)
-                fetched, host_counts = jax.device_get((fb, counts))
+                fetched, host_counts, host_rows_in = jax.device_get(
+                    (fb, counts,
+                     rows_in if any(k[0] == "g" for k in fb) else None))
             observed = int(fetched.pop("__stream_bytes", 0))
             if observed or bucket:
                 need = SK.bucket_for(max(observed, self._build_bytes,
@@ -807,7 +820,7 @@ class TpuFusedSegmentExec(TpuExec):
                     with _FUSED_CAPS_LOCK:
                         _remember_bucket(base_sig, need)
                     bucket = need
-                    count_discarded_launch("bucket")
+                    discard(launched, "bucket")
                     continue
             escalated = set()
             for k, v in fetched.items():
@@ -816,9 +829,13 @@ class TpuFusedSegmentExec(TpuExec):
                     caps[k] = round_up_pow2(max(req, 1))
                     escalated.add(_CAP_REASON[k[0]])
             if escalated:
-                count_discarded_launch(*escalated)
+                discard(launched, *escalated)
                 continue
-            span.tags = {"attempts": attempt}
+            groups_out = [int(v) for k, v in fetched.items() if k[0] == "g"]
+            if groups_out:
+                from spark_rapids_tpu.shuffle.stats import SHUFFLE_COUNTERS
+                SHUFFLE_COUNTERS.add(agg_partial_rows_in=int(host_rows_in),
+                                     agg_partial_groups_out=sum(groups_out))
             # tracing seeded the capacity defaults AFTER build_key was
             # formed; register the program under the converged key too so
             # the next batch (and the next identical query) hits the jit

@@ -56,6 +56,22 @@ _FIELDS = (
     "range_view_materializes",  # views sliced by a standalone gather for
                               # a non-fused consumer (the materialize
                               # fallback: OOC joins, sort, per-op reads)
+    # the grouped aggregate across an exchange (plan/fused.py _converge,
+    # plan/execs/exchange.py, plan/execs/aggregate.py): numbers the host
+    # holds anyway, no sync of their own
+    "agg_partial_rows_in",    # rows into fused programs that hold a
+                              # grouped partial aggregate (the stream
+                              # batch's, before the chain's filters)
+    "agg_partial_groups_out",  # rows those programs' aggregates handed on:
+                              # their groups, batch by batch (the feedback
+                              # _converge fetches)
+    "exchange_rows_written",  # rows the map side put into its reduce
+                              # partitions (the per-batch counts)
+    "reduce_groups",          # reduce groups a final or complete aggregate
+                              # merged
+    "reduce_groups_out_of_core",  # of them, those that took the out-of-core
+                              # sub-partition merge (one agg.out_of_core
+                              # span each): past reduce_group_in_core
     # map side (range-serialization write path; serializer.py)
     "map_range_batches",      # map batches written via range framing
     "map_range_blocks",       # partition wire blocks framed from row ranges

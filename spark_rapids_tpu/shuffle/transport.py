@@ -96,6 +96,24 @@ class RangeView:
                             out_capacity=self.capacity)
 
 
+def views_at_one_capacity(mats: list) -> list:
+    """``mats`` (what ``materialize_pinned`` returned for the pieces of ONE
+    program call) with every RangeView at the largest capacity among them.
+
+    A view's capacity is static in the program: a program that kept each
+    view's own power of two would be another program for every multiset of
+    partition sizes, compiled anew for every data set whose counts sit
+    near a power of two (16 partitions of 262,594 groups hold 16,412 rows
+    each, 28 over one).  The concat pads every input to the largest
+    capacity anyway (``concat_batches_device``), so the larger slices add
+    gathered rows and no buffer."""
+    cap = max((m.capacity for m in mats if isinstance(m, RangeView)),
+              default=0)
+    return [RangeView(m.batch, m.start, m.count, cap)
+            if isinstance(m, RangeView) and m.capacity != cap else m
+            for m in mats]
+
+
 def piece_batch_in_trace(x):
     """Resolve a stream piece materialization to a plain batch inside a
     traced program: RangeViews slice in-trace, batches pass through.  The
@@ -119,7 +137,8 @@ class StreamPiece:
     dedupes by ``backing_key`` so a backing batch shared by several views
     pins exactly once per attempt."""
 
-    __slots__ = ("capacity", "nbytes", "_handle", "_batch", "_range")
+    __slots__ = ("capacity", "rows", "nbytes", "_handle", "_batch",
+                 "_range")
 
     def __init__(self, capacity: int, nbytes: int, handle=None, batch=None,
                  range_: Optional[Tuple[int, int]] = None):
@@ -127,6 +146,12 @@ class StreamPiece:
         assert (batch is None) == (handle is not None
                                    and range_ is not None)
         self.capacity = int(capacity)   # static row capacity (grouping)
+        #: rows the piece holds, as the host knows them without a sync: a
+        #: view's count, which is exact (the map side's statistics are
+        #: sums of these); an uploaded batch's capacity, the bound on its
+        #: device-resident row count.  What a reduce group is sized by
+        #: (plan/execs/exchange.py reduce_group_in_core)
+        self.rows = int(range_[1]) if range_ is not None else int(capacity)
         self.nbytes = int(nbytes)       # in-flight byte accounting
         self._handle = handle
         self._batch = batch

@@ -271,9 +271,14 @@ def _open_stack() -> list:
     return st
 
 
+def new_span_id() -> int:
+    """A process-unique span id (for a span recorded when it ends)."""
+    return next(_SPAN_IDS)
+
+
 def push_open_span(name: str) -> int:
     """Open a span on this thread; returns its process-unique id."""
-    span_id = next(_SPAN_IDS)
+    span_id = new_span_id()
     _open_stack().append((name, time.monotonic(), span_id))
     return span_id
 
@@ -402,9 +407,7 @@ def instrument_plan(physical) -> None:
                         return
                     _t.add(time.perf_counter_ns() - t0)
                     _batches.add(1)
-                    rng = getattr(piece, "_range", None)
-                    _rows.add(int(rng[1]) if rng
-                              else getattr(piece, "capacity", 0))
+                    _rows.add(piece.rows)
                     yield piece
             node.stream_pieces = timed_pieces
         for c in node.children:

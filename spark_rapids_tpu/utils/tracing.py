@@ -92,16 +92,12 @@ class trace_range:
     A class and not a generator: a range with every sink off is two clock
     reads, a push and a pop."""
 
-    __slots__ = ("name", "t0", "t0_epoch", "span_id", "parent", "_ann",
-                 "tags")
+    __slots__ = ("name", "t0", "t0_epoch", "span_id", "parent", "_ann")
 
     def __init__(self, name: str, doc: Optional[str] = None):
         if doc is not None and name not in _registry:
             register_range(name, doc)
         self.name = name
-        #: attributes of this span in the per-query trace (``tags`` of its
-        #: entry in ``spans_snapshot()``), set by the code inside the range
-        self.tags: Optional[dict] = None
 
     def __enter__(self):
         global _TraceAnnotation
@@ -126,9 +122,23 @@ class trace_range:
         tr = obs.current_query_trace()
         if tr is not None:
             tr.record_span(self.name, self.t0_epoch, time.time(),
-                           tags=self.tags, span_id=self.span_id,
-                           parent=self.parent)
+                           span_id=self.span_id, parent=self.parent)
         return False
+
+
+def record_range(name: str, t0: float, t0_epoch: float) -> None:
+    """A range that is known to be one only when it ends (a launch is a
+    ``fused.discard`` once its feedback has condemned it): from ``t0``
+    (``time.perf_counter``; ``t0_epoch`` the same instant on
+    ``time.time``) to now, into the span log and the ambient per-query
+    trace, a child of the span open on this thread.  The profiler's trace
+    cannot be written after the fact and does not get it."""
+    span_log.record(name, t0, time.perf_counter())
+    tr = obs.current_query_trace()
+    if tr is not None:
+        tr.record_span(name, t0_epoch, time.time(),
+                       span_id=obs.new_span_id(),
+                       parent=obs.current_span_id())
 
 
 def generate_ranges_doc() -> str:
@@ -194,6 +204,21 @@ _STATIC_RANGES = (
                     "feedback"),
     ("fused.feedback", "task thread blocked on the device for the "
                        "capacity feedback of one fused-program call"),
+    ("fused.discard", "one launch of a fused program that was thrown away "
+                      "and run again larger: from its dispatch to the "
+                      "feedback that condemned it (written then: in the "
+                      "span log and the query trace, not the profiler's)"),
+    # final aggregate + HAVING (plan/execs/aggregate.py, coalesce.py)
+    ("agg.final", "the final aggregate's own work for one reduce group: "
+                  "pull the group's pieces from the exchange's read side, "
+                  "dispatch the combine (or, out of core, one bucket's "
+                  "merge and finalize)"),
+    ("agg.out_of_core", "one per reduce group that outgrew the in-core "
+                        "bound: its partials sub-partitioned into "
+                        "spillable buckets (keyless: the tree merge)"),
+    ("batch.shrink", "maybe_shrink of a batch over the floor capacity: "
+                     "the host sync on its row count and, where it is "
+                     "sparse, the regather dispatch"),
     # exchange + range sort (plan/execs/exchange.py, range_sort.py)
     ("exchange.write", "the exchange's own map-side work for one map "
                        "batch: slice dispatch, counts sync or download, "

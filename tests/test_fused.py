@@ -795,11 +795,13 @@ def test_masked_grouped_agg_under_a_slice_matches_the_compacting_lowering(
         assert _live_rows(b) == _live_rows(cb)
 
 
-def test_fused_batch_span_says_how_many_launches_it_took(
-        fresh_program_caches):
-    """``attempts`` on ``fused.batch`` and ``launch_stats()["discarded"]``:
-    2 and ``group_cap`` for the batch that outgrew the default, 1 and
-    nothing once the signature remembers its capacity."""
+def test_a_discarded_launch_is_one_fused_discard_span(fresh_program_caches):
+    """One ``fused.discard`` span, a child of its ``fused.batch``, and
+    ``group_cap`` in ``launch_stats()["discarded"]`` for the batch that
+    outgrew the default in a process's first query; none once the
+    signature remembers its capacity.  The span runs from the launch's
+    dispatch to the feedback that condemned it: that ``fused.feedback``
+    lies inside it."""
     from spark_rapids_tpu.plan.execs.base import (
         launch_stats, reset_launch_stats)
     from spark_rapids_tpu.utils import tracing
@@ -810,15 +812,27 @@ def test_fused_batch_span_says_how_many_launches_it_took(
     try:
         for _ in range(2):
             reset_launch_stats()
+            tracing.span_log.clear()
             assert df.collect()
             spans = s.last_query_trace.spans_snapshot()
-            seen.append((sorted(sp["tags"]["attempts"] for sp in spans
-                                if sp["name"] == "fused.batch"),
-                         launch_stats()["discarded"]))
+            batches = [sp["id"] for sp in spans if sp["name"] == "fused.batch"]
+            discards = [sp for sp in spans if sp["name"] == "fused.discard"]
+            seen.append((sorted(sum(d["parent"] == b for d in discards)
+                                for b in batches),
+                         launch_stats()["discarded"],
+                         tracing.span_log.summary().get("fused.discard",
+                                                        (0, 0.0))[0]))
+            for d in discards:
+                assert any(sp["name"] == "fused.feedback"
+                           and sp["parent"] == d["parent"]
+                           and d["t0"] <= sp["t0"] and sp["t1"] <= d["t1"]
+                           for sp in spans), (d, spans)
+            assert all("tags" not in sp for sp in spans
+                       if sp["name"].startswith("fused."))
     finally:
         tracing.span_log.enabled = False
         tracing.span_log.clear()
-    assert seen == [([1, 2], {"group_cap": 1}), ([1, 1], {})]
+    assert seen == [([0, 1], {"group_cap": 1}, 1), ([0, 0], {}, 0)]
 
 
 BSCHEMA = Schema.of(k=T.INT, a=T.STRING, b=T.STRING, s=T.STRING, v=T.DOUBLE,

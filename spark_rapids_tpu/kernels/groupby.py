@@ -17,7 +17,7 @@ Spark grouping semantics honored here:
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -26,8 +26,9 @@ import numpy as np
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnarBatch, Schema
 from spark_rapids_tpu.columnar.column import DeviceColumn
-from spark_rapids_tpu.kernels.selection import compaction_map, gather_batch, gather_column
-from spark_rapids_tpu.kernels.sort import SortOrder, sort_indices
+from spark_rapids_tpu.kernels.selection import OOB, compaction_map, gather_column
+from spark_rapids_tpu.kernels.sort import (
+    BYTES_PER_CHUNK, SortOrder, sort_indices, string_key_planes)
 
 
 def normalize_key_column(col: DeviceColumn) -> DeviceColumn:
@@ -71,27 +72,65 @@ def _rows_equal_prev(col: DeviceColumn) -> jax.Array:
     return eq & same_null
 
 
-def _string_rows_equal_prev(col: DeviceColumn, max_bytes: int) -> jax.Array:
-    from spark_rapids_tpu.kernels.sort import _string_data_keys
-    chunks = _string_data_keys(col, SortOrder(True), max_bytes)
-    starts = col.offsets[:-1]
-    lengths = col.offsets[1:] - starts
-    eq = lengths == jnp.roll(lengths, 1)
-    for c in chunks:
-        eq = eq & (c == jnp.roll(c, 1))
-    same_null = col.validity == jnp.roll(col.validity, 1)
-    return eq & same_null
+def _string_rows_equal_prev(col: DeviceColumn, planes: jax.Array,
+                            steps: jax.Array, idx: jax.Array) -> jax.Array:
+    """[capacity] bool: in the order ``idx``, row i holds the string of row
+    i-1 (null==null).  ``planes``, ``steps``: the column's chunk keys and
+    the byte steps they took (``_string_chunk_planes``), in input order.
+    Chunk sequences are injective for strings within the bucket, so equal
+    chunks are equal bytes; the lengths are compared beside them, so a key
+    longer than the bucket merges only with one of its own length.  A chunk
+    past the longest string is zero on every row and is not gathered."""
+    lengths = col.offsets[1:] - col.offsets[:-1]
+    tag = jnp.where(col.validity, lengths, -1)[idx]     # -1: null
+
+    def step(c, eq):
+        chunk = planes[c][idx]
+        return eq & (chunk == jnp.roll(chunk, 1))
+
+    return jax.lax.fori_loop(0, -(-steps // BYTES_PER_CHUNK), step,
+                             tag == jnp.roll(tag, 1))
 
 
 @dataclasses.dataclass
 class GroupedLayout:
-    """Result of the grouping phase: the batch sorted by keys plus segment
-    structure.  Aggregations are segment reductions over this layout."""
+    """Result of the grouping phase: the order of the rows by key plus
+    segment structure; no column is moved but the fixed-width keys, which
+    the boundaries compare in sorted order.  Aggregations are segment
+    reductions over the columns a caller asks for in that order
+    (``sorted_column``): what it does not read is not gathered."""
 
-    sorted_batch: ColumnarBatch
+    source: ColumnarBatch        # rows in input order, keys normalized
+    indices: jax.Array           # int32 [capacity]: sorted position -> source row
+    num_rows: jax.Array          # scalar int32: the rows that count, a prefix of the order
     segment_ids: jax.Array       # int32 [capacity], 0-based; padding rows -> last
     num_groups: jax.Array        # scalar int32
     boundary: jax.Array          # bool [capacity], True at first row of group
+    _sorted: Dict[int, DeviceColumn]
+
+    @property
+    def capacity(self) -> int:
+        return self.indices.shape[0]
+
+    def live_mask(self) -> jax.Array:
+        return jnp.arange(self.capacity, dtype=jnp.int32) < self.num_rows
+
+    def sorted_column(self, ci: int) -> DeviceColumn:
+        """Column ``ci`` of the source in sorted order, rows past
+        ``num_rows`` canonical padding; gathered once."""
+        if ci not in self._sorted:
+            with jax.named_scope("gather_sorted"):
+                self._sorted[ci] = gather_column(
+                    self.source.columns[ci], self.indices, self.num_rows)
+        return self._sorted[ci]
+
+    @property
+    def sorted_batch(self) -> ColumnarBatch:
+        """Every column in sorted order, for a caller that reads them all."""
+        return ColumnarBatch(
+            tuple(self.sorted_column(ci)
+                  for ci in range(len(self.source.columns))),
+            self.num_rows, self.source.schema)
 
 
 @jax.named_scope("group_rows")
@@ -102,18 +141,20 @@ def group_rows(
     allow_split_groups: bool = False,
     live: Optional[jax.Array] = None,
 ) -> GroupedLayout:
-    """Sort rows by keys and delimit groups.
+    """Order rows by keys and delimit groups.
 
     ``live``: bool [capacity], the rows that count where they are not the
     prefix ``batch.live_mask()`` (a fused filter's mask, taken in the place
     of its compaction).  The sort sinks every other row to the end and is
-    stable, so the sorted batch holds the rows that count as a prefix of
+    stable, so the order holds the rows that count as a prefix of
     ``sum(live)`` rows, in the order a compaction before the sort would
     have left them in: everything that reads the layout reads what it
     would have read of a compacted batch.
 
     string_max_bytes must cover the longest live string key or distinct
     groups silently merge; None derives it from the data (host sync).
+    A string key's chunk keys are built once, on the rows as given: the
+    sort reads them, and the boundaries read them through the order.
 
     ``allow_split_groups``: sort string keys by ONE hashed key each
     instead of their full chunk sequence — ceil(max_bytes/7) sort passes
@@ -135,42 +176,47 @@ def group_rows(
         cols[ci] = normalize_key_column(cols[ci])
     nb = ColumnarBatch(tuple(cols), batch.num_rows, batch.schema)
 
+    planes = string_key_planes(nb, key_cols, string_max_bytes)
     orders = [SortOrder(True, True) for _ in key_cols]
     idx = sort_indices(nb, key_cols, orders, string_max_bytes,
-                       hash_string_keys=allow_split_groups, live=live)
+                       hash_string_keys=allow_split_groups, live=live,
+                       string_planes=planes)
     count = (nb.num_rows if live is None
-             else jnp.sum(live.astype(jnp.int32)))
-    sb = gather_batch(nb, idx, count)
+             else jnp.sum(live.astype(jnp.int32))).astype(jnp.int32)
 
-    live = sb.live_mask()       # from here on the rows that count: a prefix
-    eq = jnp.ones((sb.capacity,), dtype=jnp.bool_)
+    # from here on the rows that count are a prefix of the order
+    cap = idx.shape[0]
+    live = jnp.arange(cap, dtype=jnp.int32) < count
+    sorted_keys = {}
+    eq = jnp.ones((cap,), dtype=jnp.bool_)
     for ci in key_cols:
-        col = sb.columns[ci]
-        if col.is_string_like:
-            eq = eq & _string_rows_equal_prev(col, string_max_bytes)
+        if ci in planes:
+            eq = eq & _string_rows_equal_prev(nb.columns[ci], *planes[ci], idx)
         else:
-            eq = eq & _rows_equal_prev(col)
-    first_row = jnp.arange(sb.capacity, dtype=jnp.int32) == 0
+            sorted_keys[ci] = gather_column(nb.columns[ci], idx, count)
+            eq = eq & _rows_equal_prev(sorted_keys[ci])
+    first_row = jnp.arange(cap, dtype=jnp.int32) == 0
     boundary = live & (first_row | ~eq)
     segment_ids = jnp.cumsum(boundary.astype(jnp.int32)) - 1
-    segment_ids = jnp.where(live, segment_ids, sb.capacity - 1)
+    segment_ids = jnp.where(live, segment_ids, cap - 1)
     num_groups = jnp.sum(boundary.astype(jnp.int32))
-    return GroupedLayout(sb, segment_ids.astype(jnp.int32), num_groups, boundary)
+    return GroupedLayout(nb, idx, count, segment_ids.astype(jnp.int32),
+                         num_groups, boundary, sorted_keys)
 
 
 # -- segment reductions -----------------------------------------------------
 
 def seg_count_valid(col: DeviceColumn, layout: GroupedLayout) -> Tuple[jax.Array, jax.Array]:
     """COUNT(col): number of non-null values per group -> (int64, validity)."""
-    live = layout.sorted_batch.live_mask()
+    live = layout.live_mask()
     contrib = (col.validity & live).astype(jnp.int64)
     out = jax.ops.segment_sum(contrib, layout.segment_ids, num_segments=col.capacity)
     return out, jnp.ones((col.capacity,), jnp.bool_)
 
 
 def seg_count_star(layout: GroupedLayout) -> Tuple[jax.Array, jax.Array]:
-    cap = layout.sorted_batch.capacity
-    live = layout.sorted_batch.live_mask()
+    cap = layout.capacity
+    live = layout.live_mask()
     out = jax.ops.segment_sum(live.astype(jnp.int64), layout.segment_ids, num_segments=cap)
     return out, jnp.ones((cap,), jnp.bool_)
 
@@ -178,7 +224,7 @@ def seg_count_star(layout: GroupedLayout) -> Tuple[jax.Array, jax.Array]:
 def seg_sum(col: DeviceColumn, layout: GroupedLayout, out_dtype) -> Tuple[jax.Array, jax.Array]:
     """SUM: nulls ignored; all-null group -> null; int64 overflow wraps
     (non-ANSI Spark)."""
-    live = layout.sorted_batch.live_mask()
+    live = layout.live_mask()
     valid = col.validity & live
     vals = col.data.astype(out_dtype)
     contrib = jnp.where(valid, vals, jnp.zeros((), out_dtype))
@@ -194,7 +240,7 @@ def seg_m2_update(col: DeviceColumn, layout: GroupedLayout) -> Tuple[jax.Array, 
     The two-pass form avoids the sum-of-squares cancellation the textbook
     identity suffers when mean >> stddev (reference: Welford/Chan numerics
     in aggregateFunctions.scala GpuStddevSamp)."""
-    live = layout.sorted_batch.live_mask()
+    live = layout.live_mask()
     valid = col.validity & live
     x = col.data.astype(jnp.float64)
     cap = col.capacity
@@ -212,7 +258,7 @@ def seg_m2_update(col: DeviceColumn, layout: GroupedLayout) -> Tuple[jax.Array, 
 def seg_m2_merge(m2col: DeviceColumn, scol: DeviceColumn, ncol: DeviceColumn,
                  layout: GroupedLayout) -> Tuple[jax.Array, jax.Array]:
     """Chan's parallel merge: M2 = sum_i M2_i + n_i*(mean_i - mean)^2."""
-    live = layout.sorted_batch.live_mask()
+    live = layout.live_mask()
     valid = m2col.validity & live
     n_i = jnp.where(valid, ncol.data.astype(jnp.float64), 0.0)
     s_i = jnp.where(valid, scol.data.astype(jnp.float64), 0.0)
@@ -241,7 +287,7 @@ def seg_min(col: DeviceColumn, layout: GroupedLayout) -> Tuple[jax.Array, jax.Ar
     """Spark MIN: NaN sorts greater than everything (Spark's total order), so
     MIN returns the smallest non-NaN value and is NaN only for all-NaN
     groups.  segment_min's native NaN propagation would be wrong here."""
-    live = layout.sorted_batch.live_mask()
+    live = layout.live_mask()
     valid = col.validity & live
     ident = _extreme(col.data.dtype, is_min=True)
     if jnp.issubdtype(col.data.dtype, jnp.floating):
@@ -269,7 +315,7 @@ def seg_max(col: DeviceColumn, layout: GroupedLayout) -> Tuple[jax.Array, jax.Ar
     """Spark MAX: NaN is the greatest value, so any valid NaN in the group
     makes the result NaN (explicitly, not via float-max propagation, whose
     NaN behavior XLA does not guarantee)."""
-    live = layout.sorted_batch.live_mask()
+    live = layout.live_mask()
     valid = col.validity & live
     ident = _extreme(col.data.dtype, is_min=False)
     if jnp.issubdtype(col.data.dtype, jnp.floating):
@@ -308,7 +354,12 @@ def group_keys_output(layout: GroupedLayout, key_cols: Sequence[int],
                       string_max_bytes: int = 0) -> List[DeviceColumn]:
     """Gather the first row of each group for the key output columns.
 
-    The columns have the sorted batch's capacity, or ``out_capacity`` rows
+    A fixed-width key is read from its sorted copy, which the boundaries
+    compared; a string key has none and is read from the source through the
+    order at the group starts, so its gather runs over as many rows and bytes
+    as the output has and not over the batch's byte plane.
+
+    The columns have the layout's capacity, or ``out_capacity`` rows
     where one is given: the gather then reads the first ``out_capacity``
     group starts only, and a string key's byte plane holds ``out_capacity
     × string_max_bytes`` bytes (a string gather costs by its output byte
@@ -317,16 +368,25 @@ def group_keys_output(layout: GroupedLayout, key_cols: Sequence[int],
     ``layout.num_groups`` fits ``out_capacity``: the caller reports the
     group count to whoever chose the capacity and discards an output that
     overflowed (``plan/fused.py``'s ``g<pos>`` feedback)."""
-    indices, count = compaction_map(layout.boundary)
-    cols = [layout.sorted_batch.columns[ci] for ci in key_cols]
-    return [
-        gather_column(
-            col, indices, count, out_capacity=out_capacity,
-            out_byte_capacity=(
-                None if out_capacity is None else
-                string_plane_capacity(col, out_capacity, string_max_bytes)))
-        for col in cols
-    ]
+    starts, count = compaction_map(layout.boundary)
+    starts = starts[:out_capacity]
+    cap = layout.capacity
+    source_rows = jnp.where(
+        starts < cap, layout.indices[jnp.minimum(starts, cap - 1)], OOB)
+    out = []
+    for ci in key_cols:
+        col = layout.source.columns[ci]
+        if col.is_string_like:
+            out.append(gather_column(
+                col, source_rows, count, out_capacity=out_capacity,
+                out_byte_capacity=(
+                    None if out_capacity is None else
+                    string_plane_capacity(col, out_capacity,
+                                          string_max_bytes))))
+        else:
+            out.append(gather_column(layout.sorted_column(ci), starts, count,
+                                     out_capacity=out_capacity))
+    return out
 
 
 def finalize_agg_column(values: jax.Array, validity: jax.Array,
@@ -383,7 +443,7 @@ def seg_extreme_string(col: DeviceColumn, layout: GroupedLayout,
     RANK per segment selects the first row (input order) holding the
     extreme value; all-null groups yield null."""
     from spark_rapids_tpu.kernels.selection import OOB, gather_column
-    live = layout.sorted_batch.live_mask()
+    live = layout.live_mask()
     cap = col.capacity
     rank = string_order_rank(col, max_bytes)
     valid = col.validity & live
@@ -447,7 +507,7 @@ def seg_pick(col: DeviceColumn, layout: GroupedLayout, ignore_nulls: bool,
     """FIRST/LAST as a gather: works for every device dtype incl. strings
     (the picked subset can never exceed the source byte planes)."""
     from spark_rapids_tpu.kernels.selection import OOB, gather_column
-    live = layout.sorted_batch.live_mask()
+    live = layout.live_mask()
     eligible = live & col.validity if ignore_nulls else live
     arg, has = _seg_arg(eligible, layout, last)
     idx = jnp.where(has, arg, jnp.int32(OOB))
@@ -465,7 +525,7 @@ def seg_pick_by(xcol: DeviceColumn, ycol: DeviceColumn,
     String ordering keys reduce over their rank surrogate
     (string_order_rank; string_max_bytes must cover the longest live y)."""
     from spark_rapids_tpu.kernels.selection import OOB, gather_column
-    live = layout.sorted_batch.live_mask()
+    live = layout.live_mask()
     if ycol.is_string_like:
         ycol = _string_rank_column(ycol, string_max_bytes)
     ycol = normalize_key_column(ycol)
@@ -489,7 +549,7 @@ def seg_bitwise(col: DeviceColumn, layout: GroupedLayout, op: str,
     """bit_and / bit_or / bit_xor over integral groups via a segmented
     inclusive scan (flag-resetting combine), reading the running value at
     each segment's last live row."""
-    live = layout.sorted_batch.live_mask()
+    live = layout.live_mask()
     valid = col.validity & live
     ident = jnp.asarray(_BIT_IDENT[op], out_dtype)
     x = jnp.where(valid, col.data.astype(out_dtype), ident)

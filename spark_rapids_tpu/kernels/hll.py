@@ -107,7 +107,7 @@ def seg_update(col, layout, p: int) -> jax.Array:
     """Grouped registers [capacity, m] int8 over a GroupedLayout."""
     m = 1 << p
     cap = col.capacity
-    live = layout.sorted_batch.live_mask()
+    live = layout.live_mask()
     valid = col.validity & live
     v = col.data.astype(jnp.int64).astype(jnp.uint64)
     idx, rho = row_idx_rho(v, valid, p)

@@ -18,7 +18,7 @@ max_bytes is a static bucket — the planner falls back for longer sort keys.
 """
 from __future__ import annotations
 
-from typing import List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -157,32 +157,62 @@ def _struct_data_keys(col: DeviceColumn, order: SortOrder) -> List[jax.Array]:
 BYTES_PER_CHUNK = 7  # 9-bit lanes (byte value + 1; 0 = past end) in a uint64
 
 
-def _string_data_keys(col: DeviceColumn, order: SortOrder, max_bytes: int) -> List[jax.Array]:
-    """uint64 chunk keys, most-significant chunk first.  Lexicographic byte
-    order == unsigned comparison of the chunk sequence (Spark
-    UTF8String.binaryCompare)."""
+def _string_chunk_planes(col: DeviceColumn, max_bytes: int):
+    """(uint64 [n_chunks, capacity] ascending chunk keys, most-significant
+    chunk first and zero on null rows; int32 scalar: the byte steps run).
+
+    The shapes follow the static bucket, ``ceil(max_bytes / 7)`` chunks; the
+    work follows the data: one u8 gather a byte position up to the longest
+    string of the column (padding and dead rows included: never fewer steps
+    than a live key needs), a device scalar, so a char(1) column under a
+    16-byte bucket costs one gather and not 21.  A position no step visits
+    leaves lane 0, which is what "past the end" is."""
     starts = col.offsets[:-1]
     lengths = col.offsets[1:] - starts
     n_chunks = max(1, -(-max_bytes // BYTES_PER_CHUNK))
-    keys = []
-    for c in range(n_chunks):
-        chunk = jnp.zeros((col.capacity,), dtype=jnp.uint64)
-        for b in range(BYTES_PER_CHUNK):
-            pos = c * BYTES_PER_CHUNK + b
-            idx = jnp.clip(starts + pos, 0, col.data.shape[0] - 1)
-            lane = jnp.where(
-                pos < lengths, col.data[idx].astype(jnp.uint64) + 1, jnp.uint64(0)
-            )
-            chunk = (chunk << 9) | lane
-        if not order.ascending:
-            chunk = ~chunk
-        keys.append(jnp.where(col.validity, chunk, jnp.uint64(0)))
-    return keys
+    steps = jnp.minimum(jnp.max(lengths, initial=0),
+                        n_chunks * BYTES_PER_CHUNK).astype(jnp.int32)
+    last = col.data.shape[0] - 1
+
+    def step(pos, planes):
+        byte = col.data[jnp.clip(starts + pos, 0, last)]
+        lane = jnp.where(pos < lengths, byte.astype(jnp.uint64) + 1,
+                         jnp.uint64(0))
+        c = pos // BYTES_PER_CHUNK
+        shift = 9 * (BYTES_PER_CHUNK - 1 - pos % BYTES_PER_CHUNK)
+        return planes.at[c].set(planes[c] | (lane << shift.astype(jnp.uint64)))
+
+    planes = jax.lax.fori_loop(
+        0, steps, step, jnp.zeros((n_chunks, col.capacity), jnp.uint64))
+    return jnp.where(col.validity[None, :], planes, jnp.uint64(0)), steps
 
 
-def _string_hash_key(col: DeviceColumn, max_bytes: int) -> jax.Array:
+def string_key_planes(batch: ColumnarBatch, key_cols: Sequence[int],
+                      max_bytes: int) -> Dict[int, tuple]:
+    """{ordinal: ``_string_chunk_planes``} of the string columns among
+    ``key_cols``: for a caller that sorts by them (``sort_indices``,
+    ``string_planes=``) and reads them again to delimit runs of equal keys,
+    so that they are built once."""
+    return {ci: _string_chunk_planes(batch.columns[ci], max_bytes)
+            for ci in key_cols if batch.columns[ci].is_string_like}
+
+
+def _string_data_keys(col: DeviceColumn, order: SortOrder, max_bytes: int,
+                      planes: Optional[jax.Array] = None) -> List[jax.Array]:
+    """uint64 chunk keys, most-significant chunk first.  Lexicographic byte
+    order == unsigned comparison of the chunk sequence (Spark
+    UTF8String.binaryCompare).  ``planes``: the column's ascending planes
+    where the caller holds them already."""
+    if planes is None:
+        planes, _ = _string_chunk_planes(col, max_bytes)
+    if not order.ascending:
+        planes = jnp.where(col.validity[None, :], ~planes, jnp.uint64(0))
+    return list(planes)
+
+
+def _string_hash_key(col: DeviceColumn, chunks: Sequence[jax.Array]) -> jax.Array:
     """ONE uint64 GROUPING key per string column: an FNV-1a-style fold of
-    the lexicographic chunk keys.  Equal strings always hash equal;
+    its ascending chunk keys.  Equal strings always hash equal;
     distinct strings may collide — so this key is ONLY valid for callers
     that need EQUAL-KEYS-CONTIGUOUS rather than byte order, and whose
     group boundaries re-verify the actual bytes (groupby's exact
@@ -192,7 +222,7 @@ def _string_hash_key(col: DeviceColumn, max_bytes: int) -> jax.Array:
     partials merge again downstream) trade that for sorting 1 key pass
     per string column instead of ceil(max_bytes/7) passes."""
     h = jnp.full((col.capacity,), jnp.uint64(14695981039346656037))
-    for chunk in _string_data_keys(col, SortOrder(True), max_bytes):
+    for chunk in chunks:
         h = (h ^ chunk) * jnp.uint64(1099511628211)
     return jnp.where(col.validity, h, jnp.uint64(0))
 
@@ -205,6 +235,7 @@ def sort_indices(
     string_max_bytes: Optional[int] = None,
     hash_string_keys: bool = False,
     live: Optional[jax.Array] = None,
+    string_planes: Optional[Dict[int, tuple]] = None,
 ) -> jax.Array:
     """Stable argsort of live rows by the given keys; padding rows at end.
     Returns int32 [capacity] gather indices.
@@ -219,18 +250,26 @@ def sort_indices(
 
     ``hash_string_keys``: sort strings by ONE hashed key each instead of
     their chunk sequence — equal-keys-contiguous (up to rare collision
-    SPLITS), not byte order; see _string_hash_key for the contract."""
+    SPLITS), not byte order; see _string_hash_key for the contract.
+
+    ``string_planes``: ``string_key_planes`` of the batch under
+    ``string_max_bytes``, from a caller that reads them again; a string key
+    that is not in it gets its chunks built here."""
     if string_max_bytes is None:
         from spark_rapids_tpu.kernels import strings as strkern
         string_max_bytes = strkern.live_string_bucket_for_batch(batch, key_cols)
     keys = []  # least significant first (jnp.lexsort: last key is primary)
     for ci, order in zip(reversed(list(key_cols)), reversed(list(orders))):
         col = batch.columns[ci]
-        if col.is_string_like and hash_string_keys:
-            keys.append(_string_hash_key(col, string_max_bytes))
-        elif col.is_string_like:
-            for chunk in reversed(_string_data_keys(col, order, string_max_bytes)):
-                keys.append(chunk)
+        if col.is_string_like:
+            planes, _ = (string_planes or {}).get(ci, (None, None))
+            chunks = _string_data_keys(
+                col, SortOrder(True) if hash_string_keys else order,
+                string_max_bytes, planes)
+            if hash_string_keys:
+                keys.append(_string_hash_key(col, chunks))
+            else:
+                keys.extend(reversed(chunks))
         elif col.is_struct and isinstance(col.dtype, T.DecimalType):
             for k in reversed(_decimal128_data_keys(col, order)):
                 keys.append(k)

@@ -113,7 +113,7 @@ def _digest_from_weighted(values, weights, seg, valid, cap, num_groups,
 def seg_update(col: DeviceColumn, layout, delta: int,
                want: str) -> DeviceColumn:
     """Raw grouped rows -> centroid arrays (update phase)."""
-    live = layout.sorted_batch.live_mask()
+    live = layout.live_mask()
     valid = col.validity & live
     cap = col.capacity
     return _digest_from_weighted(
@@ -151,7 +151,7 @@ def seg_merge(means_col: DeviceColumn, weights_col: DeviceColumn, layout,
               delta: int, want: str) -> DeviceColumn:
     """Partial digests (array rows) -> merged digests per group: pool all
     centroids of a group, re-cluster by cumulative weight."""
-    live = layout.sorted_batch.live_mask()
+    live = layout.live_mask()
     row_valid = means_col.validity & live
     cap = means_col.capacity
     ev, ew, eseg, elive, _ = _element_points(

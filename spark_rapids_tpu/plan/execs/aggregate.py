@@ -114,7 +114,7 @@ def _seg_sum128(col: DeviceColumn, count_col: Optional[DeviceColumn],
     count is an overflow marker and poisons its group (SPARK-28067
     semantics); fresh overflow beyond the buffer precision nulls too."""
     from spark_rapids_tpu.kernels import decimal as DK
-    live = layout.sorted_batch.live_mask()
+    live = layout.live_mask()
     valid = col.validity & live
     cap = col.capacity
     hi, lo = DK.limbs_of(col, col.dtype)
@@ -138,7 +138,7 @@ def _seg_extreme128(col: DeviceColumn, layout: G.GroupedLayout,
     """Segmented min/max over two-limb decimal columns (update AND merge:
     min of mins is min).  Null inputs/partials are simply excluded."""
     from spark_rapids_tpu.kernels import decimal as DK
-    live = layout.sorted_batch.live_mask()
+    live = layout.live_mask()
     valid = col.validity & live
     cap = col.capacity
     hi, lo = DK.limbs_of(col, col.dtype)
@@ -586,8 +586,8 @@ class _AggDeviceSpec:
         cols = list(out_keys)
         for ai, slot in self.slot_specs:
             agg = self.aggregates[ai]
-            col = (layout.sorted_batch.columns[
-                       col_of_agg[(id(agg), slot.input_index)]]
+            col = (layout.sorted_column(
+                       col_of_agg[(id(agg), slot.input_index)])
                    if agg.inputs else None)
             if slot.update_op == HLL_UPDATE:
                 from spark_rapids_tpu.kernels import hll as HLL
@@ -603,7 +603,7 @@ class _AggDeviceSpec:
                                             slot.update_op == MIN128))
                 continue
             if slot.update_op == COLLECT:
-                live2 = layout.sorted_batch.live_mask()
+                live2 = layout.live_mask()
                 cols.append(_collect_update(col, layout, live2,
                                             layout.num_groups))
                 continue
@@ -619,8 +619,7 @@ class _AggDeviceSpec:
                                        slot.update_op.startswith("last")))
                 continue
             if slot.update_op in (MAXBY_VAL, MINBY_VAL):
-                ycol = layout.sorted_batch.columns[
-                    col_of_agg[(id(agg), 1)]]
+                ycol = layout.sorted_column(col_of_agg[(id(agg), 1)])
                 cols.append(G.seg_pick_by(col, ycol, layout,
                                           slot.update_op == MINBY_VAL,
                                           string_max_bytes=string_bucket))
@@ -732,12 +731,12 @@ class _AggDeviceSpec:
         out_keys = G.group_keys_output(layout, list(range(nkeys)))
         cols = list(out_keys)
         for si, (ai, slot) in enumerate(self.slot_specs):
-            col = layout.sorted_batch.columns[nkeys + si]
+            col = layout.sorted_column(nkeys + si)
             if slot.merge_op == HLL_MERGE:
                 agg = self.aggregates[ai]
                 cap = col.capacity
                 regs2d = _hll_regs2d(col, cap, agg.m)
-                live2 = layout.sorted_batch.live_mask()
+                live2 = layout.live_mask()
                 keep = (col.validity & live2)[:, None]
                 r = jnp.where(keep, regs2d, jnp.int8(0))
                 merged = jax.ops.segment_max(
@@ -747,8 +746,7 @@ class _AggDeviceSpec:
                                            cap, agg.m))
                 continue
             if slot.merge_op == SUM128:
-                ncol = layout.sorted_batch.columns[
-                    nkeys + self._count_companion(ai)]
+                ncol = layout.sorted_column(nkeys + self._count_companion(ai))
                 cols.append(_seg_sum128(col, ncol, layout, slot.dtype))
                 continue
             if slot.merge_op in (MIN128, MAX128):
@@ -756,7 +754,7 @@ class _AggDeviceSpec:
                                             slot.merge_op == MIN128))
                 continue
             if slot.merge_op == COLLECT_MERGE:
-                live2 = layout.sorted_batch.live_mask()
+                live2 = layout.live_mask()
                 cols.append(_collect_merge(col, layout, live2,
                                            layout.num_groups))
                 continue
@@ -764,8 +762,8 @@ class _AggDeviceSpec:
                 from spark_rapids_tpu.kernels import tdigest as TDK
                 m_si = self._td_companion(ai, TD_MEANS)
                 w_si = self._td_companion(ai, TD_WEIGHTS)
-                mc = layout.sorted_batch.columns[nkeys + m_si]
-                wc = layout.sorted_batch.columns[nkeys + w_si]
+                mc = layout.sorted_column(nkeys + m_si)
+                wc = layout.sorted_column(nkeys + w_si)
                 cols.append(TDK.seg_merge(
                     mc, wc, layout, self.aggregates[ai].delta,
                     "means" if slot.merge_op == TD_MEANS_MERGE
@@ -777,8 +775,7 @@ class _AggDeviceSpec:
                                        slot.merge_op.startswith("last")))
                 continue
             if slot.merge_op in (MAXBY_VAL, MINBY_VAL):
-                ycol = layout.sorted_batch.columns[
-                    nkeys + self._by_companion(ai)]
+                ycol = layout.sorted_column(nkeys + self._by_companion(ai))
                 cols.append(G.seg_pick_by(col, ycol, layout,
                                           slot.merge_op == MINBY_VAL,
                                           string_max_bytes=string_bucket))
@@ -797,8 +794,8 @@ class _AggDeviceSpec:
             if slot.merge_op == M2_MERGE:
                 s_si, n_si = self._m2_companions(ai)
                 v, valid = G.seg_m2_merge(
-                    col, layout.sorted_batch.columns[nkeys + s_si],
-                    layout.sorted_batch.columns[nkeys + n_si], layout)
+                    col, layout.sorted_column(nkeys + s_si),
+                    layout.sorted_column(nkeys + n_si), layout)
             else:
                 v, valid = _seg_update(slot.merge_op, col, layout, slot.dtype)
             cols.append(G.finalize_agg_column(

@@ -19,6 +19,7 @@ from __future__ import annotations
 import collections
 import functools
 import hashlib
+import sys
 import threading
 import time
 from typing import Callable, Dict, Iterator, List, Optional, Tuple
@@ -68,6 +69,44 @@ class MaterializeLock:
 _JIT_CACHE: "collections.OrderedDict[str, object]" = collections.OrderedDict()
 _JIT_CACHE_MAX = 512
 _JIT_CACHE_LOCK = threading.Lock()
+
+
+# On the CPU backend an executable is JIT-compiled code in anonymous memory
+# mappings, 130 to 300 of them a program with a sort in it, and the kernel
+# gives a process vm.max_map_count of them (65,530 by default): some 300
+# cached programs, fewer than _JIT_CACHE_MAX.  Past it mmap fails inside the
+# compiler and the process dies there (SIGSEGV or abort in
+# backend_compile_and_load; a tier-1 worker at 64,894 mappings, PR 34).  So
+# the cache is also bounded by what it can observe of the process: past half
+# the allowance the least recently used half goes.  A TPU executable maps
+# nothing like it, and a process that cannot read /proc is not bounded here.
+
+@functools.lru_cache(maxsize=None)
+def _mappings_budget() -> int:
+    try:
+        with open("/proc/sys/vm/max_map_count") as f:
+            return int(f.read()) // 2
+    except (OSError, ValueError):
+        return sys.maxsize
+
+
+def _mappings_in_use() -> int:
+    try:
+        with open("/proc/self/maps", "rb") as f:
+            return f.read().count(b"\n")
+    except OSError:
+        return 0
+
+
+def _trim_jit_cache_locked() -> None:
+    """The LRU bound, and the mappings bound after a miss (the caller holds
+    ``_JIT_CACHE_LOCK``; a miss is a compile, beside which reading
+    /proc/self/maps is nothing)."""
+    while len(_JIT_CACHE) > _JIT_CACHE_MAX:
+        _JIT_CACHE.popitem(last=False)
+    if _mappings_in_use() > _mappings_budget():
+        for _ in range(len(_JIT_CACHE) // 2):
+            _JIT_CACHE.popitem(last=False)
 
 
 class _LaunchStats:
@@ -200,8 +239,7 @@ def shared_jit(key: str, make_fn: Callable[[], Callable], *, kind: str,
     with _JIT_CACHE_LOCK:
         fn = _JIT_CACHE.setdefault(key, made)   # racer may have won; reuse
         _JIT_CACHE.move_to_end(key)
-        while len(_JIT_CACHE) > _JIT_CACHE_MAX:
-            _JIT_CACHE.popitem(last=False)
+        _trim_jit_cache_locked()
     return fn
 
 

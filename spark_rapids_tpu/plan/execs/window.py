@@ -24,9 +24,10 @@ from spark_rapids_tpu.expressions.window import (
     PercentRank, Rank, RowNumber, WindowExpression, WindowFrame)
 from spark_rapids_tpu.kernels import window as WK
 from spark_rapids_tpu.kernels.groupby import (
-    _rows_equal_prev, normalize_key_column)
+    _rows_equal_prev, _string_rows_equal_prev, normalize_key_column)
 from spark_rapids_tpu.kernels.selection import gather_batch
-from spark_rapids_tpu.kernels.sort import SortOrder, sort_indices
+from spark_rapids_tpu.kernels.sort import (
+    SortOrder, sort_indices, string_key_planes)
 from spark_rapids_tpu.memory.retry import with_retry_no_split
 from spark_rapids_tpu.plan.execs.base import TpuExec, string_key_bucket, timed
 from spark_rapids_tpu.plan.execs.coalesce import (
@@ -63,25 +64,27 @@ class _WindowDeviceSpec:
         key_idx = list(range(nbase, nbase + len(pcols) + len(ocols)))
         orders = ([SortOrder(True, True)] * len(pcols)
                   + [o for _, o in spec.order_by])
+        planes = string_key_planes(work, key_idx, string_bucket)
         idx = sort_indices(work, key_idx, orders,
-                           string_max_bytes=string_bucket)
+                           string_max_bytes=string_bucket,
+                           string_planes=planes)
+        # a string key's sorted copy is read by nobody and is compiled away
         sw = gather_batch(work, idx, work.num_rows)
         live = sw.live_mask()
         first = jnp.arange(sw.capacity, dtype=jnp.int32) == 0
 
-        from spark_rapids_tpu.kernels.groupby import _string_rows_equal_prev
-
-        def eq_prev(col):
-            if col.is_string_like:
-                return _string_rows_equal_prev(col, string_bucket)
-            return _rows_equal_prev(col)
+        def eq_prev(ci):
+            if ci in planes:
+                return _string_rows_equal_prev(work.columns[ci], *planes[ci],
+                                               idx)
+            return _rows_equal_prev(sw.columns[ci])
 
         part_eq = jnp.ones((sw.capacity,), jnp.bool_)
         for i in range(len(pcols)):
-            part_eq = part_eq & eq_prev(sw.columns[nbase + i])
+            part_eq = part_eq & eq_prev(nbase + i)
         peer_eq = part_eq
         for i in range(len(ocols)):
-            peer_eq = peer_eq & eq_prev(sw.columns[nbase + len(pcols) + i])
+            peer_eq = peer_eq & eq_prev(nbase + len(pcols) + i)
         part_boundary = live & (first | ~part_eq)
         peer_boundary = live & (first | ~peer_eq)
         layout = WK.window_layout(part_boundary, peer_boundary, live)

@@ -178,3 +178,49 @@ def test_sort_kernel_compiles_small(one_chip, tpu_branch):
     _compile(lambda b: sort_indices(b, [3], [SortOrder(True)],
                                     string_max_bytes=0),
              (batch,), one_chip)
+
+
+def _char1_keys_batch(rows):
+    """Two char(1) string keys and a value: the shape of q1's work batch."""
+    from spark_rapids_tpu import types as T
+    from spark_rapids_tpu.columnar.batch import ColumnarBatch, Schema
+    return ColumnarBatch.from_pydict(
+        {"f": ["R", "A", "N", None] * (rows // 4),
+         "s": ["O", "F"] * (rows // 2),
+         "v": [1.5] * rows},
+        Schema.of(f=T.STRING, s=T.STRING, v=T.DOUBLE), capacity=rows)
+
+
+def test_string_chunk_keys_compile_at_batch_capacity(one_chip, tpu_branch):
+    """The byte loop of the string chunk keys (a trip count from the data,
+    a uint64 shift by a traced amount under the X64 rewrite, a row of the
+    planes updated in place) has no sort: it compiles at the capacity the
+    engine dispatches, and so does the compare through an order."""
+    from spark_rapids_tpu.kernels.groupby import _string_rows_equal_prev
+    # the column's planes at BATCH_ROWS rows: validity and bytes one a row
+    # (char(1)), offsets one more
+    col = jax.tree.map(
+        lambda x: np.zeros((BATCH_ROWS + (x.shape[0] - SMALL),), x.dtype),
+        _char1_keys_batch(SMALL).columns[0])
+
+    def keys_and_runs(c, idx):
+        planes, steps = sort_kernels._string_chunk_planes(c, 16)
+        return planes, _string_rows_equal_prev(c, planes, steps, idx)
+
+    compiled = _compile(keys_and_runs,
+                        (col, np.zeros((BATCH_ROWS,), np.int32)), one_chip)
+    assert compiled.as_text().count(" while(") >= 2
+
+
+def test_group_rows_on_string_keys_compiles_small(one_chip, tpu_branch):
+    from spark_rapids_tpu.kernels import groupby as G
+    batch = _char1_keys_batch(SMALL)
+
+    def grouped(b):
+        layout = G.group_rows(b, [0, 1], string_max_bytes=16,
+                              allow_split_groups=True)
+        keys = G.group_keys_output(layout, [0, 1], out_capacity=256,
+                                   string_max_bytes=16)
+        return keys, G.seg_sum(layout.sorted_column(2), layout, jnp.float64)
+
+    _compile(grouped, (batch,), one_chip)

@@ -516,10 +516,11 @@ def _string_gather_loops(text, capacity):
 
 def test_q1_program_moves_its_rows_once(monkeypatch, tmp_path):
     """The filter under q1's grouped aggregate hands its mask to the
-    grouping sort: of the four string gathers at the batch's capacity
-    (two key columns, gathered by the filter and again by ``group_rows``)
-    two are left, and the filter's ``compaction_map`` and ``gather_batch``
-    are gone."""
+    grouping sort, and the grouping moves no key column (PR 34: the keys
+    are read from the unsorted batch through the order, at the group
+    capacity): no string gather at the batch's capacity is left, where the
+    lowering whose filter compacts keeps the filter's two, and the
+    filter's ``compaction_map`` and ``gather_batch`` are gone."""
     import re
 
     from spark_rapids_tpu.kernels.strings import MIN_BUCKET
@@ -536,8 +537,8 @@ def test_q1_program_moves_its_rows_once(monkeypatch, tmp_path):
         "fused_agg_filter_slice"
     compacting = _lowered_text(seg, batch, slice_spec, scopes=True,
                                bucket=MIN_BUCKET)
-    assert _string_gather_loops(compacting, cap) == 4
-    assert _string_gather_loops(text, cap) == 2
+    assert _string_gather_loops(compacting, cap) == 2
+    assert _string_gather_loops(text, cap) == 0
     # the keys born at the group capacity and the slice's gather: as before
     small = fused.GROUP_CAP_DEFAULT
     assert _string_gather_loops(text, small) == \
@@ -549,7 +550,8 @@ def test_q1_program_moves_its_rows_once(monkeypatch, tmp_path):
     scatters = [len(re.findall(r"stablehlo\.scatter", t))
                 for t in (text, compacting)]
     assert scatters[0] < scatters[1], scatters
-    assert "/group_rows/gather_batch" in text
+    # the aggregate's inputs are what the grouping still moves
+    assert "/gather_sorted" in text and "/group_rows/gather_batch" not in text
 
 
 def _filter_under_join(s):
@@ -916,6 +918,45 @@ def test_group_capacity_keeps_string_and_array_buffers_whole(
     got = df.collect()
     want = _buffer_query(TpuSession({"spark.rapids.sql.enabled": "false"}),
                          case).collect()
+    assert len(got) == len(want) > 3900
+    for g, w in zip(got, want):
+        assert _eq_val(tuple(g), tuple(w)), (g, w)
+
+
+@pytest.mark.parametrize("keys", [("a",), ("a", "b"), ("b", "k")],
+                         ids=lambda k: "_".join(k))
+def test_group_capacity_with_string_keys_and_string_buffers(
+        keys, fresh_program_caches):
+    """String keys read from the unsorted batch at the group starts (PR
+    34), beside min/max of a string and first/last: the partial batch at
+    the group capacity holds the oracle's groups, its keys' byte planes cut
+    to the bucket."""
+    from spark_rapids_tpu.expressions import first, last, max_, min_
+    from spark_rapids_tpu.kernels.strings import MIN_BUCKET as bucket
+    from spark_rapids_tpu.plan import fused
+    from tests.test_queries import _eq_val
+
+    def query(s):
+        df = s.create_dataframe(_buffer_batches(), num_partitions=2)
+        return df.group_by(*keys).agg(
+            min_("s").alias("lo"), max_("s").alias("hi"),
+            first("a").alias("fa"), last("b", ignore_nulls=True).alias("lb"),
+            sum_("v").alias("sv"), count().alias("n")).order_by(*keys)
+
+    s = TpuSession({"spark.rapids.sql.enabled": "true",
+                    "spark.rapids.sql.batchSizeRows": "16384"})
+    seg, xkeys, n_out = _sliced_segment(query(s).physical_plan())
+    cap = fused.GROUP_CAP_DEFAULT
+    for part in range(seg.num_partitions()):
+        for batch, _ in seg.execute_partition_sliced(part, xkeys, n_out, "t"):
+            assert batch.capacity == cap
+            assert 3000 < batch.host_num_rows() <= cap
+            for c in batch.columns[:len(keys)]:
+                assert (not c.is_string_like
+                        or c.byte_capacity == cap * bucket)
+    seg.cleanup()
+    got = query(s).collect()
+    want = query(TpuSession({"spark.rapids.sql.enabled": "false"})).collect()
     assert len(got) == len(want) > 3900
     for g, w in zip(got, want):
         assert _eq_val(tuple(g), tuple(w)), (g, w)

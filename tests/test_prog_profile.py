@@ -118,3 +118,40 @@ def test_run_suites_still_names_this_file():
     here = "tests/" + os.path.basename(__file__)
     assert here in SUITES["profile"][0]
     assert here in SUITES["observability"][0]
+
+
+def _fill_jit_cache(monkeypatch, n):
+    import collections
+
+    from spark_rapids_tpu.plan.execs import base
+    monkeypatch.setattr(base, "_JIT_CACHE", collections.OrderedDict())
+    for i in range(n):
+        base.shared_jit(f"mappings-bound-{i}", lambda: (lambda x: x + 1),
+                        kind="probe")
+    return base
+
+
+def test_the_program_cache_sheds_its_older_half_near_the_mappings_limit(
+        monkeypatch):
+    """A CPU executable is JIT code in anonymous mappings and the kernel
+    caps a process's mappings: past half of vm.max_map_count a miss drops
+    the least recently used half of the cache, the new program stays, and
+    under the budget nothing goes but by the LRU bound."""
+    base = _fill_jit_cache(monkeypatch, 8)
+    assert len(base._JIT_CACHE) == 8          # this process: far under
+    base.shared_jit("mappings-bound-0", lambda: None, kind="probe")  # a hit
+    monkeypatch.setattr(base, "_mappings_in_use",
+                        lambda: base._mappings_budget() + 1)
+    base.shared_jit("mappings-bound-new", lambda: (lambda x: x), kind="probe")
+    kept = [k.split("|")[0] for k in base._JIT_CACHE]
+    assert kept == ["mappings-bound-5", "mappings-bound-6", "mappings-bound-7",
+                    "mappings-bound-0", "mappings-bound-new"]
+
+
+def test_the_mappings_budget_is_half_of_what_the_kernel_allows():
+    from spark_rapids_tpu.plan.execs import base
+    with open("/proc/sys/vm/max_map_count") as f:
+        assert base._mappings_budget() == int(f.read()) // 2
+    with open("/proc/self/maps") as f:
+        here = len(f.readlines())
+    assert 0 < base._mappings_in_use() <= here + 64

@@ -3,7 +3,8 @@ keys on string columns (max-bytes bucket threading)."""
 import pytest
 
 from spark_rapids_tpu.api.session import TpuSession
-from spark_rapids_tpu.expressions import RowNumber, col, count, over, sum_
+from spark_rapids_tpu.expressions import (
+    DenseRank, RowNumber, col, count, max_, min_, over, sum_)
 from spark_rapids_tpu.kernels.sort import SortOrder
 from tests.test_queries import assert_tpu_cpu_equal
 from tests.test_strings import strings_df
@@ -54,3 +55,30 @@ def test_window_partition_by_string():
     assert_tpu_cpu_equal(
         lambda s: strings_df(s).with_column(
             "rn", over(RowNumber(), partition_by=["s"], order_by=["n"])))
+
+
+@pytest.mark.parametrize("ascending", [True, False], ids=["asc", "desc"])
+def test_window_ranked_by_string_within_string_partitions(ascending):
+    """Peers are runs of equal string order keys: the chunk keys that the
+    sort read, compared through the order, nulls a peer group of their own."""
+    assert_tpu_cpu_equal(
+        lambda s: strings_df(s).with_column(
+            "rk", over(DenseRank(), partition_by=["s"],
+                       order_by=[("t", SortOrder(ascending))])))
+
+
+def test_window_totals_over_string_partitions():
+    """The unbounded-frame path groups the batch by the partition keys and
+    reads its columns in sorted order (``layout.sorted_batch``)."""
+    assert_tpu_cpu_equal(
+        lambda s: strings_df(s).with_column(
+            "total", over(sum_("n"), partition_by=["s", "t"])))
+
+
+def test_merge_of_string_keyed_partials_with_string_buffers():
+    """``_merge_step`` over two string keys: keys from the unsorted partial
+    batches through the order, min/max string buffers in sorted order."""
+    assert_tpu_cpu_equal(
+        lambda s: strings_df(s, parts=3).group_by("s", "t").agg(
+            min_("t").alias("lo"), max_("s").alias("hi"),
+            sum_("n").alias("sn"), count().alias("c")))

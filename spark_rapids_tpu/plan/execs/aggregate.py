@@ -917,20 +917,10 @@ class TpuHashAggregateExec(TpuExec):
             # partials to hold, rounded up; without it the sum of their
             # capacities, which bounds them
             from spark_rapids_tpu.shuffle.transport import (
-                piece_batch_in_trace)
-            partials = tuple(piece_batch_in_trace(p) for p in partials)
-            if len(partials) == 1:
-                merged_in = partials[0]
-            else:
-                from spark_rapids_tpu.kernels.selection import (
-                    concat_batches_device)
-                cap = out_capacity or round_up_pow2(
-                    max(sum(p.capacity for p in partials), 1))
-                # tpu-lint: allow-retry-discipline(traced body of _jit_combine; its one call site runs under with_retry_no_split)
-                merged_in, _ = concat_batches_device(
-                    list(partials), cap)
-            return spec._finalize(
-                spec._merge_step(merged_in, string_bucket=string_bucket))
+                fold_pieces_in_trace)
+            return spec._finalize(spec._merge_step(
+                fold_pieces_in_trace(partials, out_capacity),
+                string_bucket=string_bucket))
 
         def _combine_bucket(partials) -> int:
             from spark_rapids_tpu.kernels import strings as SK
@@ -1003,8 +993,7 @@ class TpuHashAggregateExec(TpuExec):
         breaks it (a single oversized partition) takes the default path's
         out-of-core sub-partition merge."""
         from spark_rapids_tpu.plan.execs.coalesce import (
-            retry_over_stream_pieces)
-        from spark_rapids_tpu.plan.execs.exchange import reduce_group_in_core
+            pull_group_in_core, retry_over_stream_pieces)
         from spark_rapids_tpu.shuffle.stats import SHUFFLE_COUNTERS
         from spark_rapids_tpu.shuffle.transport import (
             views_at_one_capacity, views_over_memory_budget)
@@ -1014,27 +1003,20 @@ class TpuHashAggregateExec(TpuExec):
         first = list(itertools.islice(stream, 1))
         out = None
         with timed(self.op_time, "agg.final"):
-            # accumulate with an INCREMENTAL size check: the moment the
-            # group passes the in-core bound, stop pulling, DROP what was
-            # pulled (wire pieces hold real device batches — keeping them
-            # across the re-read would double residency on exactly the
-            # oversized path the fallback protects), and let the default
-            # path's out-of-core merge re-read the partition
-            pieces, rows = [], 0
-            for p in itertools.chain(first, stream):
-                pieces.append(p)
-                rows += p.rows
-                if not reduce_group_in_core(rows, self.target_capacity):
-                    break
+            # pulled with an INCREMENTAL size check: a group that passes
+            # the in-core bound is dropped at once and the default path's
+            # out-of-core merge re-reads the partition
+            pieces, rows = pull_group_in_core(
+                itertools.chain(first, stream), self.target_capacity)
             # range-view residency guard: one attempt pins each view's
             # FULL backing batch (deduped), which no spill can reclaim
             # mid-attempt — near the arena's byte budget the default path
             # (its reads slice views pin-balanced and release the
             # backing) must run instead of the fold
-            in_core = (reduce_group_in_core(rows, self.target_capacity)
+            in_core = (pieces is not None
                        and not views_over_memory_budget([pieces]))
             if not in_core:
-                pieces = first = p = None
+                pieces = first = None
             elif pieces:
                 n_views = sum(1 for p in pieces if p.is_range_view)
                 if n_views:

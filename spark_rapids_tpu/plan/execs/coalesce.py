@@ -205,6 +205,25 @@ def retry_over_stream_pieces(piece_lists, body):
     return with_retry_no_split(attempt)
 
 
+def pull_group_in_core(pieces, bound: int, rows: int = 0):
+    """The pieces ``pieces`` hands out (one side of one reduce group, raw
+    from ``stream_pieces``) for as long as the group stays one program's
+    work by ``exchange.reduce_group_in_core``: ``(list, rows)`` with
+    ``rows`` the rows held so far (``rows`` in: what the group's other side
+    holds).  The moment the bound is passed the pull STOPS and what was
+    pulled is DROPPED: ``(None, rows)``.  Wire pieces hold real device
+    batches, and keeping them across the merged path's re-read would double
+    residency on exactly the oversized path the fallback protects."""
+    from spark_rapids_tpu.plan.execs.exchange import reduce_group_in_core
+    out = []
+    for p in pieces:
+        out.append(p)
+        rows += p.rows
+        if not reduce_group_in_core(rows, bound):
+            return None, rows
+    return out, rows
+
+
 def coalesce_to_one(batches: List[ColumnarBatch]) -> Optional[ColumnarBatch]:
     """Concat same-schema batches into one (None for empty input)."""
     if not batches:

@@ -293,12 +293,14 @@ class TpuShuffleExchangeExec(TpuExec):
         return self.target_rows
 
     def stream_pieces(self, idx: int):
-        """Raw reduce pieces for the fused-across-shuffle path
-        (plan/fused.py): StreamPiece items (shuffle/transport.py) with NO
-        merge/concat — the fused consumer concats them INSIDE its one
-        program per coalesced partition group, pin-balanced via
+        """Raw reduce pieces: StreamPiece items (shuffle/transport.py)
+        with NO merge/concat.  THE read of a consumer that can take a
+        reduce group as one program's work — a fused segment
+        (plan/fused.py), the final aggregate, a shuffled join — which
+        folds them INSIDE its own program
+        (transport.fold_pieces_in_trace), pin-balanced via
         coalesce.retry_over_stream_pieces.  execute_partition() remains
-        the merged path for per-op consumers."""
+        the merged read for consumers that need batches."""
         transport = self._materialize()
         it = iter(transport.read_pieces(idx, target_rows=self.target_rows))
         while True:
@@ -310,8 +312,19 @@ class TpuShuffleExchangeExec(TpuExec):
             yield piece
 
     def execute_partition(self, idx: int) -> Iterator[ColumnarBatch]:
-        """Reduce side: coalesce fetched slices up to the batch target and
-        stream them (GpuShuffleCoalesceExec.scala:72's target-size goal) —
+        """Reduce side, the MERGED read: every piece a batch of its own (a
+        CACHE_ONLY range view sliced by a launch of its own), coalesced up
+        to the batch target.  Who still reads it: consumers that need
+        batches (sort, window, a broadcast build, the per-op aggregate of
+        an unfused plan's partial side), every consumer of a wire
+        transport's already merged batches, and a reduce partition that
+        outgrew ``reduce_group_in_core`` (the join's streamed probe and
+        sub-partitioned out-of-core path, the aggregate's out-of-core
+        merge).  A reduce group that is one program's work is read through
+        stream_pieces() instead.
+
+        Coalesces fetched slices up to the batch target and
+        streams them (GpuShuffleCoalesceExec.scala:72's target-size goal) —
         an oversized reduce partition arrives as several batches so the
         downstream operator's out-of-core path can engage instead of one
         unbounded concat.  Consumption is STREAMING (transport.read_iter):

@@ -163,9 +163,13 @@ def _fusable_shuffled_join(node: TpuExec) -> bool:
     against the full co-partition build, so the join type must decompose
     by probe rows (the join's own _LEFT_SPLITTABLE contract minus
     ``existence``, which the fused emitter does not lower) and the
-    condition must be empty (the conditional path is a multi-program
-    shape).  The build side's size is a RUNTIME property — an oversized
-    partition falls back to the per-op out-of-core path at execution."""
+    condition must be empty: a conditional join sizes its pair region from
+    the probe's candidate count between two launches, which ``_converge``
+    does not speculate.  It runs per-op and reads its exchanges the same
+    way a tail does: raw pieces, folded inside its probe program
+    (plan/execs/join.py ``PieceSide``).  The build side's size is a
+    RUNTIME property — an oversized partition falls back to the per-op
+    out-of-core path at execution."""
     from spark_rapids_tpu.plan.execs.join import TpuShuffledHashJoinExec
     return (isinstance(node, TpuShuffledHashJoinExec)
             and node.condition is None
@@ -960,24 +964,6 @@ def _degrade_over_budget_group(group, extra_pieces=()):
             for p in group]
 
 
-def _concat_in_trace(batches: tuple) -> ColumnarBatch:
-    """Concat a pytree tuple of pieces INSIDE the traced program (the
-    reduce-side merge fused into the compute program).  A piece is a
-    batch or a RangeView of a shared CACHE_ONLY backing batch — views
-    slice in-trace first (the map-side piece gather folded into THIS
-    program).  Capacity is the static sum of the pieces' capacities, so
-    the concat can never overflow and needs no feedback."""
-    from spark_rapids_tpu.kernels.selection import concat_batches_device
-    from spark_rapids_tpu.shuffle.transport import piece_batch_in_trace
-    batches = tuple(piece_batch_in_trace(b) for b in batches)
-    if len(batches) == 1:
-        return batches[0]
-    cap = round_up_pow2(max(sum(b.capacity for b in batches), 1))
-    # tpu-lint: allow-retry-discipline(traced body of the fused program; every call site dispatches under with_retry_no_split via _run's invoke)
-    out, _ = concat_batches_device(list(batches), cap)
-    return out
-
-
 def _make_program(chain: List[TpuExec], join_build_ix: Dict[int, int],
                   exprs: List[Expression], bucket: int,
                   caps: Dict[str, int], slice_spec=None,
@@ -1018,14 +1004,15 @@ def _make_program(chain: List[TpuExec], join_build_ix: Dict[int, int],
 
     def fn(stream, builds: tuple, consts: tuple):
         from spark_rapids_tpu.kernels.strings import max_live_string_bytes
+        from spark_rapids_tpu.shuffle.transport import fold_pieces_in_trace
         cmap = bind_trace_consts(exprs, consts)
         feedback: Dict[str, jax.Array] = {}
         part_builds = [i for i, b in enumerate(builds)
                        if isinstance(b, tuple)]
-        builds = tuple(_concat_in_trace(b) if isinstance(b, tuple) else b
-                       for b in builds)
+        builds = tuple(fold_pieces_in_trace(b) if isinstance(b, tuple)
+                       else b for b in builds)
         if isinstance(stream, tuple):
-            stream = _concat_in_trace(stream)
+            stream = fold_pieces_in_trace(stream)
         byte_obs = [jnp.asarray(max_live_string_bytes(stream.columns[i],
                                                       stream.num_rows))
                     for i in stream_string_ords]

@@ -72,6 +72,12 @@ _FIELDS = (
     "reduce_groups_out_of_core",  # of them, those that took the out-of-core
                               # sub-partition merge (one agg.out_of_core
                               # span each): past reduce_group_in_core
+    # joins (plan/execs/join.py): numbers the kernel's capacity loop has
+    # on the host anyway, no sync of their own
+    "join_candidate_pairs",   # pairs the equi-key probes found: what a
+                              # condition is evaluated over, or an
+                              # unconditional join's expansion needs
+    "join_output_rows",       # rows the joins' accepted launches handed on
     # map side (range-serialization write path; serializer.py)
     "map_range_batches",      # map batches written via range framing
     "map_range_blocks",       # partition wire blocks framed from row ranges

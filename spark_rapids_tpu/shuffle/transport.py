@@ -114,12 +114,32 @@ def views_at_one_capacity(mats: list) -> list:
             for m in mats]
 
 
-def piece_batch_in_trace(x):
-    """Resolve a stream piece materialization to a plain batch inside a
-    traced program: RangeViews slice in-trace, batches pass through.  The
-    ONE resolution point shared by the fused-segment concat and the
-    final-aggregate combine."""
-    return x.slice_in_trace() if isinstance(x, RangeView) else x
+def fold_pieces_in_trace(pieces, out_capacity: Optional[int] = None
+                         ) -> ColumnarBatch:
+    """One reduce group's pieces as ONE batch, inside the traced program
+    that consumes them: the reduce-side merge as the first step of the
+    consumer's own program (the final aggregate's combine, a fused
+    segment's stream group and co-partition build, a join's probe), so no
+    view costs a launch and no side a concat launch.  A piece is a batch or
+    a RangeView of a shared CACHE_ONLY backing batch; views slice in-trace
+    first (the map-side piece gather folded in as well).
+
+    ``out_capacity`` (static): the rows the caller knows the pieces to
+    hold, rounded up (the concat compacts live rows, so it has to cover
+    the rows alone); without it the sum of the pieces' capacities, which
+    bounds them.  Either way the concat cannot overflow and needs no
+    feedback."""
+    from spark_rapids_tpu.columnar.column import round_up_pow2
+    from spark_rapids_tpu.kernels.selection import concat_batches_device
+    batches = tuple(p.slice_in_trace() if isinstance(p, RangeView) else p
+                    for p in pieces)
+    if len(batches) == 1:
+        return batches[0]
+    cap = out_capacity or round_up_pow2(
+        max(sum(b.capacity for b in batches), 1))
+    # tpu-lint: allow-retry-discipline(traced body of the consumer's program; every call site dispatches it under with_retry_no_split or retry_over_stream_pieces)
+    out, _ = concat_batches_device(list(batches), cap)
+    return out
 
 
 class StreamPiece:

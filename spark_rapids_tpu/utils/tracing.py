@@ -219,6 +219,25 @@ _STATIC_RANGES = (
     ("batch.shrink", "maybe_shrink of a batch over the floor capacity: "
                      "the host sync on its row count and, where it is "
                      "sparse, the regather dispatch"),
+    # joins (plan/execs/join.py)
+    ("join.build", "a join's own work to have one reduce group's (or the "
+                   "broadcast) build side ready: pull its pieces from the "
+                   "exchange's read side, or coalesce its batches"),
+    ("join.probe", "one probe group against its build: the pull of its "
+                   "pieces, the probe launch (which folds both sides' "
+                   "pieces) and the condition or expansion launches with "
+                   "their host syncs, and the output's shrink"),
+    ("join.retry", "one launch of a join's expansion or condition program "
+                   "that was run again at a larger capacity: from its "
+                   "dispatch to the status that condemned it (written "
+                   "then: in the span log and the query trace, not the "
+                   "profiler's)"),
+    ("join.decide", "an adaptive join's own work once its build side is "
+                    "pulled: the count of its rows (a host sync) and the "
+                    "building of the inner plan"),
+    ("join.out_of_core", "one per join partition past the in-core bound: "
+                         "both sides sub-partitioned into spillable "
+                         "co-buckets"),
     # exchange + range sort (plan/execs/exchange.py, range_sort.py)
     ("exchange.write", "the exchange's own map-side work for one map "
                        "batch: slice dispatch, counts sync or download, "

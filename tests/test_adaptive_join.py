@@ -49,43 +49,31 @@ def _build(sess, n_right, filtered=True):
     return left.join(r, on=([col("k")], [col("rk")]), how="inner")
 
 
-def test_static_estimate_wrong_runtime_broadcasts():
-    """Estimate says 'too big to broadcast' (ambiguous zone); the actual
-    build side is tiny after a selective filter -> runtime broadcasts."""
-    sess = TpuSession({"spark.rapids.sql.enabled": "true",
-                       "spark.rapids.sql.join.broadcastRowThreshold": "64"})
-    left = _df(sess, 400, seed=1)
-    right = _df(sess, 300, seed=2, parts=1)      # estimate 300//2=150 > 64
-    r = (right.select(col("k").alias("rk"), col("v").alias("rv"))
-         .filter(col("rv") < lit(20_000)))       # actually keeps ~2% -> ~6
-    df = left.join(r, on=([col("k")], [col("rk")]), how="inner")
-    plan, _ = plan_query(df.plan, sess.conf)
-    ad = _adaptive_of(plan)
-    assert ad is not None, plan.tree_string()
-    rows = df.collect()
-    plan2, _ = plan_query(df.plan, sess.conf)
-    ad2 = _adaptive_of(plan2)
-    ad2.num_partitions()   # forces the decision
-    assert ad2.chosen == "broadcast", ad2.describe()
-    ad2.cleanup()
-
-
-def test_static_estimate_wrong_runtime_shuffles():
-    """Estimate says 'small enough' is impossible here: estimate is 150
-    (ambiguous), actual is 300 (> threshold) -> runtime shuffles."""
+@pytest.mark.parametrize("keep,chosen", [
+    # a selective filter keeps ~2% (~6 rows): the estimate was 8x too big
+    (lambda rv: rv < lit(20_000), "broadcast"),
+    # a pass-through filter keeps all 300: the estimate was 2x too small
+    (lambda rv: rv >= lit(0), "shuffled")],
+    ids=["runtime_broadcasts", "runtime_shuffles"])
+def test_static_estimate_wrong_runtime_decides(keep, chosen):
+    """The estimate (300 // 2 = 150 > 64) lands in the ambiguous zone; the
+    materialized build side's actual row count picks the strategy, and the
+    rows are the oracle's either way.  (The same choice on a conditional
+    semi- and anti-join over an exchange: tests/test_q21_lineitem.py.)"""
     sess = TpuSession({"spark.rapids.sql.enabled": "true",
                        "spark.rapids.sql.join.broadcastRowThreshold": "64"})
     left = _df(sess, 400, seed=1)
     right = _df(sess, 300, seed=2, parts=1)
-    r = (right.select(col("k").alias("rk"), col("v").alias("rv"))
-         .filter(col("rv") >= lit(0)))           # keeps everything: 300
-    df = left.join(r, on=([col("k")], [col("rk")]), how="inner")
+    r = right.select(col("k").alias("rk"), col("v").alias("rv"))
+    df = left.join(r.filter(keep(col("rv"))),
+                   on=([col("k")], [col("rk")]), how="inner")
     plan, _ = plan_query(df.plan, sess.conf)
     ad = _adaptive_of(plan)
     assert ad is not None, plan.tree_string()
-    ad.num_partitions()
-    assert ad.chosen == "shuffled", ad.describe()
+    ad.num_partitions()    # forces the decision
+    assert ad.chosen == chosen, ad.describe()
     ad.cleanup()
+    df.collect()
 
 
 @pytest.mark.parametrize("n_right", [40, 2000])

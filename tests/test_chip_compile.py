@@ -224,3 +224,31 @@ def test_group_rows_on_string_keys_compiles_small(one_chip, tpu_branch):
         return keys, G.seg_sum(layout.sorted_column(2), layout, jnp.float64)
 
     _compile(grouped, (batch,), one_chip)
+
+
+def test_join_probe_folding_its_pieces_compiles_small(one_chip, tpu_branch,
+                                                      monkeypatch):
+    """A shuffled join's first program with both sides as raw exchange
+    pieces (six range views a side, as one reduce group of the SF1 Q21 cell
+    has; here over 4,096-row backings): the in-trace slices, the two
+    concats and the probe's sorts as ONE program."""
+    from spark_rapids_tpu import types as T
+    from spark_rapids_tpu.columnar.batch import ColumnarBatch, Schema
+    from spark_rapids_tpu.plan.execs import base
+    from spark_rapids_tpu.plan.execs.join import _JoinKernel
+    from spark_rapids_tpu.shuffle.transport import RangeView
+    made = []
+    shared_jit = base.shared_jit
+    monkeypatch.setattr(
+        base, "shared_jit",
+        lambda key, make, **kw: shared_jit(
+            key, lambda: made.append(make()) or made[-1], **kw))
+    kv = Schema.of(k=T.LONG, v=T.LONG)
+    kernel = _JoinKernel([0], [0], "left_semi", kv, left_schema=kv,
+                         right_schema=kv)
+    kernel._jitted_probe(0, "left_semi", (SMALL // 2, SMALL))
+    backing = ColumnarBatch.from_pydict(
+        {"k": list(range(SMALL)), "v": [1] * SMALL}, kv, capacity=SMALL)
+    views = tuple(RangeView(backing, jnp.int32(i * 512), jnp.int32(300),
+                            SMALL // 4) for i in range(6))
+    _compile(made[-1], (views, views), one_chip)

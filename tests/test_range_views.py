@@ -159,15 +159,22 @@ def test_deleted_options_and_switches_are_gone():
         assert not hasattr(transport, name), name
 
 
-def test_materialize_fallback_for_per_op_consumers():
+@pytest.mark.parametrize("batch_rows,folds", [(None, True), ("256", False)])
+def test_per_op_join_folds_its_views_and_falls_back_past_the_bound(
+        batch_rows, folds):
     """With fusion off a shuffled join is a per-op consumer of its
-    exchanges: their views slice through the standalone-gather fallback
-    (counted) and rows still match the fused path."""
+    exchanges.  A reduce group that is one program's work arrives as raw
+    pieces and the probe program folds its views (no standalone gather);
+    a partition past the in-core bound (a small batchSizeRows) takes the
+    merged read, whose views slice through the standalone-gather fallback
+    (counted).  Rows match the fused path either way."""
     from spark_rapids_tpu.cluster.stats import (
         local_shuffle_counters, reset_local_shuffle_counters)
     conf = dict(CONF, **{
         "spark.rapids.sql.join.broadcastRowThreshold": "1",
         "spark.rapids.sql.join.adaptive.enabled": "false"})
+    if batch_rows:
+        conf["spark.rapids.sql.batchSizeRows"] = batch_rows
 
     def query(s):
         fact = s.create_dataframe(
@@ -189,8 +196,12 @@ def test_materialize_fallback_for_per_op_consumers():
     sc = local_shuffle_counters()
     assert rows_fused and _norm(rows_fused) == _norm(rows_perop)
     assert sc["range_view_blocks"] > 0, sc
-    assert sc["range_view_materializes"] > 0, sc
     assert sc["slice_gather_programs"] == 0, sc
+    if folds:
+        assert sc["range_view_folds"] > 0, sc
+        assert sc["range_view_materializes"] == 0, sc
+    else:
+        assert sc["range_view_materializes"] > 0, sc
 
 
 # -- transport-level spill/teardown correctness ------------------------------

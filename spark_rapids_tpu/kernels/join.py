@@ -9,12 +9,18 @@ assembly reuses the filter/sort machinery.
 
 Strategy — sort-merge under the hood (the inverse of the reference, which
 plans sort-merge joins AS hash joins, GpuSortMergeJoinMeta.scala): both
-sides' keys are concatenated, lex-sorted once (XLA variadic sort — the
+sides' keys are concatenated and sorted once (XLA variadic sort — the
 shape-static operation TPUs like), segment boundaries delimit equal-key
-runs, and per-row match counts + first-match positions fall out of segment
-reductions.  Expansion to pairs is an offsets + searchsorted gather with a
-static output capacity and an OverflowStatus for the capacity-retry loop
-(the GpuSplitAndRetryOOM analog pointed at output growth).
+runs, and per-row match counts + first-match positions fall out of running
+minima (one fixed-width key) or segment reductions (the general path).
+Expansion to pairs is offsets + a slot-to-row map (_slot_rows: one scatter
+of the rows' starts and a running maximum) with a static output capacity
+and an OverflowStatus for the capacity-retry loop (the GpuSplitAndRetryOOM
+analog pointed at output growth).  No program of a join holds a binary
+search: a search is a loop of dependent gathers over the whole array, the
+access pattern this chip is worst at, and every position map here is the
+same map built from sorts, scans and one scatter (tests/test_join_maps.py
+holds the programs to it).
 
 Spark join semantics honored:
   * null keys never match (no null == null in equi-joins);
@@ -154,10 +160,12 @@ def join_path(left: ColumnarBatch, left_keys: Sequence[int],
 def _probe_single(left: ColumnarBatch, lk: int, right: ColumnarBatch,
                   rk: int, join_type: str) -> Tuple[Tuple[jax.Array, ...],
                                                     jax.Array]:
-    """Capacity-independent half of the single fixed-width-key join:
-    sorted-build + binary-search probe (O((L+R) log R), no combined
-    lexsort).  Null keys never match; normalize_key_column canonicalizes
-    NaN/-0.0 so uint64 order-key equality == Spark equality.
+    """Capacity-independent half of the single fixed-width-key join: one
+    stable sort of both sides' rows by (eligibility, key, side), running
+    minima over the sorted order, and one sort back to the probe rows' own
+    order; no search, no gather, no scatter (O((L+R) log^2 (L+R)) compares,
+    all streaming).  Null keys never match; normalize_key_column
+    canonicalizes NaN/-0.0 so uint64 order-key equality == Spark equality.
 
     Returns (state, required_rows).  state shapes depend only on the
     input capacities, so capacity retries reuse it (the
@@ -165,34 +173,45 @@ def _probe_single(left: ColumnarBatch, lk: int, right: ColumnarBatch,
     BaseHashJoinIterator, GpuHashJoin.scala:1136).
     """
     CL, CR = left.capacity, right.capacity
+    TC = CL + CR
     left_live = left.live_mask()
     right_live = right.live_mask()
     lc = normalize_key_column(left.columns[lk])
     rc = normalize_key_column(right.columns[rk])
-    lkey = _data_key_fixed(lc, _ASC)
-    rkey = _data_key_fixed(rc, _ASC)
     lvalid = lc.validity & left_live
     rvalid = rc.validity & right_live
 
-    # Sort build rows by (validity DESC, key ASC) — a value sentinel would
-    # collide with a legitimate Long.MAX_VALUE key.  The invalid tail is
-    # then OVERWRITTEN with the max sentinel so the full array stays
-    # monotonic for searchsorted; probes equal to the sentinel still
-    # resolve correctly because hi is clamped to the valid prefix.
-    MAXK = jnp.uint64(0xFFFFFFFFFFFFFFFF)
-    invalid_rank = (~rvalid).astype(jnp.uint8)
-    perm = jnp.lexsort((rkey, invalid_rank)).astype(jnp.int32)
-    n_build = jnp.sum(rvalid.astype(jnp.int32))
-    pos_b = jnp.arange(CR, dtype=jnp.int32)
-    sorted_keys = jnp.where(pos_b < n_build, rkey[perm], MAXK)
-
-    lo = jnp.searchsorted(sorted_keys, lkey, side="left").astype(jnp.int32)
-    hi = jnp.searchsorted(sorted_keys, lkey, side="right").astype(jnp.int32)
-    # a probe key equal to MAXK's sentinel can only "match" build nulls;
-    # clamp the range to the valid-build prefix
-    lo = jnp.minimum(lo, n_build)
-    hi = jnp.minimum(hi, n_build)
-    matches = jnp.where(lvalid, hi - lo, 0)
+    # Eligibility is a sort key of its own, most significant (null and dead
+    # rows last): a value sentinel would collide with a legitimate
+    # Long.MAX_VALUE key.  Within a run of equal keys the probe rows come
+    # first and the build rows follow in their own order (the sort is
+    # stable), so a probe row's matches are the build rows from the next
+    # build row on to the end of its run.
+    s_inel, s_key, s_side, s_orig = jax.lax.sort(
+        (jnp.concatenate([~lvalid, ~rvalid]).astype(jnp.uint8),
+         jnp.concatenate([_data_key_fixed(lc, _ASC),
+                          _data_key_fixed(rc, _ASC)]),
+         jnp.concatenate([jnp.zeros((CL,), jnp.uint8),
+                          jnp.ones((CR,), jnp.uint8)]),
+         jnp.concatenate([jnp.arange(CL, dtype=jnp.int32),
+                          jnp.arange(CR, dtype=jnp.int32)])),
+        num_keys=3, is_stable=True)
+    pos = jnp.arange(TC, dtype=jnp.int32)
+    is_probe = s_side == 0
+    s_elig = s_inel == 0
+    ends_run = jnp.concatenate([
+        (s_key[1:] != s_key[:-1]) | (s_inel[1:] != s_inel[:-1]),
+        jnp.ones((1,), jnp.bool_)])
+    run_end = jax.lax.cummin(jnp.where(ends_run, pos + 1, TC), reverse=True)
+    s_first = jax.lax.cummin(
+        jnp.where(s_elig & ~is_probe, pos, TC), reverse=True)
+    s_matches = jnp.where(s_elig & is_probe & (s_first < run_end),
+                          run_end - s_first, 0)
+    # back to the probe rows' own order: they sort first, by row
+    _, matches, first = jax.lax.sort(
+        (jnp.where(is_probe, s_orig, CL + s_orig), s_matches, s_first),
+        num_keys=1, is_stable=False)
+    matches, first = matches[:CL], first[:CL]
 
     if join_type in ("left_semi", "left_anti"):
         mask = left_live & ((matches > 0) if join_type == "left_semi"
@@ -206,7 +225,45 @@ def _probe_single(left: ColumnarBatch, lk: int, right: ColumnarBatch,
                            else matches, 0).astype(jnp.int64)
     offsets = jnp.concatenate([jnp.zeros((1,), jnp.int64),
                                jnp.cumsum(out_counts)])
-    return (offsets, matches, lo, perm), offsets[CL]
+    return (offsets, matches, first, s_orig), offsets[CL]
+
+
+def _slot_rows(offsets: jax.Array, out_capacity: int,
+               base: Optional[jax.Array] = None
+               ) -> Tuple[jax.Array, jax.Array]:
+    """Which row owns each output slot, and how far into the row's run the
+    slot lies: the position map of every expansion, built without a search.
+
+    ``offsets`` (int64[n + 1], non-decreasing, offsets[0] == 0) are the
+    rows' start slots, counted from ``base`` (a device scalar; 0 if None).
+    Returns int32 (row, within)[out_capacity]: for every live slot
+    ``base <= k < base + offsets[n]``, row[k] is the largest i < n with
+    ``base + offsets[i] <= k`` and within[k] = k - (base + offsets[row[k]]).
+    Dead slots hold anything; callers mask them by ``k < total``.
+
+    Each row's index is scattered to its start slot and a running maximum
+    carries it over the row's run.  Rows with no output share their start
+    with the next row, so the scatter combines by ``max``: the last of them
+    wins.  Starts at or past ``out_capacity`` are dropped.  A slot is a
+    start iff it is marked (row 0 marks its slot with 0, so it is named),
+    and the running maximum of the starts' own slots is each run's start:
+    no gather of ``offsets``.  Slots are int32: out_capacity < 2**31.
+    """
+    assert out_capacity < 2 ** 31, out_capacity
+    n = offsets.shape[0] - 1
+    starts = offsets[:n] if base is None else offsets[:n] + base
+    start = jnp.minimum(starts, out_capacity).astype(jnp.int32)
+    marks = jnp.zeros((out_capacity,), jnp.int32).at[start].max(
+        jnp.arange(n, dtype=jnp.int32), mode="drop", indices_are_sorted=True)
+    k = jnp.arange(out_capacity, dtype=jnp.int32)
+    is_start = (marks > 0) | (k == start[0])
+    return jax.lax.cummax(marks), k - jax.lax.cummax(jnp.where(is_start, k, 0))
+
+
+def _live_slots(total: jax.Array, out_capacity: int) -> jax.Array:
+    """bool[out_capacity]: slot k < total (int64), compared as int32."""
+    return (jnp.arange(out_capacity, dtype=jnp.int32)
+            < jnp.minimum(total, out_capacity).astype(jnp.int32))
 
 
 def _expand_left_only_mask(mask: jax.Array,
@@ -232,17 +289,16 @@ def _expand_single(state: Tuple[jax.Array, ...], join_type: str,
         (mask,) = state
         return _expand_left_only_mask(mask, out_capacity)
 
-    offsets, matches, lo, perm = state
+    # first: a probe row's first build row, as a position in the combined
+    # sorted order; s_orig: the row each position holds
+    offsets, matches, first, s_orig = state
     total = offsets[CL]
-    k = jnp.arange(out_capacity, dtype=jnp.int64)
-    row = jnp.clip(jnp.searchsorted(offsets, k, side="right") - 1,
-                   0, CL - 1).astype(jnp.int32)
-    within = (k - offsets[row]).astype(jnp.int32)
+    row, within = _slot_rows(offsets, out_capacity)
     has_match = matches[row] > 0
-    bpos = jnp.clip(lo[row] + within, 0, CR - 1)
-    livek = k < total
-    li = jnp.where(livek, row, OOB).astype(jnp.int32)
-    ri = jnp.where(livek & has_match, perm[bpos], OOB).astype(jnp.int32)
+    bpos = jnp.clip(first[row] + within, 0, CL + CR - 1)
+    livek = _live_slots(total, out_capacity)
+    li = jnp.where(livek, row, OOB)
+    ri = jnp.where(livek & has_match, s_orig[bpos], OOB)
     return li, ri, jnp.minimum(total, out_capacity).astype(jnp.int32), \
         OverflowStatus(total)
 
@@ -262,12 +318,10 @@ def _expand_cross(state: Tuple[jax.Array, ...], CL: int,
                                               jax.Array, OverflowStatus]:
     (offsets,) = state
     total = offsets[CL]
-    k = jnp.arange(out_capacity, dtype=jnp.int64)
-    row = jnp.clip(jnp.searchsorted(offsets, k, side="right") - 1, 0, CL - 1)
-    j = k - offsets[row]
-    livek = k < total
-    li = jnp.where(livek, row, OOB).astype(jnp.int32)
-    ri = jnp.where(livek, j, OOB).astype(jnp.int32)
+    row, j = _slot_rows(offsets, out_capacity)
+    livek = _live_slots(total, out_capacity)
+    li = jnp.where(livek, row, OOB)
+    ri = jnp.where(livek, j, OOB)
     return li, ri, jnp.minimum(total, out_capacity).astype(jnp.int32), \
         OverflowStatus(total)
 
@@ -429,26 +483,22 @@ def _expand_multi(state: Tuple[jax.Array, ...], join_type: str,
                              if join_type in ("right", "full")
                              else jnp.int64(0))
 
-    k = jnp.arange(out_capacity, dtype=jnp.int64)
-    in_left_region = k < total_left
+    in_left_region = _live_slots(total_left, out_capacity)
     # left-driven region
-    lrow = jnp.clip(jnp.searchsorted(offsets, k, side="right") - 1, 0, CL - 1)
-    j = (k - offsets[lrow]).astype(jnp.int32)
+    lrow, j = _slot_rows(offsets, out_capacity)
     has_match = j < M[lrow]
     rpos = jnp.clip(FIRSTR[lrow] + j, 0, TC - 1)
     r_of_pair = jnp.where(has_match, s_orig[rpos], OOB)
     if join_type in ("left_semi", "left_anti"):
         r_of_pair = jnp.full((out_capacity,), OOB, dtype=jnp.int32)
-    li = jnp.where(in_left_region, lrow.astype(jnp.int32), OOB)
+    li = jnp.where(in_left_region, lrow, OOB)
     ri = jnp.where(in_left_region, r_of_pair, OOB)
 
     if join_type in ("right", "full"):
-        ka = k - total_left
-        in_append = (~in_left_region) & (k < required)
-        arow = jnp.clip(jnp.searchsorted(a_offsets, ka, side="right") - 1,
-                        0, CR - 1)
+        in_append = (~in_left_region) & _live_slots(required, out_capacity)
+        arow, _ = _slot_rows(a_offsets, out_capacity, base=total_left)
         li = jnp.where(in_append, OOB, li)
-        ri = jnp.where(in_append, arow.astype(jnp.int32), ri)
+        ri = jnp.where(in_append, arow, ri)
 
     count = jnp.minimum(required, out_capacity).astype(jnp.int32)
     return li, ri, count, OverflowStatus(required)

@@ -788,6 +788,10 @@ class DataFrame:
         names its parent.  With no sink on no trace is made."""
         conf = self.session.conf
         own = None
+        if tracing.span_log.enabled:
+            # host.lock_wait and the late ticks: the one sampler thread,
+            # which ends by itself once the log is off again
+            tracing.sampler.ensure_running()
         if obs.current_query_trace() is None and (
                 tracing.span_log.enabled or conf.trace_enabled):
             own = obs.QueryTrace(f"collect-{next(_COLLECT_IDS)}",
@@ -836,10 +840,10 @@ class DataFrame:
                     pass   # mode switch: fall back to the task engine
             engine = TpuEngine(self.session.conf)
             if self.session.conf.profile_enabled:
-                # per-query flamegraph + bubble report (asyncProfiler /
-                # GpuBubbleTimerManager analogs, utils/profiler.py).
-                # Diagnostics must never fail the query: artifact I/O
-                # errors are swallowed (unwritable dir, full disk).
+                # per-query flamegraph + late ticks (asyncProfiler analog,
+                # utils/profiler.py).  Diagnostics must never fail the
+                # query: artifact I/O errors are swallowed (unwritable
+                # dir, full disk).
                 from spark_rapids_tpu.utils.profiler import QueryProfiler
                 qp = None
                 try:
@@ -852,7 +856,7 @@ class DataFrame:
                 finally:
                     if qp is not None:
                         try:
-                            qp.finish(engine.last_metrics)
+                            qp.finish()
                         except Exception:  # noqa: BLE001 — diagnostics
                             qp.__exit__()  # must never fail the query
             else:

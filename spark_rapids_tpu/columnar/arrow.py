@@ -10,14 +10,14 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import (ColumnarBatch, Schema,
                                               host_scalar)
-from spark_rapids_tpu.columnar.column import DeviceColumn, round_up_pow2
+from spark_rapids_tpu.columnar.column import (DeviceColumn, put_plane,
+                                              round_up_pow2)
 
 _ARROW_TO_SQL = {
     pa.bool_(): T.BOOLEAN,
@@ -177,10 +177,10 @@ def arrow_column_to_device(arr: pa.Array, dtype: T.DataType,
         validity_full = np.zeros((cap,), dtype=np.bool_)
         validity_full[:n] = validity
         return DeviceColumn(
-            data=jnp.asarray(datab),
-            validity=jnp.asarray(validity_full),
+            data=put_plane(datab),
+            validity=put_plane(validity_full),
             dtype=dtype,
-            offsets=jnp.asarray(offsets),
+            offsets=put_plane(offsets),
         )
     if isinstance(dtype, T.TimestampType):
         arr = arr.cast(pa.timestamp("us"))

@@ -28,6 +28,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from spark_rapids_tpu import types as T
+from spark_rapids_tpu.utils.tracing import trace_range
+
+
+def put_plane(plane: np.ndarray) -> jax.Array:
+    """One host plane handed to the runtime.  The ``upload.put`` span is
+    what the call costs the calling thread; the transfer completes later."""
+    with trace_range("upload.put"):
+        return jnp.asarray(plane)
 
 
 def round_up_pow2(n: int) -> int:
@@ -224,7 +232,7 @@ class DeviceColumn:
         v = np.where(validity, v, np.zeros_like(v))
         data[:n] = v
         valid[:n] = validity
-        return DeviceColumn(data=jnp.asarray(data), validity=jnp.asarray(valid), dtype=dtype)
+        return DeviceColumn(data=put_plane(data), validity=put_plane(valid), dtype=dtype)
 
     @staticmethod
     def from_strings(

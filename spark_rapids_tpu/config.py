@@ -166,14 +166,15 @@ SHUFFLE_PARTITIONS = conf("spark.sql.shuffle.partitions").doc(
 
 PROFILE_ENABLED = conf("spark.rapids.profile.enabled").doc(
     "Per-query profiling: a sampled flamegraph (collapsed stacks, "
-    "flamegraph.pl/speedscope format) plus a bubble/idle report derived "
-    "from per-exec opTime vs wall time (reference: asyncProfiler.scala "
-    "per-stage flamegraphs + GpuBubbleTimerManager)."
+    "flamegraph.pl/speedscope format) plus the sampler's ticks that got "
+    "the interpreter lock more than 20 ms late, each with the frames of "
+    "every other thread (reference: asyncProfiler.scala per-stage "
+    "flamegraphs)."
 ).boolean_conf(False)
 
 PROFILE_DIR = conf("spark.rapids.profile.dir").doc(
     "Directory for profiling artifacts (query<N>_flame.txt / "
-    "query<N>_bubble.json)."
+    "query<N>_late_ticks.json)."
 ).string_conf("tpu_profile")
 
 AQE_COALESCE_PARTITIONS = conf(

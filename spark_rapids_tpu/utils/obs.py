@@ -2,7 +2,7 @@
 
 The repo grew every observability primitive in isolation — per-exec
 ``MetricSet`` (plan/execs/base.py), ``SpanLog``/``trace_range``
-(utils/tracing.py), ``QueryProfiler`` bubble reports, process-global
+(utils/tracing.py), ``QueryProfiler`` flamegraphs, process-global
 ``ShuffleCounters`` (shuffle/stats.py) and per-program launch
 attribution — but none of them were correlated per QUERY or across
 processes: two concurrent serving queries interleave one global counter

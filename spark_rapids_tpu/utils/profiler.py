@@ -1,128 +1,48 @@
-"""Per-query profiling depth: sampled flamegraphs + bubble (idle) timing.
+"""Per-query profiling depth: a sampled flamegraph and the late ticks.
 
-Reference analogs:
-  * per-stage flame graphs — asyncProfiler.scala:58 embeds async-profiler
-    and emits one flamegraph per stage epoch
-    (docs/additional-functionality/per-stage-flamegraph.md);
-  * bubble/idle accounting — metrics/GpuBubbleTimerManager.scala measures
-    time the GPU sits idle while tasks hold it.
+Reference analog: per-stage flame graphs — asyncProfiler.scala:58 embeds
+async-profiler and emits one flamegraph per stage epoch
+(docs/additional-functionality/per-stage-flamegraph.md).
 
-TPU lowering: a pure-python stack SAMPLER (sys._current_frames at a fixed
-cadence, aggregated into collapsed-stack lines that flamegraph.pl /
-speedscope ingest directly) plus a BUBBLE report derived from the metric
-tree — device-busy time is the sum of per-exec op_time (each exec times
-its jitted calls), so ``bubble = wall - busy`` is the time the chip sat
-idle waiting on host work (decode, planning, python).  Both are
-query-scoped and conf-gated:
+TPU lowering: the process's one sampler thread (``tracing.StackSampler``:
+``sys._current_frames`` at a fixed cadence) aggregates every thread's stack
+into collapsed-stack lines that flamegraph.pl / speedscope ingest directly,
+and keeps the ticks that got the interpreter lock more than 20 ms late with
+the frames of the thread that held it.  Query-scoped and conf-gated:
 
-    spark.rapids.profile.enabled     -> sampler + bubble per collect()
-    spark.rapids.profile.dir         -> where artifacts land
+    spark.rapids.profile.enabled     -> both artifacts per collect()
+    spark.rapids.profile.dir         -> where they land
+
+How long the device sat idle is not guessed from the host's clock here: the
+benchmark's ``device_idle_pct`` reads it from the profiler's trace.
 """
 from __future__ import annotations
 
-import collections
+import json
 import os
-import sys
+import re
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict
 
-
-class StackSampler:
-    """Sampled wall-clock profiler over all python threads.
-
-    Produces collapsed stacks ("frame;frame;frame count" lines) — the
-    interchange format of the flamegraph toolchain — so no external
-    profiler dependency is needed (async-profiler's role, embedded).
-
-    Samples EVERY thread in the process (the async-profiler default):
-    with two profiled queries running concurrently, each flamegraph
-    contains the union of both queries' threads — per-query thread
-    scoping is a follow-on (tag engine task threads per collect)."""
-
-    def __init__(self, interval_s: float = 0.01):
-        self.interval_s = interval_s
-        self._counts: collections.Counter = collections.Counter()
-        self._stop = threading.Event()
-        self._thread: Optional[threading.Thread] = None
-        self.samples = 0
-
-    def _collapse(self, frame) -> str:
-        parts: List[str] = []
-        while frame is not None:
-            code = frame.f_code
-            parts.append(f"{os.path.basename(code.co_filename)}:"
-                         f"{code.co_name}")
-            frame = frame.f_back
-        return ";".join(reversed(parts))
-
-    def _run(self, own_ident: int) -> None:
-        while not self._stop.wait(self.interval_s):
-            for ident, frame in sys._current_frames().items():
-                if ident == own_ident:
-                    continue
-                self._counts[self._collapse(frame)] += 1
-            self.samples += 1
-
-    def start(self) -> None:
-        self._thread = threading.Thread(
-            target=lambda: self._run(self._thread.ident), daemon=True,
-            name="tpu-stack-sampler")
-        self._thread.start()
-
-    def stop(self) -> None:
-        self._stop.set()
-        if self._thread is not None:
-            self._thread.join(timeout=2.0)
-
-    def collapsed_stacks(self) -> List[str]:
-        return [f"{stack} {n}" for stack, n in self._counts.most_common()]
-
-    def write(self, path: str) -> None:
-        with open(path, "w") as f:
-            f.write("\n".join(self.collapsed_stacks()) + "\n")
-
-
-def bubble_report(metrics_tree, wall_ns: int) -> Dict[str, object]:
-    """Device-bubble accounting from the per-exec metric snapshot list
-    [(describe, depth, {metric: value}), ...] the engine produces.
-
-    busy = sum of root-visible opTime (each exec times only its OWN
-    device work, so the flat sum approximates chip-busy time; overlap
-    between concurrent task threads makes it an overestimate, which makes
-    the bubble estimate conservative — same caveat the reference
-    documents for its bubble timer)."""
-    busy_ns = 0
-    per_op: List[Tuple[str, int]] = []
-    for describe, _depth, snap in metrics_tree or ():
-        t = int(snap.get("opTime", 0))
-        busy_ns += t
-        if t:
-            per_op.append((describe, t))
-    per_op.sort(key=lambda kv: -kv[1])
-    bubble_ns = max(wall_ns - busy_ns, 0)
-    return {
-        "wall_ms": wall_ns / 1e6,
-        "device_busy_ms": busy_ns / 1e6,
-        "bubble_ms": bubble_ns / 1e6,
-        "bubble_fraction": (bubble_ns / wall_ns) if wall_ns else 0.0,
-        "top_ops": [(d, t / 1e6) for d, t in per_op[:10]],
-    }
+from spark_rapids_tpu.utils import tracing
 
 
 class QueryProfiler:
-    """Conf-gated per-collect() profiler: flamegraph + bubble JSON.
+    """Conf-gated per-collect() profiler.
 
     Artifacts: <dir>/query<N>_flame.txt (collapsed stacks) and
-    <dir>/query<N>_bubble.json."""
+    <dir>/query<N>_late_ticks.json (the sampler's ticks over 20 ms late
+    that fell inside this collect(), each with every other thread's
+    innermost frames)."""
 
     _seq = 0
     _lock = threading.Lock()
 
-    def __init__(self, out_dir: str, interval_s: float = 0.01):
+    def __init__(self, out_dir: str):
         self.out_dir = out_dir
-        self.sampler = StackSampler(interval_s)
-        self._t0 = 0
+        self.profile = None
+        self._t0 = 0.0
 
     def __enter__(self) -> "QueryProfiler":
         os.makedirs(self.out_dir, exist_ok=True)
@@ -130,34 +50,34 @@ class QueryProfiler:
             if QueryProfiler._seq == 0:
                 # resume numbering past artifacts from earlier processes
                 # sharing this dir (a fresh process would clobber query1_*)
-                import re
                 mx = 0
                 for n in os.listdir(self.out_dir):
                     m = re.match(r"query(\d+)_", n)
                     if m:
                         mx = max(mx, int(m.group(1)))
                 QueryProfiler._seq = mx
-        self._t0 = time.monotonic_ns()
-        self.sampler.start()
+        self._t0 = time.perf_counter()
+        self.profile = tracing.sampler.hold()
         return self
 
-    def finish(self, metrics_tree) -> Dict[str, object]:
-        wall_ns = time.monotonic_ns() - self._t0
-        self.sampler.stop()
+    def finish(self) -> Dict[str, object]:
+        tracing.sampler.release(self.profile)
         with QueryProfiler._lock:
             QueryProfiler._seq += 1
             n = QueryProfiler._seq
         flame = os.path.join(self.out_dir, f"query{n}_flame.txt")
-        self.sampler.write(flame)
-        report = bubble_report(metrics_tree, wall_ns)
-        report["flamegraph"] = flame
-        report["samples"] = self.sampler.samples
-        import json
-        bpath = os.path.join(self.out_dir, f"query{n}_bubble.json")
-        with open(bpath, "w") as f:
-            json.dump(report, f, indent=1)
-        report["report"] = bpath
-        return report
+        self.profile.write(flame)
+        late = [{"due": due, "ran": ran, "late_ms": 1e3 * (ran - due),
+                 "threads": threads}
+                for due, ran, threads in tracing.late_ticks()
+                if ran >= self._t0]
+        lpath = os.path.join(self.out_dir, f"query{n}_late_ticks.json")
+        with open(lpath, "w") as f:
+            json.dump({"wall_ms": 1e3 * (time.perf_counter() - self._t0),
+                       "samples": self.profile.samples,
+                       "late_ticks": late}, f, indent=1)
+        return {"flamegraph": flame, "samples": self.profile.samples,
+                "late_ticks": lpath}
 
     def __exit__(self, *exc) -> None:
-        self.sampler.stop()    # idempotent
+        tracing.sampler.release(self.profile)   # idempotent

@@ -14,6 +14,8 @@ Registered names + docs are dumped by tools/generate_docs.py.
 from __future__ import annotations
 
 import collections
+import os
+import sys
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
@@ -63,6 +65,168 @@ class SpanLog:
 
 
 span_log = SpanLog()
+
+#: the sampler asks for the interpreter lock this often: the interpreter's
+#: own switch interval, so under plain bytecode contention a waiter has the
+#: lock within one of them and more lateness means a C call that kept it
+SAMPLER_INTERVAL_S = 0.005
+#: a tick that ran this long after it was due is a ``host.lock_wait`` span
+LOCK_WAIT_MIN_S = 0.001
+#: and one this late keeps the other threads' frames
+LATE_TICK_S = 0.020
+LATE_TICKS_KEPT = 256
+LATE_TICK_FRAMES = 8
+SAMPLER_THREAD_NAME = "tpu-stack-sampler"
+
+
+def _collapse(frame) -> str:
+    """A thread's whole stack as one collapsed-stack line, outermost first
+    (the flamegraph toolchain's interchange format)."""
+    parts: List[str] = []
+    while frame is not None:
+        code = frame.f_code
+        parts.append(f"{os.path.basename(code.co_filename)}:{code.co_name}")
+        frame = frame.f_back
+    return ";".join(reversed(parts))
+
+
+def _innermost(frame) -> List[str]:
+    """The innermost ``LATE_TICK_FRAMES`` frames of a thread, innermost
+    first, each as ``file:line:function``."""
+    out: List[str] = []
+    while frame is not None and len(out) < LATE_TICK_FRAMES:
+        code = frame.f_code
+        out.append(f"{os.path.basename(code.co_filename)}:{frame.f_lineno}:"
+                   f"{code.co_name}")
+        frame = frame.f_back
+    return out
+
+
+class StackProfile:
+    """Collapsed stacks ("frame;frame;frame count" lines, what
+    flamegraph.pl and speedscope ingest) of every thread of the process,
+    one sample a tick of the sampler while the profile is held."""
+
+    def __init__(self):
+        self.counts: collections.Counter = collections.Counter()
+        self.samples = 0
+
+    def collapsed_stacks(self) -> List[str]:
+        return [f"{stack} {n}" for stack, n in self.counts.most_common()]
+
+    def write(self, path: str) -> None:
+        with open(path, "w") as f:
+            f.write("\n".join(self.collapsed_stacks()) + "\n")
+
+
+LateTick = Tuple[float, float, Dict[str, List[str]]]
+
+
+class StackSampler:
+    """The process's one sampler: a daemon thread that asks for the
+    interpreter lock every ``SAMPLER_INTERVAL_S`` and knows how late it got
+    it.  A tick over ``LOCK_WAIT_MIN_S`` late is a ``host.lock_wait`` span
+    in ``span_log`` (from when it was due to when it ran; written then, so
+    not in the profiler's trace).  A tick over ``LATE_TICK_S`` late also
+    keeps, in a ring of the newest ``LATE_TICKS_KEPT``, the innermost
+    frames of every other thread as they stand at that moment: the thread
+    that held the lock is the one standing at the line of its C call.
+
+    It runs while ``span_log.enabled`` is true or a profile is held
+    (``hold`` / ``release``: ``utils/profiler.QueryProfiler``), and its
+    loop ends by itself within two intervals of neither being so.  For a
+    held profile it also collapses every thread's whole stack each tick
+    (every thread of the process: two profiled queries running at once
+    each see the union of both)."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._thread: Optional[threading.Thread] = None
+        self._profiles: List[StackProfile] = []
+        self._late: "collections.deque[LateTick]" = collections.deque(
+            maxlen=LATE_TICKS_KEPT)
+
+    def ensure_running(self) -> None:
+        """Start the thread unless it runs (a second ``collect()``, or a
+        concurrent one, finds it running)."""
+        with self._lock:
+            if self._thread is None:
+                self._thread = threading.Thread(
+                    target=self._run, daemon=True, name=SAMPLER_THREAD_NAME)
+                self._thread.start()
+
+    def hold(self) -> StackProfile:
+        """A new profile, sampled from now until ``release``."""
+        profile = StackProfile()
+        with self._lock:
+            self._profiles.append(profile)
+        self.ensure_running()
+        return profile
+
+    def release(self, profile: StackProfile) -> None:
+        with self._lock:
+            self._profiles = [p for p in self._profiles if p is not profile]
+
+    def late_ticks(self) -> List[LateTick]:
+        """The newest ticks over ``LATE_TICK_S`` late: ``(due, ran, {thread
+        name: innermost frames, as file:line:function})`` on the
+        ``time.perf_counter`` clock."""
+        with self._lock:
+            return list(self._late)
+
+    def clear(self) -> None:
+        with self._lock:
+            self._late.clear()
+
+    def _run(self) -> None:
+        own = threading.get_ident()
+        while True:
+            due = time.perf_counter() + SAMPLER_INTERVAL_S
+            time.sleep(SAMPLER_INTERVAL_S)      # gives the lock up
+            ran = time.perf_counter()
+            # the whole tick under the sampler's lock: a profile that
+            # release() has returned for is written to no more
+            with self._lock:
+                if not self._profiles and not span_log.enabled:
+                    self._thread = None
+                    return
+                late = ran - due
+                if late > LOCK_WAIT_MIN_S:
+                    span_log.record("host.lock_wait", due, ran)
+                if self._profiles or late > LATE_TICK_S:
+                    self._read_frames(
+                        own, (due, ran) if late > LATE_TICK_S else None)
+
+    def _read_frames(self, own: int,
+                     late_tick: Optional[Tuple[float, float]]) -> None:
+        """One look at every other thread's frames: the late tick's ring
+        entry and each held profile's sample.  A method of its own so that
+        the frames, which keep their threads' locals alive, are let go
+        before the next sleep."""
+        frames = {ident: frame
+                  for ident, frame in sys._current_frames().items()
+                  if ident != own}
+        if late_tick is not None:
+            stacks: Dict[str, List[str]] = {}
+            names = {t.ident: t.name for t in threading.enumerate()}
+            for ident, frame in frames.items():
+                name = names.get(ident, str(ident))
+                if name in stacks:
+                    name = f"{name}#{ident}"
+                stacks[name] = _innermost(frame)
+            self._late.append((*late_tick, stacks))
+        for profile in self._profiles:
+            for frame in frames.values():
+                profile.counts[_collapse(frame)] += 1
+            profile.samples += 1
+
+
+sampler = StackSampler()
+
+
+def late_ticks() -> List[LateTick]:
+    """The sampler's ring of ticks over ``LATE_TICK_S`` late."""
+    return sampler.late_ticks()
 
 
 def register_range(name: str, doc: str) -> None:
@@ -198,6 +362,16 @@ _STATIC_RANGES = (
                   "(semaphore released)"),
     ("scan.upload", "Arrow host chunk -> HBM batch upload "
                     "(semaphore held)"),
+    # host -> device hand-over (columnar/column.py put_plane)
+    ("upload.put", "one host plane (a column's data, validity or offsets) "
+                   "handed to the runtime: what jnp.asarray costs the "
+                   "calling thread, not the transfer's completion "
+                   "(nested in scan.upload where a scan uploads)"),
+    # the sampler thread (utils/tracing.py StackSampler)
+    ("host.lock_wait", "a tick of the sampler that got the interpreter "
+                       "lock more than 1 ms late: from when the tick was "
+                       "due to when it ran (written then: in the span "
+                       "log, not the profiler's trace or a query's)"),
     # fused segments (plan/fused.py)
     ("fused.batch", "host work for one fused-program call: key "
                     "building, shared_jit lookup, dispatch, retry loop, "

@@ -31,7 +31,7 @@ CONF = {"spark.rapids.sql.enabled": "true",
 
 Q6_SPANS = ("query.collect", "query.plan", "query.finish", "query.fetch",
             "scan.open", "scan.decode", "scan.wait", "scan.upload",
-            "fused.batch", "fused.feedback")
+            "upload.put", "fused.batch", "fused.feedback")
 Q1_SPANS = Q6_SPANS + ("exchange.write", "exchange.read", "sort.range")
 
 
@@ -178,6 +178,59 @@ def test_scan_decode_excludes_the_wait_on_a_full_queue():
                if n == "scan.decode"]
     assert len(decodes) == 7                    # six items and the end
     assert max(decodes) < stall / 5, decodes
+
+
+def test_a_scan_puts_two_planes_a_fixed_width_column_and_three_a_string(
+        tmp_path):
+    """``upload.put`` is one hand-over of a host plane to the runtime: data
+    and validity, and a string's offsets, nested in the batch's
+    ``scan.upload``; with the log off nothing is written."""
+    groups, rows = 3, 1000
+    path = os.path.join(str(tmp_path), "t.parquet")
+    pq.write_table(pa.table({
+        "k": pa.array(range(groups * rows), type=pa.int64()),
+        "s": pa.array([f"v{i % 7}" for i in range(groups * rows)])}),
+        path, row_group_size=rows)
+    sess = TpuSession({"spark.rapids.sql.enabled": "true",
+                       "spark.rapids.sql.batchSizeRows": str(rows),
+                       "spark.rapids.sql.reader.batchSizeRows": str(rows)})
+    df = sess.read_parquet(path)
+    tracing.span_log.clear()
+    assert len(df.collect()) == groups * rows
+    assert tracing.span_log.snapshot() == []
+    tracing.span_log.enabled = True
+    try:
+        assert len(df.collect()) == groups * rows
+    finally:
+        tracing.span_log.enabled = False
+    spans = tracing.span_log.snapshot()
+    uploads = [(t0, t1) for n, t0, t1 in spans if n == "scan.upload"]
+    puts = [(t0, t1) for n, t0, t1 in spans if n == "upload.put"]
+    assert len(uploads) == groups
+    assert len(puts) == (2 + 3) * groups
+    for t0, t1 in puts:
+        assert sum(u0 <= t0 and t1 <= u1 for u0, u1 in uploads) == 1
+    for u0, u1 in uploads:
+        assert sum(u0 <= t0 and t1 <= u1 for t0, t1 in puts) == 2 + 3
+
+
+@pytest.mark.parametrize("name", ["upload.put", "host.lock_wait"])
+def test_the_host_spans_are_registered_and_documented(name):
+    assert name in tracing.static_ranges()
+    with open(os.path.join(REPO, "docs", "trace_ranges.md")) as f:
+        assert f"| `{name}` |" in f.read()
+
+
+def test_upload_put_is_a_child_of_scan_upload_in_the_query_trace(traced):
+    spans = traced["q6"][0].spans_snapshot()
+    by_id = {s["id"]: s for s in spans}
+    puts = [s for s in spans if s["name"] == "upload.put"]
+    assert puts
+    for s in puts:
+        assert by_id[s["parent"]]["name"] == "scan.upload"
+        assert s["thread"] == by_id[s["parent"]]["thread"]
+    # the sampler's span is written after the fact, outside any query
+    assert not [s for s in spans if s["name"] == "host.lock_wait"]
 
 
 def test_span_log_is_a_bounded_ring():

@@ -1,5 +1,6 @@
 """The comparison that decides ``correct``: every answer the timed
-``collect()`` calls returned, against the plain reference's rows.
+``collect()`` calls returned, against the plain reference's rows for the same
+query and substitution set.
 
 Rows are compared in the order in which they came where the query file says
 ``ORDERED`` (it has an ORDER BY), else as a multiset (sorted, then pairwise).
@@ -35,13 +36,17 @@ def answer_gap(got_rows, want_rows, ordered=False):
 
 def compare(answers, references, limits, fallback_nodes, missing,
             ordered=()) -> dict:
-    """``answers``: (query name, rows) of every query that returned;
-    ``references``: query name -> rows; ``ordered``: the queries whose rows
-    come in a stated order.  Returns name -> {value, limit}, and ``correct``
-    is that every value is within its limit."""
+    """``answers``: (key, rows) of every query that returned, where a key is
+    (query name, substitution set as ``traffic.sub_key`` gives it);
+    ``references``: key -> rows; ``missing``: the queries that raised (a
+    query that admission refused, or that had not finished when the window
+    was drained, is no answer and is not counted here); ``ordered``: the
+    query names whose rows come in a stated order.  Returns name ->
+    {value, limit}, and ``correct`` is that every value is within its
+    limit."""
     wrong, gap = 0, 0.0
-    for qname, rows in answers:
-        exact, g = answer_gap(rows, references[qname], qname in ordered)
+    for key, rows in answers:
+        exact, g = answer_gap(rows, references[key], key[0] in ordered)
         wrong += not exact
         gap = max(gap, g)
     return {

@@ -1,11 +1,11 @@
-"""TPC-H Q1 (spec 2.4.1), the pricing summary report, with the validation
-substitution DELTA 90:
+"""TPC-H Q1 (spec 2.4.1), the pricing summary report, by default with the
+validation substitution DELTA 90:
 
     select l_returnflag, l_linestatus, sum(l_quantity),
            sum(l_extendedprice), sum(l_extendedprice * (1 - l_discount)),
            sum(l_extendedprice * (1 - l_discount) * (1 + l_tax)),
            avg(l_quantity), avg(l_extendedprice), avg(l_discount), count(*)
-    from lineitem where l_shipdate <= date '1998-12-01' - interval '90' day
+    from lineitem where l_shipdate <= date '1998-12-01' - interval 'DELTA' day
     group by l_returnflag, l_linestatus
     order by l_returnflag, l_linestatus
 
@@ -21,9 +21,11 @@ TABLE = "lineitem"
 COLUMNS = ("l_shipdate", "l_discount", "l_quantity", "l_extendedprice",
            "l_tax", "l_returnflag", "l_linestatus")
 ORDERED = True
+# spec 2.4.1.3: DELTA in [60, 120] days
+SUBSTITUTIONS = {"delta": list(range(60, 121))}
 
 
-def build(df):
+def build(df, delta=90):
     from spark_rapids_tpu import types as T
     from spark_rapids_tpu.expressions import Cast, avg, col, count, lit, sum_
     qty = Cast(col("l_quantity"), T.DOUBLE)
@@ -32,7 +34,8 @@ def build(df):
     tax = Cast(col("l_tax"), T.DOUBLE)
     disc_price = price * (lit(1.0) - disc)
     charge = disc_price * (lit(1.0) + tax)
-    return (df.filter(col("l_shipdate") <= lit(days(1998, 9, 2), T.DATE))
+    return (df.filter(col("l_shipdate")
+                      <= lit(days(1998, 12, 1) - delta, T.DATE))
             .group_by("l_returnflag", "l_linestatus")
             .agg(sum_(qty).alias("sum_qty"),
                  sum_(price).alias("sum_base_price"),
@@ -45,8 +48,8 @@ def build(df):
             .order_by("l_returnflag", "l_linestatus"))
 
 
-def reference(li) -> list:
-    sel = li[li["l_shipdate"] <= days(1998, 9, 2)].copy()
+def reference(li, delta=90) -> list:
+    sel = li[li["l_shipdate"] <= days(1998, 12, 1) - delta].copy()
     one = sel["l_discount"].dtype.type(1.0)
     sel["disc_price"] = sel["l_extendedprice"] * (one - sel["l_discount"])
     sel["charge"] = sel["disc_price"] * (one + sel["l_tax"])

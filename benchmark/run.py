@@ -10,11 +10,13 @@ JAX finds; it says so on every line and still exits non-zero.
 Everything that belongs to one cell is data found by name from
 ``BENCHMARK.json``: ``configs/<config>.json``, ``traffic/<traffic>.json``,
 ``tables/<table>.py``, ``queries/<query>.py``, ``metrics/<metric>.py``.
-See ``README.md``.
+A traffic file's ``loop`` is ``closed`` (clients that each
+wait for their last answer) or ``open`` (arrivals on a schedule, served
+through ``serving.QueryQueue``).  See ``README.md``.
 """
 import argparse
 import concurrent.futures
-import faulthandler
+import contextlib
 import gc
 import importlib.util
 import json
@@ -24,6 +26,7 @@ import sys
 import tempfile
 import threading
 import time
+import traceback
 import types
 
 _T0 = time.perf_counter()       # set-up is counted from here: before numpy,
@@ -41,7 +44,12 @@ SLICE_MIN_QUERIES = 2
 SLICE_MIN_SECONDS = 10.0
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 MAX_CONSECUTIVE_FAILURES = 3
-STALL_FACTOR = 3.0      # a query this many times the fastest so far stalls
+STALL_FACTOR = 3.0      # a query this many times the fastest so far stalls,
+STALL_FLOOR_S = 10.0    # and never under this many seconds
+WATCH_TICK_S = 1.0      # how often the watchdog looks
+DRAIN_SECONDS = 60.0    # an open window waits this long past its last due
+#                         time for the answers still out
+JOIN_SECONDS = 30.0     # and this long more for a cancelled arrival to end
 
 
 def say(**fields) -> None:
@@ -83,16 +91,29 @@ class Cell:
         self.chips = cells[name]["chips"]
         cfg = {c["name"]: c for c in bench["configs"]}[cells[name]["config"]]
         self.config = load_json(os.path.join(ROOT, cfg["file"]))
-        self.traffic = traffic.load(os.path.join(
-            HERE, "traffic", cells[name]["traffic"] + ".json"))
+        self.traffic = traffic.load(
+            traffic_path(cells[name]["traffic"]),
+            lambda q: len(substitution_space(load_module("queries", q))))
+        self.open = self.traffic["loop"] == "open"
         self.queries = {q: load_module("queries", q)
                         for q in traffic.query_names(self.traffic)}
+        self.spaces = {q: substitution_space(mod)
+                       for q, mod in self.queries.items()}
         self.tables = {t: load_module("tables", t)
                        for t in {m.TABLE for m in self.queries.values()}}
         self.end_to_end = [m for m in bench["end_to_end"]
                            if name in m.get("workloads", [name])]
         self.per_layer = [m for m in bench["per_layer"]
                           if name in m.get("workloads", [name])]
+
+
+def traffic_path(name: str) -> str:
+    return os.path.join(HERE, "traffic", name + ".json")
+
+
+def substitution_space(mod) -> list:
+    """Every substitution set a query file's ``SUBSTITUTIONS`` allows."""
+    return traffic.substitution_space(getattr(mod, "SUBSTITUTIONS", None))
 
 
 def load_cell(name: str) -> Cell:
@@ -131,47 +152,183 @@ class GcWatch:
 
 
 class Query(types.SimpleNamespace):
-    """One ``collect()`` of the loop: name, table, input rows, start, end
-    (``time.perf_counter``), the rows it returned or the error it raised."""
+    """One query of a loop: name, table, input rows, substitution set
+    (``sub``), due time ``t0`` and end ``t1`` (``time.perf_counter``), the
+    rows it returned, or the error it raised, or why it was ``refused``
+    (admission's reason, or ``unfinished`` when the window was drained).
+    ``service_s`` is how long it ran: None for an open loop's answer from
+    the result cache, which ran nothing.  In a closed loop a query is due
+    when it is sent; an open loop's arrival also has its ``tenant`` and when
+    it was ``fired``."""
+
+    def key(self) -> tuple:
+        """(query, substitution set): what its answer is compared by."""
+        return self.name, traffic.sub_key(self.sub)
+
+
+class Watchdog:
+    """One daemon thread for the process that looks at every running query
+    each ``tick_s`` and, for each one that runs past its limit, writes every
+    thread's stack to ``out``, once a query.  It reads the stacks with
+    ``sys._current_frames()`` under the interpreter lock, so it reads no
+    frame that another thread is changing.  A query's limit is stretched by
+    the number of queries running beside it, which share the chip."""
+
+    def __init__(self, out=None, tick_s: float = None):
+        self.out = out or sys.stderr
+        self.tick_s = tick_s or WATCH_TICK_S
+        self.dumps = 0
+        self._running, self._lock = {}, threading.Lock()
+        self._stop = threading.Event()
+        self._thread = threading.Thread(
+            target=self._loop, name="bench-watchdog", daemon=True)
+        self._thread.start()
+
+    @contextlib.contextmanager
+    def watch(self, label: str, limit_s: float):
+        """Watch the query that runs inside the block."""
+        entry = types.SimpleNamespace(label=label, limit=limit_s,
+                                      start=time.perf_counter(), fired=False)
+        with self._lock:
+            self._running[id(entry)] = entry
+        try:
+            yield
+        finally:
+            with self._lock:
+                del self._running[id(entry)]
+
+    def _loop(self) -> None:
+        while not self._stop.wait(self.tick_s):
+            self.scan(time.perf_counter())
+
+    def scan(self, now: float) -> None:
+        with self._lock:
+            n = len(self._running)
+            over = [e for e in self._running.values()
+                    if not e.fired and now - e.start > e.limit * n]
+            for e in over:
+                e.fired = True
+        for e in over:
+            self._dump(e, now, n)
+
+    def _dump(self, e, now: float, running: int) -> None:
+        names = {t.ident: t.name for t in threading.enumerate()}
+        text = [f"run.py: stall: {e.label} has run {now - e.start:.2f} s, "
+                f"over {e.limit:.2f} s x {running} running; every thread's "
+                "stack follows\n"]
+        for ident, frame in sys._current_frames().items():
+            text.append(f"-- thread {names.get(ident, '?')} ({ident})\n")
+            text.extend(traceback.format_stack(frame))
+        self.dumps += 1
+        print("".join(text), end="", file=self.out, flush=True)
+
+    def close(self) -> None:
+        self._stop.set()
+        self._thread.join(timeout=self.tick_s + 5.0)
+
+
+def stall_limit(fastest) -> float:
+    return max(STALL_FACTOR * fastest, STALL_FLOOR_S)
 
 
 class Client:
     """The system under test behind one entry: ``read_parquet`` -> the
     query file's builder -> ``collect()``."""
 
-    def __init__(self, cell: Cell, files: dict, rows: dict):
+    def __init__(self, cell: Cell, files: dict, rows: dict,
+                 watchdog: Watchdog = None):
         from spark_rapids_tpu.api.session import TpuSession
         self.cell, self.files, self.rows = cell, files, rows
         self.session = TpuSession(dict(cell.config["session_conf"]))
-        self.fastest = {}           # query name -> its fastest collect()
+        self.watchdog = watchdog
+        self.fastest = {}           # query name -> its fastest run
+        self._fastest_lock = threading.Lock()
 
-    def frame(self, qname: str):
+    def frame(self, qname: str, sub: dict = None):
         mod = self.cell.queries[qname]
-        return mod.build(self.session.read_parquet(*self.files[mod.TABLE]))
+        return mod.build(self.session.read_parquet(*self.files[mod.TABLE]),
+                         **(sub or {}))
 
-    def run(self, qname: str) -> Query:
+    def query(self, qname: str, sub: dict, t0: float, **more) -> Query:
         mod = self.cell.queries[qname]
-        q = Query(name=qname, table=mod.TABLE, rows=self.rows[mod.TABLE],
-                  answer=None, error=None, t0=time.perf_counter())
-        # a query that takes STALL_FACTOR times the fastest one before it
-        # has every thread's stack written to standard error while it hangs;
-        # one that does not pays for a timer it cancels.  The timer is the
-        # process's one: with several clients the last to start holds it
+        return Query(name=qname, table=mod.TABLE, rows=self.rows[mod.TABLE],
+                     sub=sub, answer=None, error=None, refused=None, t0=t0,
+                     **more)
+
+    def watch(self, qname: str, label: str):
+        """The watchdog's block for one run of ``qname``: none before the
+        query has a fastest run, or without a watchdog."""
         fastest = self.fastest.get(qname)
-        if fastest:
-            faulthandler.dump_traceback_later(STALL_FACTOR * fastest,
-                                              file=sys.stderr)
+        if self.watchdog is None or not fastest:
+            return contextlib.nullcontext()
+        return self.watchdog.watch(label, stall_limit(fastest))
+
+    def note(self, qname: str, seconds: float) -> None:
+        with self._fastest_lock:
+            self.fastest[qname] = min(seconds,
+                                      self.fastest.get(qname, seconds))
+
+    def run(self, qname: str, sub: dict = None) -> Query:
+        q = self.query(qname, sub or {}, time.perf_counter())
         try:
-            q.answer = self.frame(qname).collect()
+            with self.watch(qname, f"query {qname}"):
+                q.answer = self.frame(qname, sub).collect()
+        except Exception as e:      # noqa: BLE001 — a failed query is counted
+            q.error = f"{type(e).__name__}: {e}"[:300]
+        q.t1 = time.perf_counter()
+        q.service_s = q.t1 - q.t0       # it ran as soon as it was sent
+        if q.error is None:
+            self.note(qname, q.service_s)
+        return q
+
+
+class Served:
+    """The serving tier under test for an open loop: ``QueryQueue`` over a
+    runner, under the configuration's ``session_conf``, with an empty
+    result cache.  ``serve`` runs one arrival on the calling thread."""
+
+    def __init__(self, client: Client, runner):
+        from spark_rapids_tpu.serving.admission import QueryQueue
+        self.client, self.runner = client, runner
+        self.queue = QueryQueue(self._run,
+                                conf=dict(client.cell.config["session_conf"]))
+        self._arrivals = {}        # thread ident -> the arrival it serves
+
+    def _run(self, plan, ctx):
+        """The runner, timed and watched: admission has let the query in."""
+        q = self._arrivals[threading.get_ident()]
+        t = time.perf_counter()
+        try:
+            with self.client.watch(q.name, f"arrival {q.qid} ({q.name})"):
+                return self.runner(plan, ctx)
+        finally:
+            q.service_s = time.perf_counter() - t
+
+    def serve(self, a: traffic.Arrival, due: float, qid: str) -> Query:
+        from spark_rapids_tpu.serving.admission import AdmissionRejected
+        from spark_rapids_tpu.utils.cancel import QueryCancelled
+        q = self.client.query(a.query, a.sub, due, tenant=a.tenant, qid=qid,
+                              fired=time.perf_counter(), service_s=None)
+        self._arrivals[threading.get_ident()] = q
+        try:
+            q.answer = self.queue.submit(
+                self.client.frame(a.query, a.sub).plan, tenant=a.tenant,
+                priority=a.priority, query_id=qid)
+        except AdmissionRejected as e:
+            q.refused = e.reason
+        except QueryCancelled:
+            q.refused = "unfinished"
         except Exception as e:      # noqa: BLE001 — a failed query is counted
             q.error = f"{type(e).__name__}: {e}"[:300]
         finally:
-            if fastest:
-                faulthandler.cancel_dump_traceback_later()
+            del self._arrivals[threading.get_ident()]
         q.t1 = time.perf_counter()
-        if q.error is None:
-            self.fastest[qname] = min(q.t1 - q.t0, fastest or q.t1 - q.t0)
+        if q.service_s is not None and q.error is None and q.refused is None:
+            self.client.note(q.name, q.service_s)
         return q
+
+    def close(self) -> None:
+        self.queue.close()
 
 
 def closed_loop(client: Client, spec: dict, seed: int, stop) -> tuple:
@@ -203,6 +360,55 @@ def closed_loop(client: Client, spec: dict, seed: int, stop) -> tuple:
     return done, t_start, max([q.t1 for q in done], default=t_start)
 
 
+def open_loop(served: Served, schedule: list, seconds: float) -> tuple:
+    """Fires each arrival of ``schedule`` at its due time on a thread of its
+    own, whatever is still in flight, and waits for the answers until
+    ``DRAIN_SECONDS`` past ``seconds``.  An arrival still out then is
+    cancelled and counts as refused, ``unfinished``.  Returns (arrivals in
+    completion order, start, end).  The window ends at the last completion,
+    or at the drain's limit where an arrival was still out, and never before
+    ``seconds``: load was offered for that long."""
+    done, lock, closed = [], threading.Lock(), []
+
+    def one(a, due, qid):
+        q = served.serve(a, due, qid)
+        with lock:
+            if not closed:
+                done.append(q)
+
+    t_start = time.perf_counter()
+    threads = []
+    for i, a in enumerate(schedule):
+        due = t_start + a.due
+        wait = due - time.perf_counter()
+        if wait > 0:
+            time.sleep(wait)
+        th = threading.Thread(target=one, args=(a, due, f"a{i}"),
+                              name=f"bench-arrival-{i}", daemon=True)
+        th.start()
+        threads.append((th, a, due, f"a{i}"))
+    limit = t_start + seconds + DRAIN_SECONDS
+    for th, *_ in threads:
+        th.join(max(0.0, limit - time.perf_counter()))
+    with lock:
+        closed.append(True)
+        out = [(th, a, due, qid) for th, a, due, qid in threads
+               if th.is_alive()]
+    for th, a, due, qid in out:
+        served.queue.cancel(qid, "the window was drained")
+        q = served.client.query(a.query, a.sub, due, tenant=a.tenant,
+                                qid=qid, fired=None, service_s=None)
+        q.refused, q.t1 = "unfinished", limit
+        done.append(q)
+    for th, *_ in out:
+        th.join(JOIN_SECONDS)
+    still = sum(th.is_alive() for th, *_ in out)
+    if out:
+        say(phase="drain", unfinished=len(out), still_running=still)
+    done.sort(key=lambda q: q.t1)
+    return done, t_start, max([q.t1 for q in done] + [t_start + seconds])
+
+
 def percentile(values, p: float) -> float:
     """Nearest-rank percentile of all the values."""
     xs = sorted(values)
@@ -216,10 +422,10 @@ def memory_peak_bytes(devices):
     return max(peaks) if peaks else None
 
 
-def traced_slice(client: Client, cell: Cell, seed: int, seconds: float,
-                 trace_dir: str) -> dict:
-    """A slice of whole queries under the profiler (Python tracer off), the
-    program's span log and a per-query trace scope for its retry counts."""
+def traced_slice(loop, trace_dir: str) -> dict:
+    """A slice of whole queries, ``loop()``'s (queries, start, end), under
+    the profiler (Python tracer off), the program's span log and a
+    per-query trace scope for its retry counts."""
     import jax.profiler
 
     from spark_rapids_tpu.memory import arena
@@ -237,10 +443,7 @@ def traced_slice(client: Client, cell: Cell, seed: int, seconds: float,
     try:
         with jax.profiler.TraceAnnotation(WINDOW_ANNOTATION), \
                 trace_scope(qtrace):
-            queries, t0, t1 = closed_loop(
-                client, cell.traffic, seed + 1,
-                lambda elapsed, n: (n >= SLICE_MIN_QUERIES
-                                    and elapsed >= seconds))
+            queries, t0, t1 = loop()
     finally:
         jax.profiler.stop_trace()
         tracing.span_log.enabled = False
@@ -305,6 +508,118 @@ def drop_data(data) -> None:
     shutil.rmtree(scratch, ignore_errors=True)
 
 
+def shapes_of(cell: Cell, args, slice_seconds: float):
+    """What a run drives: the window's loop and the traced slice's, as
+    functions of the client, and every (query, substitution set) that they
+    use, which the warm-up runs once each.  A closed loop runs each query's
+    validation values; an open one the sets its schedules draw."""
+    if not cell.open:
+        def closed(seed, stop):
+            return lambda client: closed_loop(client, cell.traffic, seed,
+                                              stop)
+        return ([(q, {}) for q in cell.queries],
+                closed(args.seed, lambda elapsed, n: elapsed >= args.seconds),
+                closed(args.seed + 1, lambda elapsed, n: (
+                    n >= SLICE_MIN_QUERIES and elapsed >= slice_seconds)))
+    sets = traffic.draw_sets(cell.traffic, args.seed, cell.spaces)
+    window = traffic.open_schedule(cell.traffic, args.seed, args.seconds,
+                                   sets)
+    sliced = (traffic.open_schedule(cell.traffic, args.seed + 1,
+                                    slice_seconds, sets)
+              if args.trace else [])
+    shapes = {(a.query, traffic.sub_key(a.sub)): (a.query, a.sub)
+              for a in window + sliced}
+
+    def opened(schedule, seconds):
+        def loop(client):
+            from spark_rapids_tpu.serving.admission import LocalSessionRunner
+            served = Served(client, LocalSessionRunner(
+                dict(cell.config["session_conf"])))
+            try:
+                return open_loop(served, schedule, seconds)
+            finally:
+                served.close()
+        return loop
+    return (list(shapes.values()), opened(window, args.seconds),
+            opened(sliced, slice_seconds))
+
+
+def warm_up(client: Client, shapes: list, watch, tag: dict) -> int:
+    """Each (query, substitution set) once, outside any loop; a query's
+    first set is planned and looked at for CPU fallbacks.  Returns the
+    number of fallback nodes found."""
+    from benchmark.plan_check import fallback_nodes, plan_nodes
+    fallbacks, planned = 0, set()
+    for qname, sub in shapes:
+        t, c0 = time.perf_counter(), watch.requests
+        line = {}
+        if qname not in planned:
+            planned.add(qname)
+            plan = client.frame(qname, sub).physical_plan()
+            line["fallback_nodes"] = bad = fallback_nodes(plan)
+            line["plan"] = plan_nodes(plan)
+            fallbacks += len(bad)
+        warm = client.run(qname, sub)
+        say(phase="warm_up", **tag, query=qname, sub=sub, **line,
+            error=warm.error, compiles=watch.requests - c0,
+            seconds=time.perf_counter() - t)
+    return fallbacks
+
+
+def window_line(window: list, served: bool) -> dict:
+    """What the window's progress line says of its queries: counts,
+    latency from the due time, and for an open loop how late the generator
+    ran, the service times, cache hits (answers that did not run), and
+    refusals by reason and by tenant."""
+    ok = [q for q in window if q.error is None and q.refused is None]
+    lat = sorted(q.t1 - q.t0 for q in ok)
+    line = {
+        "queries": len(window), "failed": len(window) - len(ok),
+        "latency_s": {"min": lat[0], "median": lat[len(lat) // 2],
+                      "max": lat[-1]} if lat else None,
+        "stalled": sum(x > STALL_FACTOR * lat[len(lat) // 2] for x in lat)}
+    if not served:
+        return line
+    late = [q.fired - q.t0 for q in window if q.fired is not None]
+    service = [q.service_s for q in ok if q.service_s is not None]
+    refused, tenants = {}, {}
+    for q in window:
+        t = tenants.setdefault(q.tenant, {"arrivals": 0, "answered": 0,
+                                          "cache_hits": 0, "refused": {}})
+        t["arrivals"] += 1
+        if q.refused:
+            refused[q.refused] = refused.get(q.refused, 0) + 1
+            t["refused"][q.refused] = t["refused"].get(q.refused, 0) + 1
+        elif q.error is None:
+            t["answered"] += 1
+            t["cache_hits"] += q.service_s is None
+    line.update(
+        arrivals=len(window), answered=len(ok), executed=len(service),
+        cache_hits=len(ok) - len(service), refused=refused,
+        errors=sum(q.error is not None for q in window),
+        lateness_s={"p50": percentile(late, 50), "p99": percentile(late, 99),
+                    "max": max(late, default=float("nan"))},
+        due_latency_s={"p50": percentile(lat, 50), "p95": percentile(lat, 95)},
+        service_s={"p50": percentile(service, 50),
+                   "p95": percentile(service, 95)},
+        by_tenant=tenants)
+    return line
+
+
+def reference_answers(cell: Cell, files: dict, keys) -> dict:
+    """The plain reference of each (query, substitution set) in ``keys``;
+    each table's frame is read once, with the columns of all its
+    queries."""
+    columns = {}
+    for mod in cell.queries.values():
+        columns.setdefault(mod.TABLE, {}).update(dict.fromkeys(mod.COLUMNS))
+    frames = {t: datagen.read_frame(files[t], list(c))
+              for t, c in columns.items()}
+    return {(qname, sk): cell.queries[qname].reference(
+                frames[cell.queries[qname].TABLE], **dict(sk))
+            for qname, sk in keys}
+
+
 def run(args, rehearsal: bool, cell: Cell = None, data=None) -> dict:
     """Set-up, window, (traced slice,) comparison.  Returns the result
     object.  ``main`` has already started the data and looked for the
@@ -322,6 +637,7 @@ def run(args, rehearsal: bool, cell: Cell = None, data=None) -> dict:
     say(phase="device", **tag, kind=device["kind"], count=device["count"],
         since_start_s=time.perf_counter() - _T0)
     trace_dir = tempfile.mkdtemp(prefix="bench_trace_")
+    dog = Watchdog()
     try:
         # -- set-up: data, session, plans, one warm-up of every query shape
         t = time.perf_counter()
@@ -334,46 +650,30 @@ def run(args, rehearsal: bool, cell: Cell = None, data=None) -> dict:
         t = time.perf_counter()
         client = Client(cell, files, rows)
         say(phase="session", **tag, seconds=time.perf_counter() - t)
-        from benchmark.plan_check import fallback_nodes, plan_nodes
-        fallbacks = 0
-        for qname in cell.queries:
-            t, c0 = time.perf_counter(), watch.requests
-            plan = client.frame(qname).physical_plan()
-            bad = fallback_nodes(plan)
-            fallbacks += len(bad)
-            warm = client.run(qname)
-            say(phase="warm_up", **tag, query=qname, plan=plan_nodes(plan),
-                fallback_nodes=bad, error=warm.error,
-                compiles=watch.requests - c0,
-                seconds=time.perf_counter() - t)
-        del warm
+        slice_seconds = min(SLICE_MIN_SECONDS, float(args.seconds))
+        shapes, window_loop, slice_loop = shapes_of(cell, args, slice_seconds)
+        fallbacks = warm_up(client, shapes, watch, tag)
+        # the loops are watched; a warm-up that compiles is no stall
+        client.watchdog = dog
 
         # -- the measured window
         c0 = watch.requests
         gcw = GcWatch()
         setup_s = time.perf_counter() - _T0
-        window, w0, w1 = closed_loop(
-            client, cell.traffic, args.seed,
-            lambda elapsed, n: elapsed >= args.seconds)
+        window, w0, w1 = window_loop(client)
         window_s = w1 - w0
         compiles_in_window = watch.requests - c0
         peak = memory_peak_bytes(devices)
-        ok = [q for q in window if q.error is None]
-        lat = sorted(q.t1 - q.t0 for q in ok)
-        say(phase="window", **tag, seconds=window_s, queries=len(window),
-            failed=len(window) - len(ok),
-            compiles_in_window=compiles_in_window,
-            latency_s={"min": lat[0], "median": lat[len(lat) // 2],
-                       "max": lat[-1]} if lat else None,
-            stalled=sum(x > STALL_FACTOR * lat[len(lat) // 2] for x in lat),
+        ok = [q for q in window if q.error is None and q.refused is None]
+        say(phase="window", **tag, seconds=window_s,
+            **window_line(window, cell.open),
+            stall_dumps=dog.dumps, compiles_in_window=compiles_in_window,
             gc_s=gcw.seconds, gc_longest_s=gcw.longest,
             memory_peak_bytes=peak)
 
         sliced, digest = None, None
         if args.trace:
-            sliced = traced_slice(
-                client, cell, args.seed,
-                min(SLICE_MIN_SECONDS, float(args.seconds)), trace_dir)
+            sliced = traced_slice(lambda: slice_loop(client), trace_dir)
             span_names = {s for r in readers.values()
                           for s in getattr(r, "SPANS", ())}
             t = time.perf_counter()
@@ -386,22 +686,24 @@ def run(args, rehearsal: bool, cell: Cell = None, data=None) -> dict:
 
         # -- free the program's state, then the plain reference on the host
         every = window + (sliced["queries"] if sliced else [])
-        answers = [(q.name, q.answer) for q in every if q.error is None]
+        answers = [(q.key(), q.answer) for q in every
+                   if q.error is None and q.refused is None]
         errors = [q.error for q in every if q.error is not None]
+        refused = sum(q.refused is not None for q in every)
         client = None
         gc.collect()
         t = time.perf_counter()
-        references = {
-            qname: mod.reference(datagen.read_frame(
-                files[mod.TABLE], mod.COLUMNS))
-            for qname, mod in cell.queries.items()}
+        references = reference_answers(cell, files,
+                                       dict.fromkeys(k for k, _ in answers))
         compared = compare.compare(
             answers, references, cell.config["limits"], fallbacks,
             len(errors), {qname for qname, mod in cell.queries.items()
                           if getattr(mod, "ORDERED", False)})
         say(phase="reference", **tag, answers=len(answers),
-            seconds=time.perf_counter() - t, first_error=errors[:1])
+            references=len(references), seconds=time.perf_counter() - t,
+            first_error=errors[:1])
     finally:
+        dog.close()
         drop_data((scratch, data_ready))
         shutil.rmtree(trace_dir, ignore_errors=True)
 
@@ -424,7 +726,9 @@ def run(args, rehearsal: bool, cell: Cell = None, data=None) -> dict:
             device_kind=device["kind"], window_queries=ok,
             window_s=window_s, compiles_in_window=compiles_in_window,
             memory_peak_bytes=peak, trace=digest,
-            slice_queries=[q for q in sliced["queries"] if q.error is None],
+            slice_queries=[q for q in sliced["queries"]
+                           if q.error is None and q.refused is None
+                           and q.service_s is not None],
             slice_interval=sliced["interval"], spans=sliced["spans"],
             slice_launches=sliced["launches"], slice_oom=sliced["oom"],
             slice_retries=sliced["retries"])
@@ -433,7 +737,7 @@ def run(args, rehearsal: bool, cell: Cell = None, data=None) -> dict:
                      "idle_gaps": digest["idle_gaps"]}
     result = {
         "correct": compare.is_correct(compared),
-        "attempted": len(every), "failed": len(errors),
+        "attempted": len(every), "failed": len(errors) + refused,
         "metrics": metrics, "device": device,
     }
     if breakdown is not None:

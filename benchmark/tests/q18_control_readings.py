@@ -64,8 +64,8 @@ def reading(control: str, mod, frame, row_group_rows, limits,
     """What ``compare`` reads with the control's rows in the program's
     place, and whether that is ``correct``."""
     got, want = CONTROLS[control](mod, frame, row_group_rows, quantity)
-    compared = compare.compare([(QUERY, got)], {QUERY: want}, limits,
-                               fallback_nodes=0, missing=0)
+    compared = compare.compare([((QUERY, ()), got)], {(QUERY, ()): want},
+                               limits, fallback_nodes=0, missing=0)
     return {"control": control, "rows_got": len(got), "rows_want": len(want),
             "correct": compare.is_correct(compared),
             **{k: v["value"] for k, v in compared.items()}}

@@ -75,8 +75,8 @@ def reading(control: str, mod, frame, row_group_rows, limits) -> dict:
     """What ``compare`` reads with the control's rows in the program's
     place, and whether that is ``correct``."""
     got, want = CONTROLS[control](mod, frame, row_group_rows)
-    compared = compare.compare([(QUERY, got)], {QUERY: want}, limits,
-                               fallback_nodes=0, missing=0,
+    compared = compare.compare([((QUERY, ()), got)], {(QUERY, ()): want},
+                               limits, fallback_nodes=0, missing=0,
                                ordered={QUERY} if mod.ORDERED else ())
     differ = sum(a != b for a, b in zip(got, want)) + abs(
         len(got) - len(want))

@@ -217,8 +217,9 @@ def test_the_control_is_not_correct(tmp_path, control, seed):
     if control == "anti_join_without_its_condition":
         assert r["rows_got"] == 0 < r["rows_want"]
     # and the reference in the program's place is correct
+    key = (controls.QUERY, ())
     ok = controls.compare.compare(
-        [(controls.QUERY, mod.reference(frame))],
-        {controls.QUERY: mod.reference(frame)}, config["limits"], 0, 0,
+        [(key, mod.reference(frame))],
+        {key: mod.reference(frame)}, config["limits"], 0, 0,
         {controls.QUERY})
     assert controls.compare.is_correct(ok)

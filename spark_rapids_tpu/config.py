@@ -649,7 +649,10 @@ WATCHDOG_STALL_SECONDS = conf("spark.rapids.watchdog.stallSeconds").doc(
     "and a wait older than this bumps watchdog_stalls and writes a "
     "crashdump-style stall report of all registered waits + thread "
     "stacks (utils/watchdog.py) — a silent hang becomes an actionable, "
-    "typed artifact."
+    "typed artifact.  It also bounds how long a serving single-flight "
+    "follower with no spark.rapids.serving.query.deadline waits for a "
+    "leader still running (spark.rapids.serving.queue.timeout when 0): "
+    "past it the follower runs the query itself."
 ).double_conf(300.0)
 
 WATCHDOG_CANCEL_ON_STALL = conf("spark.rapids.watchdog.cancelOnStall").doc(
@@ -1347,7 +1350,26 @@ def generate_config_docs() -> str:
         default = "(none)" if e.default is None else str(e.default)
         doc = e.doc.replace("\n", " ")
         lines.append(f"| `{e.key}` | {doc} | {default} |")
+    lines += ["", "## Query parameters and compiled programs", "",
+              _LITERAL_RULE_DOC]
     return "\n".join(lines) + "\n"
+
+
+_LITERAL_RULE_DOC = (
+    "No key selects it: a numeric, date, timestamp or boolean literal of a "
+    "projection or a filter, at the root of an expression or under a "
+    "comparison, arithmetic, boolean logic or an alias, is an argument of "
+    "the program that evaluates it (`expressions/core.py` "
+    "`literal_is_argument`), and the program's key holds its type and "
+    "position, not its value. The same query with other values (another "
+    "year, discount or DELTA of a dashboard's query) runs the programs its "
+    "first values compiled. A string, a null, a two-limb decimal, a literal "
+    "under any other expression (which may read its value while the program "
+    "is built: a scale, a count, a pattern, a sort direction), a LIMIT (the "
+    "host's), and the literals of aggregate, join, sort and window "
+    "expressions stay constants: another value there is another program. "
+    "The serving result cache keys every value (`serving/cache.py` "
+    "`plan_fingerprint`), so two values never share an answer.")
 
 
 # -- session timezone ambient -------------------------------------------------

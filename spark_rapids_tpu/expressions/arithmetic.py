@@ -88,6 +88,8 @@ class BinaryArithmetic(BinaryExpression):
     The planner gates result precisions > 18 until the two-limb int128
     kernels land."""
 
+    literal_children_are_arguments = True
+
     _decimal_capable = False
 
     def _is_decimal(self) -> bool:
@@ -258,6 +260,8 @@ class Divide(BinaryExpression):
     intermediate kernel with one final HALF_UP rounding to the Spark
     result scale (reference: GpuDecimalDivide via DecimalUtils,
     arithmetic.scala:1387)."""
+
+    literal_children_are_arguments = True
 
     symbol = "/"
 

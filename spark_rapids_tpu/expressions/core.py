@@ -113,6 +113,13 @@ class Expression:
     #: reads any of those sets it False (plan/fused.py then compacts a
     #: filter's rows before such an expression sees them).
     row_local = True
+    #: the class evaluates its literal children through their ``eval``
+    #: alone and reads no literal's value in Python, so such a child can
+    #: be a program argument (``literal_is_argument``).  Off by default: a
+    #: class that does not say so keeps its literals constants of the
+    #: program and in its key (tests/test_literal_args.py checks the
+    #: source of every class that says so)
+    literal_children_are_arguments = False
 
     @property
     def dtype(self) -> T.DataType:
@@ -365,6 +372,11 @@ class Literal(Expression):
     def nullable(self):
         return self.value is None
 
+    def argument(self) -> np.ndarray:
+        """The value as a 0-d host array of the device type: what a
+        program that takes this literal as an argument is called with."""
+        return np.asarray(self.value, dtype=np.dtype(self._dtype.jnp_dtype))
+
     def eval(self, ctx: EvalContext) -> DeviceColumn:
         cap = ctx.capacity
         if self._dtype.variable_width:
@@ -386,7 +398,12 @@ class Literal(Expression):
         if self.value is None:
             data = jnp.zeros((cap,), _np_dtype_for(self._dtype) if self._dtype.jnp_dtype is None else self._dtype.jnp_dtype)
             return DeviceColumn(data, jnp.zeros((cap,), jnp.bool_), self._dtype)
-        data = jnp.full((cap,), self.value, dtype=self._dtype.jnp_dtype)
+        # a literal the program takes as an argument reads it from the
+        # context (plan/execs/base.py bind_trace_consts); else the value
+        # is a constant of the program
+        arg = ctx.trace_consts.get(id(self))
+        data = jnp.full((cap,), self.value if arg is None else arg[0],
+                        dtype=self._dtype.jnp_dtype)
         data = jnp.where(live, data, jnp.zeros((), data.dtype))
         return DeviceColumn(data, live, self._dtype)
 
@@ -406,6 +423,40 @@ class Literal(Expression):
         return f"lit({self.value!r})"
 
 
+#: the types whose literal can be a program argument: one fixed-width
+#: scalar on the device (a two-limb decimal is two)
+_ARGUMENT_TYPES = (T.BooleanType, T.ByteType, T.ShortType, T.IntegerType,
+                   T.LongType, T.FloatType, T.DoubleType, T.DateType,
+                   T.TimestampType, T.DecimalType)
+
+
+def literal_is_argument(e: Expression,
+                        parent: Optional[Expression] = None) -> bool:
+    """The one rule for which literals a program takes as arguments, so
+    that the same query with other values runs the same compiled program.
+
+    An argument is a non-null ``Literal`` of a fixed-width numeric, date,
+    timestamp or boolean type (a decimal of one limb) at the root of an
+    expression or under a parent whose class evaluates it only through
+    its ``eval`` (``literal_children_are_arguments``: comparisons,
+    arithmetic, boolean logic, an alias): only the literal's own ``eval``
+    reads it, from the bound argument.  Its key records its type and
+    position, not its value (``plan/execs/base.py`` ``expr_cache_key``).
+    A literal under any other parent (a scale, a count, a pattern, a sort
+    direction), a string, a null, and any literal of a program that does
+    not bind its arguments stay constants of the program and in its
+    key."""
+    if type(e) is not Literal or e.value is None:
+        return False
+    dt = e.dtype
+    if not isinstance(dt, _ARGUMENT_TYPES) or (
+            isinstance(dt, T.DecimalType) and dt.uses_two_limbs):
+        return False
+    if not isinstance(e.value, (bool, int, float, np.bool_, np.number)):
+        return False
+    return parent is None or parent.literal_children_are_arguments
+
+
 def lit(value, dtype: Optional[T.DataType] = None) -> Literal:
     if isinstance(value, Literal):
         return value
@@ -419,6 +470,8 @@ def col(name: str) -> Col:
 @dataclasses.dataclass(init=False, eq=False, repr=False)
 class Alias(Expression):
     """Name a subexpression (projection output naming)."""
+
+    literal_children_are_arguments = True
 
     def __init__(self, child: Expression, name: str):
         self.child = child

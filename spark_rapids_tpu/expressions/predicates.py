@@ -49,6 +49,8 @@ def _cmp_dtype(l: T.DataType, r: T.DataType) -> T.DataType:
 
 
 class BinaryComparison(BinaryExpression):
+    literal_children_are_arguments = True
+
     @property
     def dtype(self):
         return T.BOOLEAN
@@ -260,6 +262,8 @@ class EqualNullSafe(BinaryComparison):
 
 
 class And(BinaryExpression):
+    literal_children_are_arguments = True
+
     symbol = "AND"
 
     @property
@@ -288,6 +292,8 @@ class And(BinaryExpression):
 
 
 class Or(BinaryExpression):
+    literal_children_are_arguments = True
+
     symbol = "OR"
 
     @property
@@ -312,6 +318,8 @@ class Or(BinaryExpression):
 
 
 class Not(UnaryExpression):
+    literal_children_are_arguments = True
+
     @property
     def dtype(self):
         return T.BOOLEAN

@@ -75,10 +75,12 @@ _SPMD_CAPS: "_collections.OrderedDict[str, dict]" = \
 _SPMD_CACHE_MAX = 64
 
 
-def _exec_signature(node) -> str:
+def _exec_signature(node, bound: frozenset = frozenset()) -> str:
     """Canonical exec-tree signature: class + schema + every expression
     attribute via expr_cache_key (which records scalar params and dtypes)
-    + plain scalar attributes.  Metrics/caches/execs are skipped."""
+    + plain scalar attributes.  Metrics/caches/execs are skipped.
+    ``bound``: the literals the program binds as arguments
+    (``bound_literals``), keyed by type (``expr_cache_key``)."""
     from spark_rapids_tpu.expressions.core import Expression
     from spark_rapids_tpu.plan.execs.base import (
         expr_cache_key, schema_cache_key)
@@ -88,16 +90,17 @@ def _exec_signature(node) -> str:
             continue
         v = vars(node)[k]
         if isinstance(v, Expression):
-            atoms.append(f"{k}={expr_cache_key(v)}")
+            atoms.append(f"{k}={expr_cache_key(v, bound)}")
         elif (isinstance(v, (tuple, list)) and v
               and all(isinstance(t, Expression) for t in v)):
             atoms.append(
-                f"{k}=[{';'.join(expr_cache_key(t) for t in v)}]")
+                f"{k}=[{';'.join(expr_cache_key(t, bound) for t in v)}]")
         elif (isinstance(v, (tuple, list)) and v
               and all(isinstance(t, tuple) and len(t) == 2
                       and isinstance(t[0], Expression) for t in v)):
             atoms.append(f"{k}=[" + ";".join(
-                expr_cache_key(t[0]) + "/" + repr(t[1]) for t in v) + "]")
+                expr_cache_key(t[0], bound) + "/" + repr(t[1])
+                for t in v) + "]")
         elif isinstance(v, (str, int, float, bool, type(None))):
             atoms.append(f"{k}={v!r}")
         elif (isinstance(v, (tuple, list)) and all(
@@ -108,7 +111,8 @@ def _exec_signature(node) -> str:
             # share a cached program
             atoms.append(f"{k}={list(v)!r}")
     return ("|".join(atoms) + "("
-            + ",".join(_exec_signature(c) for c in node.children) + ")")
+            + ",".join(_exec_signature(c, bound)
+                       for c in node.children) + ")")
 
 
 def _is_scan(node) -> bool:

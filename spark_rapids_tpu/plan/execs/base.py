@@ -22,7 +22,8 @@ import hashlib
 import sys
 import threading
 import time
-from typing import Callable, Dict, Iterator, List, Optional, Tuple
+from typing import (
+    Callable, Dict, FrozenSet, Iterator, List, Optional, Tuple)
 
 from spark_rapids_tpu.columnar.batch import ColumnarBatch, Schema
 from spark_rapids_tpu.utils.tracing import trace_range
@@ -122,6 +123,8 @@ class _LaunchStats:
     by_program: Dict[str, int] = {}     # program name -> launches since reset
     discarded: Dict[str, int] = {}      # reason -> launches whose output a
                                         # fused segment threw away
+    key_last: Dict[str, float] = {}     # program key -> its latest launch
+                                        # (time.perf_counter)
 
 
 #: runtime-sanitizer compile-budget seam (utils/sanitizer.py): called
@@ -140,6 +143,7 @@ def reset_launch_stats() -> None:
         _LaunchStats.count = 0
         _LaunchStats.by_program = {}
         _LaunchStats.discarded = {}
+        _LaunchStats.key_last = {}
 
 
 def launch_stats() -> dict:
@@ -153,12 +157,25 @@ def launch_stats() -> dict:
     window), ``join_cap`` (a join's output rows or gather bytes),
     ``group_cap`` (a grouped partial aggregate's output rows).  A launch
     short of two of them counts under both.  Empty once a plan's
-    capacities have converged."""
+    capacities have converged.
+
+    ``program_keys``: the distinct program keys launched (a key names one
+    compiled program; an alias of a key counts under the key it was
+    compiled for)."""
     with _LaunchStats.lock:
         return {"launches": _LaunchStats.count,
                 "programs": len(_LaunchStats.by_program),
+                "program_keys": len(_LaunchStats.key_last),
                 "by_program": dict(_LaunchStats.by_program),
                 "discarded": dict(_LaunchStats.discarded)}
+
+
+def program_keys_since(t0: float) -> int:
+    """Distinct program keys whose latest launch since the last reset was
+    at or after ``t0`` (``time.perf_counter``): with nothing launched
+    after it, the programs a span of work from ``t0`` on ran."""
+    with _LaunchStats.lock:
+        return sum(t >= t0 for t in _LaunchStats.key_last.values())
 
 
 def count_discarded_launch(*reasons: str) -> None:
@@ -180,12 +197,13 @@ def program_name(kind: str, key: str) -> str:
     return f"{kind}_{digest.hexdigest()}"
 
 
-def _counted(name: str, fn):
+def _counted(name: str, key: str, fn):
     def wrapper(*a, **k):
         with _LaunchStats.lock:
             _LaunchStats.count += 1
             _LaunchStats.by_program[name] = \
                 _LaunchStats.by_program.get(name, 0) + 1
+            _LaunchStats.key_last[key] = time.perf_counter()
         return fn(*a, **k)
     wrapper.__wrapped__ = fn
     return wrapper
@@ -234,7 +252,7 @@ def shared_jit(key: str, make_fn: Callable[[], Callable], *, kind: str,
     # a REAL XLA RESOURCE_EXHAUSTED from any cached program enters the
     # retry/spill machinery as TpuRetryOOM (DeviceMemoryEventHandler analog)
     name = program_name(kind, key)
-    made = _counted(name, translate_device_oom(
+    made = _counted(name, key, translate_device_oom(
         jax.jit(_named(make_fn(), name), **jit_kwargs)))
     with _JIT_CACHE_LOCK:
         fn = _JIT_CACHE.setdefault(key, made)   # racer may have won; reuse
@@ -260,11 +278,15 @@ def alias_shared_jit(key_from: str, key_to: str) -> None:
                 _JIT_CACHE.popitem(last=False)
 
 
-def expr_cache_key(e) -> str:
+def expr_cache_key(e, bound: FrozenSet[int] = frozenset()) -> str:
     """Canonical signature of a bound expression tree for shared_jit keys.
 
     repr() alone is unsafe (lit(5) INT vs LONG print the same), so walk the
-    tree recording class names, dtypes, and scalar attributes."""
+    tree recording class names, dtypes, and scalar attributes.
+
+    ``bound``: the literals the program binds as arguments, as
+    ``bound_literals`` gives them for the expressions it binds; each is
+    keyed by its type and position, not its value."""
     from spark_rapids_tpu import types as T
     from spark_rapids_tpu.expressions.core import Expression
     atoms: List[str] = []
@@ -275,6 +297,9 @@ def expr_cache_key(e) -> str:
             atoms.append(repr(x.dtype))
         except Exception:
             atoms.append("?")
+        if id(x) in bound:
+            atoms.append("arg")
+            return
         for k in sorted(vars(x)):
             if k == "children" or k.startswith("_"):
                 # private attrs are derived caches (e.g. a compiled DFA);
@@ -299,8 +324,8 @@ def expr_cache_key(e) -> str:
     return "|".join(atoms)
 
 
-def exprs_cache_key(exprs) -> str:
-    return ";".join(expr_cache_key(e) for e in exprs)
+def exprs_cache_key(exprs, bound: FrozenSet[int] = frozenset()) -> str:
+    return ";".join(expr_cache_key(e, bound) for e in exprs)
 
 
 def schema_cache_key(s: Schema) -> str:
@@ -423,44 +448,68 @@ class timed:
         return False
 
 
+def _consts_of(exprs):
+    """(expression, its host arrays) for every expression of the trees
+    that a program takes arguments for, in deterministic walk order: the
+    per-expression device constants (``trace_consts``) and the literals
+    that are arguments (``literal_is_argument``)."""
+    from spark_rapids_tpu.expressions.core import literal_is_argument
+    out = []
+
+    def walk(e, parent):
+        tc = getattr(e, "trace_consts", None)
+        if tc is not None:
+            out.append((e, tc()))
+        elif literal_is_argument(e, parent):
+            out.append((e, [e.argument()]))
+        for c in e.children:
+            walk(c, e)
+    for e in exprs:
+        walk(e, None)
+    return out
+
+
 def collect_trace_consts(exprs):
     """Gather per-expression device constants (e.g. compiled DFA tables)
-    from an expression tree, in deterministic walk order.
+    and the literals that are program arguments from an expression tree,
+    in deterministic walk order.
 
     These must enter jitted step functions as ARGUMENTS, not closed-over
     concrete arrays: a closed-over array becomes a hoisted executable
     parameter, which trips jax-0.9 dispatch when equivalent computations
     are traced under more than one jit wrapper (kernels/cast_strings.py
-    note).  Returns a flat list of arrays; bind_trace_consts() re-attaches
-    them inside the trace by repeating the same walk.
+    note); and a literal that is an argument lets the same query with
+    other values run the same program, whose key then holds the literal's
+    type only (``bound_literals``).  Returns a flat list of arrays;
+    bind_trace_consts() re-attaches them inside the trace by repeating the
+    same walk.
     """
-    out = []
+    return [a for _, arrays in _consts_of(exprs) for a in arrays]
 
-    def walk(e):
-        tc = getattr(e, "trace_consts", None)
-        if tc is not None:
-            out.extend(tc())
-        for c in e.children:
-            walk(c)
-    for e in exprs:
-        walk(e)
-    return out
+
+def bound_literals(exprs) -> FrozenSet[int]:
+    """The literals (by ``id``) that a program binding ``exprs`` takes as
+    arguments: what ``expr_cache_key`` keys by type.  The same walk as
+    the binding's, so a program's key and its arguments cannot differ."""
+    from spark_rapids_tpu.expressions.core import Literal
+    return frozenset(id(e) for e, _ in _consts_of(exprs)
+                     if isinstance(e, Literal))
+
+
+def tree_has_trace_consts(exprs) -> bool:
+    """Whether an expression of the trees carries device constants of its
+    own (``trace_consts``, e.g. a compiled DFA); a literal that is a
+    program argument does not count."""
+    return any(getattr(e, "trace_consts", None) is not None
+               or tree_has_trace_consts(e.children) for e in exprs)
 
 
 def bind_trace_consts(exprs, arrays):
     """exprs + flat (possibly traced) array list -> {id(expr): [arrays]}."""
     mapping = {}
     it = iter(arrays)
-
-    def walk(e):
-        tc = getattr(e, "trace_consts", None)
-        if tc is not None:
-            n = len(tc())
-            mapping[id(e)] = [next(it) for _ in range(n)]
-        for c in e.children:
-            walk(c)
-    for e in exprs:
-        walk(e)
+    for e, host in _consts_of(exprs):
+        mapping[id(e)] = [next(it) for _ in host]
     return mapping
 
 
@@ -516,28 +565,37 @@ def regex_bucket(batch, exprs) -> int:
     return SK.bucket_for(m)
 
 
-def jit_bucketed_step(key: str, exprs, make_call, *, kind: str):
+def jit_bucketed_step(prefix: str, exprs, make_call, *, kind: str):
     """Shared project/filter wiring: collect trace consts once, then per
     batch compute the static regex bucket, key the shared_jit cache on it,
-    and invoke with (batch, consts).  ``make_call(string_bucket)`` returns
-    the traceable fn(batch, consts); ``kind`` names the program
+    and invoke with (batch, consts).  The key is ``prefix`` and the
+    expressions' key, whose literals that are arguments are keyed by type
+    (``bound_literals``).  ``make_call(string_bucket)`` returns the
+    traceable fn(batch, consts); ``kind`` names the program
     (shared_jit)."""
     import jax.numpy as _jnp
     from spark_rapids_tpu.expressions.bridge import tree_has_bridge
     exprs = tuple(exprs)
-    consts = tuple(_jnp.asarray(a) for a in collect_trace_consts(exprs))
+    key = f"{prefix}|{exprs_cache_key(exprs, bound_literals(exprs))}"
+
+    @functools.cache
+    def consts():
+        # put on the device at the first call, not when the plan is
+        # built: a fused segment runs these expressions in its own
+        # program and never calls this step
+        return tuple(_jnp.asarray(a) for a in collect_trace_consts(exprs))
 
     if tree_has_bridge(exprs):
         # CPU-bridged steps run EAGERLY: the host round-trip inside
         # CpuBridgeExpression cannot live under jax.jit; surrounding
         # device expressions still execute as (op-by-op) XLA
         return lambda batch: make_call(regex_bucket(batch, exprs))(
-            batch, consts)
+            batch, consts())
 
     def call(batch):
         bkt = regex_bucket(batch, exprs)
         fn = shared_jit(f"{key}|{bkt}", lambda: make_call(bkt), kind=kind)
-        return fn(batch, consts)
+        return fn(batch, consts())
     return call
 
 

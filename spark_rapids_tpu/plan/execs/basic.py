@@ -21,8 +21,6 @@ from spark_rapids_tpu.kernels.selection import compaction_map, gather_batch
 from spark_rapids_tpu.memory.retry import with_retry_no_split
 from spark_rapids_tpu.plan.execs.base import (
     TpuExec,
-    expr_cache_key,
-    exprs_cache_key,
     schema_cache_key,
     shared_jit,
     timed,
@@ -47,11 +45,9 @@ class TpuProjectExec(TpuExec):
             cols = tuple(e.eval(ctx) for e in exprs_t)
             return ColumnarBatch(cols, batch.num_rows, out_schema)
 
-        key = (f"project|{schema_cache_key(child.schema)}|"
-               f"{exprs_cache_key(self.exprs)}")
         self._run = jit_bucketed_step(
-            key, self.exprs, lambda bkt: _p(run, string_bucket=bkt),
-            kind="project")
+            f"project|{schema_cache_key(child.schema)}", self.exprs,
+            lambda bkt: _p(run, string_bucket=bkt), kind="project")
 
     def execute_partition(self, idx: int) -> Iterator[ColumnarBatch]:
         for batch in self.children[0].execute_partition(idx):
@@ -85,11 +81,9 @@ class TpuFilterExec(TpuExec):
             # there is no overflow path here
             return gather_batch(batch, indices, count)
 
-        key = (f"filter|{schema_cache_key(child.schema)}|"
-               f"{expr_cache_key(condition)}")
         self._run = jit_bucketed_step(
-            key, [condition], lambda bkt: _p(run, string_bucket=bkt),
-            kind="filter")
+            f"filter|{schema_cache_key(child.schema)}", [condition],
+            lambda bkt: _p(run, string_bucket=bkt), kind="filter")
 
     def execute_partition(self, idx: int) -> Iterator[ColumnarBatch]:
         from spark_rapids_tpu.plan.execs.coalesce import maybe_shrink

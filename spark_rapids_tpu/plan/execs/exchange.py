@@ -143,14 +143,14 @@ class TpuShuffleExchangeExec(TpuExec):
         per batch for the whole map side, VERDICT r4 #1)."""
         from spark_rapids_tpu.expressions.bridge import tree_has_bridge
         from spark_rapids_tpu.plan.execs.base import (
-            collect_trace_consts, exprs_cache_key, tree_uses_string_bucket)
+            exprs_cache_key, tree_has_trace_consts, tree_uses_string_bucket)
         from spark_rapids_tpu.plan.fused import TpuFusedSegmentExec
         child = self.children[0]
         self._part_rows = [0] * self.out_partitions
         fused = (isinstance(child, TpuFusedSegmentExec)
                  and not tree_has_bridge(self.keys)
                  and not tree_uses_string_bucket(self.keys)
-                 and not collect_trace_consts(self.keys))
+                 and not tree_has_trace_consts(self.keys))
         if fused:
             ex_sig = f"{self.out_partitions}|{exprs_cache_key(self.keys)}"
             for in_part in range(child.num_partitions()):

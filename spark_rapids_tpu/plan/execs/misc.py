@@ -28,7 +28,7 @@ from spark_rapids_tpu.expressions.core import EvalContext, Expression
 from spark_rapids_tpu.kernels.selection import compaction_map, gather_batch
 from spark_rapids_tpu.memory.retry import with_retry_no_split
 from spark_rapids_tpu.plan.execs.base import (
-    TpuExec, exprs_cache_key, schema_cache_key, shared_jit, timed)
+    TpuExec, schema_cache_key, shared_jit, timed)
 
 
 class TpuExpandExec(TpuExec):
@@ -52,10 +52,9 @@ class TpuExpandExec(TpuExec):
                              for e, dt in zip(_proj, out_schema.dtypes))
                 return ColumnarBatch(cols, batch.num_rows, out_schema)
 
-            key = (f"expand{pi}|{schema_cache_key(child.schema)}|"
-                   f"{exprs_cache_key(proj)}")
             self._runs.append(jit_bucketed_step(
-                key, proj, lambda bkt, _r=run: _p(_r, string_bucket=bkt),
+                f"expand{pi}|{schema_cache_key(child.schema)}", proj,
+                lambda bkt, _r=run: _p(_r, string_bucket=bkt),
                 kind="expand"))
 
     def execute_partition(self, idx: int) -> Iterator[ColumnarBatch]:

@@ -429,16 +429,9 @@ class TpuFusedSegmentExec(TpuExec):
         return self._sig
 
     def _all_exprs(self) -> List[Expression]:
-        from spark_rapids_tpu.plan.execs.basic import (
-            TpuFilterExec, TpuProjectExec)
-        out: List[Expression] = []
-        for n in (list(self.chain)
-                  + [bn for bc in self.build_chains for bn in bc]):
-            if isinstance(n, TpuProjectExec):
-                out.extend(n.exprs)
-            elif isinstance(n, TpuFilterExec):
-                out.append(n.condition)
-        return out
+        return [e for n in (list(self.chain)
+                            + [bn for bc in self.build_chains for bn in bc])
+                for e in _bound_exprs(n)]
 
     # -- inputs -------------------------------------------------------------
 
@@ -920,14 +913,7 @@ def _apply_build_chain(bc: List[TpuExec],
     jitted program over the raw merged build (the pre-fold behavior,
     used when the raw build exceeds the in-trace fold bound)."""
     from spark_rapids_tpu.plan.execs.base import schema_cache_key, shared_jit
-    from spark_rapids_tpu.plan.execs.basic import (
-        TpuFilterExec, TpuProjectExec)
-    exprs: List[Expression] = []
-    for n in bc:
-        if isinstance(n, TpuProjectExec):
-            exprs.extend(n.exprs)
-        elif isinstance(n, TpuFilterExec):
-            exprs.append(n.condition)
+    exprs = [e for n in bc for e in _bound_exprs(n)]
     consts = tuple(jnp.asarray(a) for a in collect_trace_consts(exprs))
     bcaps = ",".join(str(c.byte_capacity) for c in merged.columns
                      if c.offsets is not None)
@@ -1254,15 +1240,33 @@ def _emit_join(node, pos: int, left: ColumnarBatch, right: ColumnarBatch,
     return out
 
 
+def _bound_exprs(node) -> List[Expression]:
+    """The expressions of a chain node that the fused program evaluates
+    under its bound arguments (``_emit_node``): a project's and a
+    filter's.  The program binds their literals that are arguments
+    (``_all_exprs``) and its key holds those by type
+    (``_exec_signature_shallow``)."""
+    from spark_rapids_tpu.plan.execs.basic import (
+        TpuFilterExec, TpuProjectExec)
+    if isinstance(node, TpuProjectExec):
+        return list(node.exprs)
+    if isinstance(node, TpuFilterExec):
+        return [node.condition]
+    return []
+
+
 def _exec_signature_shallow(node) -> str:
     """Signature of ONE node (class + schema + expression attrs), without
     recursing into children — segment identity is the chain of node
-    signatures; the stream input's shapes are carried by jit retracing."""
+    signatures; the stream input's shapes are carried by jit retracing.
+    The literals the program binds are keyed by type, so one program
+    serves every value."""
     from spark_rapids_tpu.parallel.stage import _exec_signature
+    from spark_rapids_tpu.plan.execs.base import bound_literals
     saved = node.children
     try:
         node.children = ()
-        return _exec_signature(node)
+        return _exec_signature(node, bound_literals(_bound_exprs(node)))
     finally:
         node.children = saved
 

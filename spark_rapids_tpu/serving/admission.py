@@ -46,6 +46,7 @@ from spark_rapids_tpu.shuffle.stats import HISTOGRAMS, SHUFFLE_COUNTERS
 from spark_rapids_tpu.testing.chaos import CHAOS
 from spark_rapids_tpu.utils.cancel import (
     CANCELS, CancelToken, QueryCancelled, cancellable_wait)
+from spark_rapids_tpu.utils.obs import span
 from spark_rapids_tpu.utils.telemetry import record_event
 
 from spark_rapids_tpu.serving.cache import (
@@ -185,6 +186,13 @@ class QueryQueue:
         #: CancelToken derives from it, so a runaway query self-cancels
         #: instead of holding slots/bytes forever
         self.query_deadline_s = conf.serving_query_deadline
+        #: how long a single-flight follower with no execution deadline
+        #: waits for a leader that is still running: the stall watchdog's
+        #: threshold (a wait that long is a stall), or queue.timeout with
+        #: the watchdog off.  A leader still running then is taken as
+        #: wedged, and the follower runs the query itself.
+        self.follow_limit_s = (conf.watchdog_stall_seconds
+                               or self.queue_timeout_s)
         #: query_id -> live CancelToken — the public cancel() handle
         self._active: Dict[str, CancelToken] = {}
         self._active_lock = threading.Lock()
@@ -420,13 +428,16 @@ class QueryQueue:
         tenant bytes on the way out instead of running to completion."""
         CHAOS.delay("serving.admit.delay")
         overrides = dict(conf or {})
-        # ONE deadline bounds the whole submission (single-flight wait
-        # AND admission): a follower whose leader wedges must not spend
-        # a full timeout on the future and then a second one in _admit
+        # the admission deadline; a single-flight follower's wait for a
+        # running leader is not admission and moves it on
+        # (_submit_under_token)
         budget_s = self.queue_timeout_s if timeout_s is None else timeout_s
         deadline = time.monotonic() + budget_s
         exec_deadline = (self.query_deadline_s if deadline_s is None
                          else deadline_s)
+        # a follower's wait: its own execution deadline bounds it where
+        # there is one (the token), else follow_limit_s
+        follow_s = None if exec_deadline else self.follow_limit_s
         token = CancelToken(label="serving query",
                             deadline_s=exec_deadline or None)
         with self._active_lock:
@@ -457,8 +468,7 @@ class QueryQueue:
         # telemetry back under it
         from contextlib import nullcontext
 
-        from spark_rapids_tpu.utils.obs import (
-            QueryTrace, span, trace_scope)
+        from spark_rapids_tpu.utils.obs import QueryTrace, trace_scope
         trace = (QueryTrace(query_id, enabled=True,
                             max_spans=self.trace_max_spans,
                             default_track="serving")
@@ -471,11 +481,11 @@ class QueryQueue:
                 (trace_scope(trace) if trace is not None
                  else nullcontext()):
             try:
-                with span("serving.submit", anchor=True,
+                with span("serving.submit", anchor=True, log=True,
                           tags={"tenant": tenant, "priority": priority}):
                     return self._submit_under_token(
                         plan, tenant, priority, est_bytes, overrides,
-                        cacheable, deadline, budget_s, token, sf)
+                        cacheable, deadline, budget_s, follow_s, token, sf)
             except QueryCancelled as e:
                 # count THIS submission only when ITS OWN token was
                 # cancelled: a single-flight follower unwinding with the
@@ -511,7 +521,7 @@ class QueryQueue:
 
     def _submit_under_token(self, plan, tenant, priority, est_bytes,
                             overrides, cacheable, deadline, budget_s,
-                            token, sf) -> list:
+                            follow_s, token, sf) -> list:
         """Cache lookup + single-flight + admission + execution of one
         submission (submit()'s body; the caller owns token registration
         and leader-future completion on the error paths)."""
@@ -535,18 +545,22 @@ class QueryQueue:
                         sf["leader"] = Future()
                         self._inflight[key] = sf["leader"]
                 if sf["leader"] is None and existing is not None:
-                    # follower: the leader's finally always completes
-                    # this future; a CANCELLED leader unblocks its
-                    # followers with the QueryCancelled itself (the
-                    # fingerprint's one execution was deliberately
-                    # stopped — re-running it would defeat the cancel);
-                    # any other failure (or a wait past OUR timeout
-                    # bound) falls through to a normal execution of our
-                    # own, bounded by admission
+                    # follower: waits for the leader while it runs, not
+                    # by the admission deadline the two share, up to
+                    # follow_s (None: its own deadline, by its token); a
+                    # CANCELLED leader unblocks its followers with the
+                    # QueryCancelled itself (the fingerprint's one
+                    # execution was deliberately stopped — re-running it
+                    # would defeat the cancel); any other failure, or a
+                    # leader still running past follow_s, falls through
+                    # to a normal execution of our own, whose admission
+                    # gets the whole budget from then on
                     try:
-                        cancellable_wait(
-                            existing, timeout=budget_s, token=token,
-                            site="serving.single_flight")
+                        with span("serving.follow", log=True,
+                                  tags={"tenant": tenant}):
+                            cancellable_wait(
+                                existing, timeout=follow_s, token=token,
+                                site="serving.single_flight")
                     except QueryCancelled:
                         raise
                     except Exception:  # noqa: BLE001  # tpu-lint: allow-swallow(the leader raises its own failure to its own caller; a follower deliberately falls through to execute the query itself, which surfaces any real error)
@@ -555,6 +569,7 @@ class QueryQueue:
                         hit = self.cache.get(key, tenant=tenant)
                         if hit is not None:
                             return hit
+                    deadline = time.monotonic() + budget_s
         # overload gate (serving/overload.py): rate limit -> breaker ->
         # shed, each a typed rejection BEFORE any slot is queued for.
         # The breaker keys on the plan fingerprint even when the result
@@ -568,8 +583,7 @@ class QueryQueue:
                 except UncacheableError:
                     fp = None
             self.overload.check(tenant, priority, fp)
-        from spark_rapids_tpu.utils.obs import span
-        with span("serving.admission", anchor=True,
+        with span("serving.admission", anchor=True, log=True,
                   tags={"tenant": tenant}):
             cost = self._admit(
                 tenant, priority,
@@ -589,7 +603,8 @@ class QueryQueue:
             ctx = QueryContext(tenant, priority, overrides,
                                cancel_token=token)
             with TENANTS.scope(tenant), \
-                    span("serving.run", anchor=True, tags={"tenant": tenant}):
+                    span("serving.run", anchor=True, log=True,
+                         tags={"tenant": tenant}):
                 rows = self.runner(plan, ctx)
             token.check()   # a cancel that raced completion wins
         except QueryCancelled:

@@ -308,7 +308,8 @@ def current_span_id() -> Optional[int]:
 
 @contextmanager
 def span(name: str, track: Optional[str] = None,
-         tags: Optional[dict] = None, anchor: bool = False):
+         tags: Optional[dict] = None, anchor: bool = False,
+         log: bool = False):
     """Lightweight named span: records into the ambient QueryTrace (if
     any) and maintains the open-span stack.  Unlike
     ``tracing.trace_range`` it never touches the XLA profiler — this is
@@ -316,14 +317,20 @@ def span(name: str, track: Optional[str] = None,
     with it must be registered in utils/tracing.py's static range table
     (the trace-ranges drift lint pins the discipline).  ``anchor=True``
     for the spans the exported timeline's structure depends on (see
-    QueryTrace.record_span)."""
+    QueryTrace.record_span).  ``log=True``: the interval also goes to
+    ``tracing.span_log`` while that is enabled, as a ``trace_range``'s
+    does (the serving spans, which the benchmark's readers read)."""
     t0 = time.time()
+    t0_log = time.perf_counter() if log else 0.0
     parent = current_span_id()
     span_id = push_open_span(name)
     try:
         yield
     finally:
         pop_open_span()
+        if log:
+            from spark_rapids_tpu.utils.tracing import span_log
+            span_log.record(name, t0_log, time.perf_counter())
         tr = current_query_trace()
         if tr is not None:
             tr.record_span(name, t0, time.time(), track=track, tags=tags,

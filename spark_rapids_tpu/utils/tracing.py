@@ -421,13 +421,20 @@ _STATIC_RANGES = (
     ("sort.range", "the ORDER BY's own work after its child is drained: "
                    "coalesce or bound sampling and routing, and each "
                    "partition's local sort dispatch"),
-    # serving control plane (serving/admission.py; obs.span)
+    # serving control plane (serving/admission.py; obs.span; admission,
+    # follow and run also go to the span log)
     ("serving.submit", "one serving submission end-to-end: cache "
-                       "lookup, admission, execution"),
+                       "lookup, admission, execution (also in the span "
+                       "log)"),
     ("serving.admission", "admission wait: slots + byte-budget "
-                          "semaphores (priority-then-FIFO)"),
+                          "semaphores (priority-then-FIFO; also in the "
+                          "span log)"),
+    ("serving.follow", "a single-flight follower waiting for the leader "
+                       "that runs the same plan, up to its answer or its "
+                       "failure (also in the span log)"),
     ("serving.run", "admitted query executing under its tenant scope "
-                    "(LocalSessionRunner or ClusterDriverRunner)"),
+                    "(LocalSessionRunner or ClusterDriverRunner; also in "
+                    "the span log)"),
     # driver control plane (cluster/driver.py; obs.span)
     ("driver.query", "one cluster submission attempt: dispatch through "
                      "last rank result"),

@@ -614,11 +614,11 @@ def test_launches_count_under_the_engaged_kind():
     reset_launch_stats()
     assert tpch.q1(df).order_by("l_linenumber").collect()
     by = launch_stats()["by_program"]
-    # the digest this program has since PR 31 took `fuse_across_shuffle` out
-    # of the aggregate's key (same data, same session conf): a digest of the
-    # cache key, which the mask is no part of; the kind before it names the
-    # chain, and says `mfilter` since the grouped aggregate takes the mask
-    assert by["fused_agg_mfilter_project_slice_21306a13"] == 1, by
+    # a digest of the cache key, which the mask is no part of and which
+    # holds the filter's literal by its type (it is an argument of the
+    # program); the kind before it names the chain, and says `mfilter`
+    # since the grouped aggregate takes the mask
+    assert by["fused_agg_mfilter_project_slice_f46377dc"] == 1, by
     assert not any("_filter" in n for n in by)
 
 
@@ -963,12 +963,12 @@ def test_group_capacity_with_string_keys_and_string_buffers(
 
 
 @pytest.mark.parametrize("cell_name,qname,program", [
-    # keyless: no capacity, no caps key, the cache key the parent built
-    ("q6_parquet_sf1", "q6", "fused_agg_mfilter_43815208"),
+    # keyless: no capacity, no caps key; the filter's literals are
+    # arguments, keyed by type, so every substitution set runs this program
+    ("q6_parquet_sf1", "q6", "fused_agg_mfilter_ff84b1e8"),
     # grouped: named after the key it is first built under, which holds no
-    # capacity yet; `mfilter` since its filter hands over the mask (the
-    # digest is the one `fused_agg_filter_slice_2051f78e` had)
-    ("q1_parquet_sf1", "q1", "fused_agg_mfilter_slice_2051f78e")])
+    # capacity yet; `mfilter` since its filter hands over the mask
+    ("q1_parquet_sf1", "q1", "fused_agg_mfilter_slice_f0e1ca90")])
 def test_spec_query_programs_keep_their_names(
         tmp_path, fresh_program_caches, cell_name, qname, program):
     """A program's name is a digest of its cache key and part of the

@@ -475,14 +475,18 @@ def test_plan_fingerprint_rejects_opaque_udfs():
 
 
 def test_single_flight_follower_honors_timeout():
-    """Review finding: a wedged leader must not hold followers hostage —
-    a follower's wait is bounded by ITS timeout, after which it falls
-    through to admission (where the timeout bound also applies)."""
-    import pyarrow.parquet  # noqa: F401 — ensure parquet path works
+    """A single-flight follower's wait for a leader that is still running
+    is bounded by the follower's own deadline (its cancel token), not by
+    the admission timeout the two share: past its deadline it unblocks
+    with a typed ``QueryCancelled`` instead of hanging, and it does not
+    run the query itself."""
+    from spark_rapids_tpu.utils.cancel import QueryCancelled
     gate = threading.Event()
     started = threading.Event()
+    runs = [0]
 
     def stuck(pl, ctx):
+        runs[0] += 1
         started.set()
         gate.wait(30)
         return [("late",)]
@@ -493,16 +497,50 @@ def test_single_flight_follower_honors_timeout():
         .group_by("k").agg(Alias(count(), "n")).plan
     leader = q.submit_async(plan)
     assert started.wait(10)
-    # follower: single-flight wait times out, falls through to
-    # admission, which (slots held by the leader) also times out ->
-    # bounded typed rejection instead of an unbounded hang
     t0 = time.monotonic()
-    with pytest.raises(AdmissionRejected) as e:
-        q.submit(plan, timeout_s=0.3)
-    assert e.value.reason == "timeout"
-    assert time.monotonic() - t0 < 5.0
+    with pytest.raises(QueryCancelled):
+        q.submit(plan, timeout_s=0.3, deadline_s=0.6)
+    assert 0.5 < time.monotonic() - t0 < 5.0
     gate.set()
-    leader.result(timeout=30)
+    assert leader.result(timeout=30) == [("late",)]
+    assert runs[0] == 1
+    q.close()
+
+
+def test_single_flight_follower_without_deadline_hands_over_to_itself():
+    """With no query deadline (the default) a follower still does not wait
+    for a wedged leader forever: past the stall watchdog's threshold it
+    runs the query itself and answers; with the watchdog off the bound is
+    queue.timeout."""
+    assert QueryQueue(lambda pl, ctx: [], conf={}).follow_limit_s == 300.0
+    assert QueryQueue(lambda pl, ctx: [], conf={
+        "spark.rapids.watchdog.stallSeconds": "0",
+        "spark.rapids.serving.queue.timeout": "7"}).follow_limit_s == 7.0
+    gate = threading.Event()
+    started = threading.Event()
+    runs = [0]
+
+    def first_wedges(pl, ctx):
+        runs[0] += 1
+        if runs[0] == 1:
+            started.set()
+            gate.wait(30)
+            return [("late",)]
+        return [("own",)]
+    q = QueryQueue(first_wedges, conf={
+        "spark.rapids.serving.maxConcurrentQueries": "2",
+        "spark.rapids.watchdog.stallSeconds": "0.5"})
+    s = TpuSession({})
+    plan = s.create_dataframe({"k": [1]}, Schema.of(k=T.INT)) \
+        .group_by("k").agg(Alias(count(), "n")).plan
+    leader = q.submit_async(plan)
+    assert started.wait(10)
+    t0 = time.monotonic()
+    assert q.submit(plan) == [("own",)]
+    assert 0.4 < time.monotonic() - t0 < 5.0
+    assert runs[0] == 2
+    gate.set()
+    assert leader.result(timeout=30) == [("late",)]
     q.close()
 
 
